@@ -27,8 +27,6 @@ assert init_worker(local_device_count=2)
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 
 def main():
     path, out_prefix, n_rounds = sys.argv[1], sys.argv[2], int(sys.argv[3])

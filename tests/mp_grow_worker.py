@@ -17,9 +17,6 @@ from xgboost_tpu.parallel.launch import init_worker  # noqa: E402
 assert init_worker(local_device_count=2)
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402

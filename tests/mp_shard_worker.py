@@ -21,9 +21,6 @@ from xgboost_tpu.parallel.launch import init_worker  # noqa: E402
 assert init_worker(local_device_count=2)
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 

@@ -360,3 +360,27 @@ def test_lane_name_derives_seed(tmp_path):
     pinned = pair("pinned", dict(PARAMS, seed=5))
     assert_all_ok(run_tenant_lanes(pinned, quiet=True, stacked=False))
     assert model_bytes(pinned["alpha"]) == model_bytes(pinned["beta"])
+
+
+def test_stacked_dispatch_failure_fails_the_run(tmp_path, monkeypatch):
+    """A stacked dispatch that cannot compile/run is NOT a tenant fault
+    (contrast the gate-fail and crash cases above): every lane of the
+    bucket ends ``status: error`` — none quietly re-runs solo to a
+    green result with the stacked program never having worked."""
+    from xgboost_tpu.models import gbtree
+
+    def refuse(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile the lane kernel")
+
+    monkeypatch.setattr(gbtree, "_scan_rounds_lanes", refuse)
+    monkeypatch.setattr(gbtree, "_scan_rounds_lanes_donated", refuse)
+    lanes = make_lanes(tmp_path, "refused", 2, cycles=2)
+    solo_before = dict(lane_metrics().solo.values())
+    res = run_tenant_lanes(lanes, quiet=True, stacked=True,
+                           window_sec=1.0)
+    for name, r in res.items():
+        assert r["status"] == "error", (name, r)
+        assert "stacked dispatch failed" in r["error"]
+        assert "Mosaic failed to compile" in r["error"]
+        assert not os.path.exists(lanes[name]["publish_path"])
+    assert dict(lane_metrics().solo.values()) == solo_before

@@ -43,7 +43,12 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only harness, pinned — not a default: this process and every
+# child it starts inherit the pin.  On a machine whose environment names
+# the TPU the parent would otherwise hold the chip that every child
+# then wants (one process per chip; ROADMAP S1/R5 bring this to the
+# chip one process per device).
+os.environ["JAX_PLATFORMS"] = "cpu"
 _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_HERE))  # repo root: xgboost_tpu
 sys.path.insert(0, _HERE)                   # tools/: launch_fleet
@@ -229,6 +234,7 @@ def deadline_only() -> int:
         out = {}
     out["deadline_feasible"] = feasible
     out["deadline"] = tight
+    out["backend"] = os.environ["JAX_PLATFORMS"]  # the pin above
     with open(_bench_path(), "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")
@@ -344,6 +350,7 @@ def catalog_only() -> int:
         out = {}
     out["catalog_1"] = cat1
     out["catalog_4"] = cat4
+    out["backend"] = os.environ["JAX_PLATFORMS"]  # the pin above
     with open(_bench_path(), "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")
@@ -394,6 +401,7 @@ def multicore_only() -> int:
                    f"subprocess replicas, {CLIENTS} clients, "
                    f"{cores} effective cores; p99={r3['p99_ms']}ms)")
     out.pop("note", None)   # the scarce-core caveat no longer applies
+    out["backend"] = os.environ["JAX_PLATFORMS"]  # the pin above
     with open(_bench_path(), "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")
@@ -480,9 +488,8 @@ def main():
     except OSError as e:
         out["bench_serving_baseline"] = f"unavailable: {e}"
 
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_fleet.json")
-    with open(path, "w") as f:
+    out["backend"] = os.environ["JAX_PLATFORMS"]  # the pin above
+    with open(_bench_path(), "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")
     print(json.dumps(out))

@@ -34,7 +34,12 @@ import sys
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only harness, pinned — not a default: this process and every
+# child it starts inherit the pin.  On a machine whose environment names
+# the TPU the parent would otherwise hold the chip that every child
+# then wants (one process per chip; ROADMAP S1/R5 bring this to the
+# chip one process per device).
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 

@@ -17,7 +17,12 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only harness, pinned — not a default: this process and every
+# child it starts inherit the pin.  On a machine whose environment names
+# the TPU the parent would otherwise hold the chip that every child
+# then wants (one process per chip; ROADMAP S1/R5 bring this to the
+# chip one process per device).
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np  # noqa: E402
 
@@ -137,6 +142,7 @@ def main():
         "unit": (f"req/s (1-row requests, depth6 x {ROUNDS} trees, "
                  f"{N_FEAT} feats, CPU; p99="
                  f"{per_size[1]['p99_ms']}ms)"),
+        "backend": os.environ["JAX_PLATFORMS"],  # the pin above
         "warmup_sec": round(warmup_s, 2),
         "buckets": engine.buckets,
         "compile_count": engine.compile_count,

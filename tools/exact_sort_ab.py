@@ -11,8 +11,8 @@ routing bits without comparing values again.
 The catch is applying that permutation: the sorted layout carries 3
 operands (packed key, g, h) that all must move, and on TPU a
 row-granular (F, N) take_along_axis / scatter is the known-serializing
-dynamic lane gather (PROFILE.md round 3: 16 ms/level at 1M x 28 for
-ONE operand, vs the whole 3-operand sort at 14 ms).  This tool
+dynamic lane gather (pre-round record: one operand cost more than the
+whole 3-operand sort).  This tool
 measures the actual alternatives at the exact-bench shape:
 
   A. lax.sort of (packed int32 key, g, h), num_keys=1 — the shipped
@@ -25,8 +25,7 @@ measures the actual alternatives at the exact-bench shape:
 
 If B or C beats A by >=1.5x, per-tree sort pays and the grower should
 adopt it; otherwise this file is the committed negative result (like
-pack2/in-kernel routing in earlier rounds).  Measured verdict in
-PROFILE.md round 5.
+pack2/in-kernel routing in earlier rounds; PERF.md "Carried over").
 """
 
 import os
@@ -89,12 +88,10 @@ def main():
     def bench(fn, *args):
         r = fn(*args)
         jax.block_until_ready(r)
-        jax.device_get(np.asarray(jax.tree.leaves(r)[0].ravel()[:1]))
         t0 = time.perf_counter()
         for _ in range(10):
             r = fn(*args)
         jax.block_until_ready(r)
-        jax.device_get(np.asarray(jax.tree.leaves(r)[0].ravel()[:1]))
         return (time.perf_counter() - t0) / 10 * 1e3
 
     t_sort = bench(sort3, key_d, g_d, h_d)

@@ -11,8 +11,7 @@ staging (XGBTPU_EXT_PREFETCH=0).  A second, larger shape (2M x 100)
 scales the streamed volume ~7x to confirm the staging-bound rate
 holds at scale.
 
-Run on the real chip: ``python tools/ext_stream_ab.py``.  Results are
-recorded in PROFILE.md (round 5).
+Run on the real chip: ``python tools/ext_stream_ab.py``.
 """
 
 import json

@@ -218,7 +218,7 @@ def main():
     # round 8: the primary sweep rides update_many's segmented fusion
     # (auto-K, or XGBTPU_ROUNDS_PER_DISPATCH in the env); a second
     # sweep at K=0 measures the per-round dispatch floor the fusion
-    # removes, so the json carries the A/B the PROFILE quotes.
+    # removes, so the json carries that A/B.
     # FIT_PER_ROUND_BASELINE=0 skips it.
     baseline = None
     if os.environ.get("FIT_PER_ROUND_BASELINE", "1") != "0":

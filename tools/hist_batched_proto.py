@@ -3,7 +3,7 @@
 The kernel itself lives in the package now
 (:func:`xgboost_tpu.ops.pallas_hist.build_level_histogram_pallas_batched`,
 dispatched by vmap via the custom_vmap rule in ops/histogram.py); this
-script reproduces the measurement that motivated it (PROFILE.md).
+script reproduces the measurement that motivated it.
 """
 import sys
 

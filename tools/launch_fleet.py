@@ -34,7 +34,12 @@ import time
 import urllib.request
 from typing import Dict, List, Optional
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only harness, pinned — not a default: this process and every
+# child it starts inherit the pin.  On a machine whose environment names
+# the TPU the parent would otherwise hold the chip that every child
+# then wants (one process per chip; ROADMAP S1/R5 bring this to the
+# chip one process per device).
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -331,8 +336,9 @@ def main(argv=None) -> int:
     print(f"[fleet] router {fl.url}; waiting for {args.replicas} "
           "replica(s) to register...", file=sys.stderr)
     fl.wait_ready()
-    print(f"[fleet] up: {args.replicas} replicas in rotation "
-          f"(logs in {args.workdir}/)", file=sys.stderr)
+    print(f"[fleet] up: {args.replicas} replicas in rotation, backend "
+          f"{os.environ['JAX_PLATFORMS']} (pinned; logs in "
+          f"{args.workdir}/)", file=sys.stderr)
 
     supervisor = None
     if args.supervise:

@@ -277,8 +277,8 @@ def write_report(results: dict):
     lines += [
         "",
         "*ours-CPU train sec includes one-off jit compilation (~10-40 s) "
-        "and is not the performance claim; see BENCH_r*.json for TPU "
-        "throughput.",
+        "and is not the performance claim; see PERF.md for what has been "
+        "measured on the chip.",
         "",
     ]
     # preserve appended analysis sections (the attribution sweep from

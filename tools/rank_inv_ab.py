@@ -1,4 +1,4 @@
-"""Rank-gradient op A/B (PROFILE.md round-5 candidate 3).
+"""Rank-gradient op A/B.
 
 The device LambdaRank gradient (rank_device.rank_gradient) is down to
 one unstable 2-key sort + one inverse-permutation scatter + two

@@ -3,8 +3,8 @@
 Trains the bench workload (higgs-1M, depth 6) for a warmup + a traced
 30-round fused launch, then parses the xplane protobuf with
 tensorboard_plugin_profile and prints the top device ops by self time.
-This is the measurement tool behind the round-4/5 "where do the
-milliseconds go" tables in PROFILE.md.
+This is the measurement tool behind the pre-round "where do the
+milliseconds go" tables (deleted in PR 22; ROADMAP S1 replaces it).
 
 Usage: python tools/trace_round.py [workload]   (binary | multiclass | rank)
 """
@@ -51,7 +51,6 @@ def build(workload):
 def barrier(b, d):
     m = b._cache[id(d)].margin
     jax.block_until_ready(m)
-    jax.device_get(np.asarray(m.ravel()[:1]))
 
 
 def main():

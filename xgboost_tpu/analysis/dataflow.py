@@ -130,8 +130,8 @@ def _wrapped_callable(node: ast.Call) -> Optional[str]:
 # ------------------------------------------------------------ traced scope
 #: wrapper callables whose function-valued arguments execute under a
 #: JAX trace.  ``scan``/``while_loop``/``cond`` cover the lax control
-#: flow family; ``shard_map`` covers both jax.experimental and this
-#: tree's parallel/mesh.py compat wrapper (same terminal name).
+#: flow family; ``shard_map`` is matched by terminal name
+#: (``jax.shard_map`` however it was imported).
 _TRACING_WRAPPERS = frozenset({
     "jit", "pmap", "vmap", "shard_map", "scan", "while_loop",
     "fori_loop", "cond", "grad", "value_and_grad", "remat",

@@ -228,9 +228,8 @@ def bin_dense_device(X, cut_values):
     """Device-side quantization of a dense (N, F) float matrix (NaN =
     missing -> bin 0): ``1 + #{c: x >= cut[c]}`` — identical to the
     host ``searchsorted(side="right")`` since cut lists are sorted and
-    inf-padded.  One fused (N, F, C) compare-reduce: ~2 ms at 1M x 28
-    on v5e where the host loop takes seconds (prediction-time path;
-    PROFILE.md round 4)."""
+    inf-padded.  One fused (N, F, C) compare-reduce where the host loop
+    takes seconds at 1M x 28 (prediction-time path)."""
     import jax
     import jax.numpy as jnp
     X = jnp.asarray(X, jnp.float32)

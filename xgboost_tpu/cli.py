@@ -279,23 +279,6 @@ class BoostLearnTask:
             from xgboost_tpu.reliability import faults
             faults.install_spec(self.faults_spec)
 
-        if (self.checkpoint_dir and self.task == "train"
-                and not os.environ.get("XGBTPU_NO_JITCACHE")):
-            # WARM-CACHE RESTART (RECOVERY.md): persist jit
-            # compilations next to the checkpoint ring, so a gang
-            # restart after a worker failure reloads compiled
-            # executables instead of re-tracing and re-compiling —
-            # the dominant recovery cost otherwise.  Must happen
-            # before any backend use.
-            import jax
-            cache_dir = os.path.join(self.checkpoint_dir, "jitcache")
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-
         # multi-host worker mode (launched by xgboost_tpu.launch or a
         # scheduler exporting XGBTPU_COORD): initialize the distributed
         # runtime BEFORE any backend use, train dsplit=row over the
@@ -989,6 +972,16 @@ def _broadcast_checkpoint(bst, start_round: int, rank: int, params: dict):
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    if argv is None:
+        # process entry point (``python -m xgboost_tpu``): place the
+        # persistent jit cache where it does not move between runs
+        # (compile_cache.py), so a gang restart after a worker failure
+        # (RECOVERY.md) or a repeated train/pred reloads compiled
+        # executables instead of re-compiling.  Before any backend use.
+        # An embedder calling ``main([...])`` keeps its own JAX
+        # settings.
+        from xgboost_tpu.compile_cache import configure_compile_cache
+        configure_compile_cache()
     task = BoostLearnTask()
     task.set_param("seed", "0")
     return task.run(list(sys.argv[1:] if argv is None else argv))

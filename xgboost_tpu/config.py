@@ -90,10 +90,10 @@ class TrainParam:
     hist_precision: str = "auto"
     # histogram subtraction + row compaction (build only the smaller
     # child per parent, derive the sibling as parent - small) is NOT a
-    # config param: measured on v5e, XLA row compaction costs an order
-    # of magnitude more than the kernel time it saves (PROFILE.md
-    # round 3), so the public surface carries no known-10x-slower knob
-    # (advisor, round 4).  The A/B stays reachable for kernel work via
+    # config param: XLA row compaction cost an order of magnitude
+    # more than the kernel time it saved when it was tried (pre-round
+    # record; PERF.md "Carried over"), so the public surface carries
+    # no known-slower knob.  The A/B stays reachable for kernel work via
     # env XGBTPU_HIST_SUBTRACTION=1 (numerics tested equal).
     # bin-count alignment quantum for the int8 MXU histogram kernel:
     # the one-hot operand tiles sublanes in 32s, so an unaligned bin
@@ -127,7 +127,8 @@ class TrainParam:
     # a chunk band.  -1 auto = 32 on TPU (batched compare-selects
     # replace the per-tree chain of dependent level launches), scan on
     # CPU (measured SLOWER there — tools/predict_microbench.py;
-    # PROFILE.md round 6); 0/1 = force the sequential scan baseline;
+    # the TPU width is not measured on this machine); 0/1 = force the
+    # sequential scan baseline;
     # >1 = force that chunk width.  XGBTPU_PREDICT_TREE_CHUNK env
     # overrides for A/Bs.
     predict_tree_chunk: int = -1

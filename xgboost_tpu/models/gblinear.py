@@ -94,7 +94,7 @@ def _linear_boost_step_dp_fn(mesh, eta, lam, alpha, lam_bias, block):
     per-round calls hit the jit cache instead of re-tracing (meshes are
     hashable; floats come in already-coerced)."""
     from jax.sharding import PartitionSpec as P
-    from xgboost_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     fn = shard_map(
         functools.partial(
             _linear_boost_step.__wrapped__, eta=eta, lam=lam, alpha=alpha,

@@ -542,8 +542,7 @@ def _traverse_one(tree: TreeArrays, binned: jax.Array, max_depth: int,
     of 2^d nodes, so the per-node lookups compare against a STATIC
     SLICE of the tree arrays (2^d wide) instead of the full perfect
     layout — sliced lookups total ~5 * n_nodes compare-selects per
-    tree where full-table lookups cost ~5 * n_nodes * depth (measured
-    6.3 s -> see PROFILE.md for 1M rows x 100 depth-6 trees).  All
+    tree where full-table lookups cost ~5 * n_nodes * depth.  All
     five channels share one (N, 2^d) compare, as in growth.
     """
     N = binned.shape[0]
@@ -663,9 +662,8 @@ def pad_predict_stack(stack: TreeArrays, tree_group: jax.Array,
 def _chunk_leaves(chunk: TreeArrays, binned, max_depth, root, n_roots):
     """(C, N) leaf indices of one tree chunk: ``_traverse_one`` vmapped
     over the tree axis.  The per-level one-hot compares batch into
-    (C, N, 2^d) fused compare-select-sums — the same lowering that made
-    vmapped ensemble GROWTH beat sequential launches (PROFILE.md round
-    3: table_lookup's custom_vmap rule; 6-tree growth 305 -> 70 ms)."""
+    (C, N, 2^d) fused compare-select-sums — the same lowering vmapped
+    ensemble GROWTH uses (table_lookup's custom_vmap rule)."""
     return jax.vmap(
         lambda tr: _traverse_one(tr, binned, max_depth, root, n_roots)
     )(chunk)
@@ -755,8 +753,8 @@ def predict_margin_binned(stack: TreeArrays, tree_group: jax.Array,
     the ensemble pads to the :func:`padded_tree_count` ladder with
     zero-leaf-value trees, ``tree_chunk`` trees traverse at once under
     ``vmap`` (each level one batched compare-select instead of a
-    per-tree chain of dependent launches — the PROFILE.md round-3
-    vmapped-growth result applied to inference), and per-tree leaf
+    per-tree chain of dependent launches — the vmapped-growth
+    formulation applied to inference), and per-tree leaf
     contributions reduce into the (N, n_group) margin in tree order —
     bit-identical to the sequential scan (tests/test_predict_chunk.py).
     One compilation serves every ensemble size on the same ladder rung
@@ -831,9 +829,9 @@ def predict_margin_fused(stack: TreeArrays, tree_group: jax.Array,
     The transfer-wall companion of :func:`predict_margin_binned` (round
     7): a one-off prediction uploads raw f32 blocks and never
     materializes the binned matrix outside the program — no second
-    device buffer, no extra launch boundary, and on hosts where the
-    upload dominates (PROFILE.md) the quantize+traverse cost hides
-    under the NEXT block's upload (learner's prefetch pipeline).
+    device buffer, no extra launch boundary, and where the upload
+    dominates the quantize+traverse cost hides under the NEXT block's
+    upload (learner's prefetch pipeline).
 
     Bit-parity contract: the quantize sub-graph IS
     ``binning.bin_dense_device`` (imported, not re-derived) and the

@@ -1,7 +1,10 @@
 """ctypes binding to the native IO runtime (native/xgtpu_io.cpp).
 
-Loads ``libxgtpu_io.so`` (building it with the repo Makefile on first
-use when a toolchain is available) and exposes:
+Loads ``libxgtpu_io.so`` — but only one built from the ``native/``
+sources as they are NOW: the library is ignored by git and survives in
+a working tree across checkouts, so a build stamp (digest of its
+sources) sits beside it and a missing or stale stamp means rebuild with
+the repo Makefile (when a toolchain is available).  Exposes:
 
   - :func:`parse_libsvm_native` — multithreaded libsvm parsing
     (reference ``src/io/libsvm_parser.h``'s OMP chunk parser);
@@ -16,6 +19,7 @@ be built (``available()`` returns False).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -30,6 +34,8 @@ from xgboost_tpu.obs.metrics import swallowed_error
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxgtpu_io.so")
+_STAMP_PATH = _LIB_PATH + ".stamp"
+_LIB_SOURCES = ("xgtpu_io.cpp", "Makefile")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -40,17 +46,42 @@ i32p = ctypes.POINTER(ctypes.c_int32)
 f32p = ctypes.POINTER(ctypes.c_float)
 
 
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for name in _LIB_SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _lib_is_current() -> bool:
+    """The library exists AND its stamp matches the present sources."""
+    try:
+        with open(_STAMP_PATH) as f:
+            return (os.path.exists(_LIB_PATH)
+                    and f.read().strip() == _source_digest())
+    except OSError:
+        return False
+
+
 def _build() -> bool:
     if os.environ.get("XGTPU_NO_NATIVE_BUILD"):
         return False
     import sys
-    print("xgboost_tpu: building native IO library (first use; set "
-          "XGTPU_NO_NATIVE_BUILD=1 to skip and use the Python parser)",
-          file=sys.stderr)
+    print("xgboost_tpu: building native IO library (no build of the "
+          "present sources found; set XGTPU_NO_NATIVE_BUILD=1 to skip "
+          "and use the Python parser)", file=sys.stderr)
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "lib"], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_LIB_PATH)
+        # -B: a library left by another checkout can be NEWER than the
+        # sources it was not built from
+        subprocess.run(["make", "-B", "-C", _NATIVE_DIR, "lib"],
+                       check=True, capture_output=True, timeout=120)
+        if not os.path.exists(_LIB_PATH):
+            return False
+        # atomic: a torn stamp must read as "stale", never as a match
+        from xgboost_tpu.reliability.integrity import atomic_write
+        atomic_write(_STAMP_PATH, (_source_digest() + "\n").encode())
+        return True
     except Exception as e:
         # no toolchain -> pure-Python fallback; the degradation is
         # counted so a fleet silently parsing at 1/8 speed shows up
@@ -89,7 +120,7 @@ def get_lib():
     with _lib_lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if not _lib_is_current() and not _build():
             _load_failed = True
             return None
         try:

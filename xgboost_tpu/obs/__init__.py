@@ -53,7 +53,7 @@ def phases_enabled() -> bool:
     ``profile>=1``: the event log is configured, the metrics server is
     up, or ``XGBTPU_OBS=1``.  Phase timing forces device barriers at
     phase boundaries (and keeps the round loop on the host), so it is
-    opt-in — the same cost contract as ``profile=1`` (PROFILE.md).
+    opt-in — the same cost contract as ``profile=1``.
 
     ``XGBTPU_OBS_PHASES=0`` keeps a configured event log / metrics
     server WITHOUT the phase barriers: discrete events and dispatch

@@ -9,8 +9,10 @@ of any single feature.
 
 A Pallas kernel variant lives in :mod:`xgboost_tpu.ops.pallas_hist`
 (selected automatically on TPU); this XLA scatter is the portable path.
-Selection: env ``XGBTPU_HIST`` = ``pallas`` | ``pallas_bf16`` | ``scatter``
-overrides; default is the Pallas kernel on TPU backends, scatter elsewhere.
+Selection happens in ONE place, :func:`hist_backend`: env
+``XGBTPU_HIST`` = ``pallas`` | ``pallas_bf16`` | ``pallas_int8`` |
+``scatter`` overrides; default is the Pallas kernel on TPU backends,
+scatter elsewhere; a pallas impl off-TPU runs interpreted.
 """
 
 from __future__ import annotations
@@ -23,11 +25,27 @@ import jax
 import jax.numpy as jnp
 
 
-def _impl(precision: str = "auto") -> str:
+class HistBackend(NamedTuple):
+    """What builds the level histograms in this process.  ``interpret``
+    is True when a pallas impl was chosen off-TPU (tests,
+    ``XGBTPU_HIST=pallas*`` on CPU): the kernels then run through the
+    Pallas interpreter, not Mosaic."""
+    impl: str        # scatter | pallas | pallas_bf16 | pallas_int8
+    interpret: bool
+
+
+def hist_backend(precision: str = "auto") -> HistBackend:
+    """THE backend -> (impl, interpret) choice; every kernel call site
+    below asks here.  A process whose TPU runtime failed to start falls
+    to the CPU backend and therefore to scatter — correct for tests and
+    CPU users, so a script that claims to run on the chip must assert
+    on this result (chip_smoke.py does) instead of trusting that
+    training finished."""
+    on_tpu = jax.default_backend() == "tpu"
     if precision == "fixed":
         # deterministic fixed-point accumulation: always the scatter
         # path (on every backend) with int32 cells — see FIXED_SCALE.
-        return "scatter"
+        return HistBackend("scatter", False)
     forced = os.environ.get("XGBTPU_HIST", "")
     if forced:
         if forced not in ("pallas", "pallas_bf16", "pallas_int8",
@@ -35,23 +53,18 @@ def _impl(precision: str = "auto") -> str:
             raise ValueError(
                 f"XGBTPU_HIST={forced!r}: expected one of "
                 "'pallas', 'pallas_bf16', 'pallas_int8', 'scatter'")
-        return forced
+        return HistBackend(forced, forced != "scatter" and not on_tpu)
     # evaluated at trace time; the default backend decides the kernel.
     # `precision` is the named TrainParam hist_precision (recorded in
-    # saved models — VERDICT r2: accuracy-affecting precision must be a
-    # visible parameter, not an env-var default): fp32 selects exact-f32
-    # histograms; bf16 takes the bf16 MXU pass (~0.0002 AUC on higgs-1M
-    # for ~1.5x round speed); int8 — the TPU auto default since round 4
-    # — quantizes gradients to 8 bits per call with int32-exact
-    # accumulation (measured ~9x kernel / ~2.4x round speed over bf16;
-    # higgs-1M AUC matches bf16 to the bench's reporting precision).
-    if jax.default_backend() != "tpu":
-        return "scatter"
-    if precision == "fp32":
-        return "pallas"
-    if precision == "bf16":
-        return "pallas_bf16"
-    return "pallas_int8"
+    # saved models: accuracy-affecting precision must be a visible
+    # parameter, not an env-var default): fp32 selects exact-f32
+    # histograms; bf16 takes the bf16 MXU pass; int8 — the TPU auto
+    # default — quantizes gradients to 8 bits per call with
+    # int32-exact accumulation.
+    if not on_tpu:
+        return HistBackend("scatter", False)
+    return HistBackend({"fp32": "pallas", "bf16": "pallas_bf16"}.get(
+        precision, "pallas_int8"), False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -142,7 +155,7 @@ def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
     :func:`build_level_histogram`).  ``binned_t`` is an optional
     RESIDENT pre-transposed operand (pallas_hist.host_transpose_bins,
     built once per dataset by the learner entry)."""
-    impl = _impl(precision)
+    impl = hist_backend(precision).impl
     if not impl.startswith("pallas"):
         return None
     from xgboost_tpu.ops import pallas_hist as ph
@@ -265,19 +278,18 @@ def build_level_histogram(binned: jax.Array, gh: jax.Array, pos: jax.Array,
     if prep is not None:
         fn = _pallas_hist_pre_vmappable(
             n_node, n_bin, prep.precision,
-            jax.default_backend() != "tpu",
+            hist_backend(precision).interpret,
             prep.scale is not None, native)
         if prep.scale is not None:
             return fn(prep.binned, prep.binned_t, prep.gh_in,
                       prep.scale, pos)
         return fn(prep.binned, prep.binned_t, prep.gh_in, pos)
     assert not native, "native layout requires the pallas prep path"
-    impl = _impl(precision)
+    impl, interpret = hist_backend(precision)
     if impl.startswith("pallas"):
         precision = {"pallas_bf16": "bf16", "pallas_int8": "int8",
                      "pallas": "fp32"}[impl]
-        fn = _pallas_hist_vmappable(
-            n_node, n_bin, precision, jax.default_backend() != "tpu")
+        fn = _pallas_hist_vmappable(n_node, n_bin, precision, interpret)
         return fn(binned, gh, pos)
     N, F = binned.shape
     f_ids = jnp.arange(F, dtype=jnp.int32)[None, :]
@@ -306,10 +318,10 @@ def node_stats(gh: jax.Array, pos: jax.Array, n_node: int,
         q = jnp.round(gh * FIXED_SCALE).astype(jnp.int32)
         out = jnp.zeros((n_node, 2), dtype=jnp.int32)
         return out.at[idx].add(q, mode="drop")
-    if _impl().startswith("pallas"):
+    impl, interpret = hist_backend()
+    if impl.startswith("pallas"):
         from xgboost_tpu.ops.pallas_hist import node_stats_pallas
-        return node_stats_pallas(gh, pos, n_node,
-                                 interpret=jax.default_backend() != "tpu")
+        return node_stats_pallas(gh, pos, n_node, interpret=interpret)
     idx = jnp.where(pos < 0, n_node, pos)
     out = jnp.zeros((n_node, 2), dtype=jnp.float32)
     return out.at[idx].add(gh, mode="drop")

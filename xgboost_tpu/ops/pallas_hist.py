@@ -135,8 +135,9 @@ def _tiling(N: int, F: int, n_bin: int):
     lane bound max(2M, 128) = 128 for every m_pad <= 64)."""
     # read at trace time: changing it after the first same-shape call
     # has no effect (jit cache) — set it before the first training
-    # round.  2048 measured best on v5e at 1M x 28
-    # (tools/hist_microbench.py); >= 8192 fails Mosaic compilation.
+    # round.  2048 was the best size in the pre-round records (not
+    # re-measured on this machine); 4096 and 8192 also compile and
+    # verify on the installed Mosaic (chip_smoke.py --kernels, PR 22).
     r_tile = int(os.environ.get("XGBTPU_HIST_RTILE", "2048"))
     # feature tile sized so the output block (f_tile*B, 2M) f32 stays
     # ~<=1MB of VMEM
@@ -559,9 +560,14 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
                       constant_values=-1)
 
     # per-tree (g, h) SUBLANE pairs, rows in lanes (see _hist_kernel's
-    # physical-tiling note): (T, N, 2) -> (2T, N)
-    gh_flat = gh.transpose(0, 2, 1).reshape(2 * T_pad, n_pad)
-    pos_t = pos.astype(jnp.int32)                    # (T_pad, N)
+    # physical-tiling note): (T, N, 2) -> (t_tiles, 2*t_tile, N).  The
+    # tree-tile axis is a LEADING (squeezed) block dim so the block's
+    # sublane dim is the whole t_tile axis: a (t_tile, R) block of a
+    # flat (T_pad, N) array is refused by the TPU lowering whenever
+    # t_tile < T_pad is not a multiple of 8 (9 classes at a 64-node
+    # level: t_tile 6 of T_pad 12)
+    gh_flat = gh.transpose(0, 2, 1).reshape(t_tiles, 2 * t_tile, n_pad)
+    pos_t = pos.astype(jnp.int32).reshape(t_tiles, t_tile, n_pad)
 
     kernel = functools.partial(_batched_hist_kernel, n_bin=n_bin,
                                m_pad=m_pad, f_tile=f_tile, t_tile=t_tile,
@@ -572,9 +578,10 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
         grid=(n_m_tiles, t_tiles, f_pad // f_tile, n_pad // r_tile),
         in_specs=[
             pl.BlockSpec((f_tile, r_tile), lambda mi, ti, fi, ri: (fi, ri)),
-            pl.BlockSpec((t_tile, r_tile), lambda mi, ti, fi, ri: (ti, ri)),
-            pl.BlockSpec((2 * t_tile, r_tile),
-                         lambda mi, ti, fi, ri: (ti, ri)),
+            pl.BlockSpec((None, t_tile, r_tile),
+                         lambda mi, ti, fi, ri: (ti, 0, ri)),
+            pl.BlockSpec((None, 2 * t_tile, r_tile),
+                         lambda mi, ti, fi, ri: (ti, 0, ri)),
         ],
         out_specs=pl.BlockSpec((1, 1, f_tile * n_bin, lanes),
                                lambda mi, ti, fi, ri: (mi, ti, fi, 0)),

@@ -107,7 +107,7 @@ def _colsplit_fn(mesh: Mesh, cfg: GrowConfig, f_local: int, n_shard: int,
     # check_vma=False: every shard derives the SAME tree/row outputs from
     # all-gathered split candidates and psum'd routing bits, but the static
     # varying-manifest analysis cannot see through the argmax/gather chain.
-    from xgboost_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, FEAT_AXIS), P(), P(FEAT_AXIS, None),
@@ -244,7 +244,7 @@ def _colsplit_exact_fn(mesh: Mesh, cfg: GrowConfig, f_local: int,
 
     # check_vma=False for the same reason as _colsplit_fn: every shard
     # derives identical outputs from the merged winners + psum'd bits
-    from xgboost_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, FEAT_AXIS), P(), P(),

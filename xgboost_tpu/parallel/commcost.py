@@ -15,7 +15,7 @@ This module makes that cost a NUMBER instead of prose:
     compiled XLA program, with their payload bytes (what the
     regression test pins against the analytic model);
   - :func:`project_round_time` — a compute/communication model for a
-    k-chip mesh, used for the v5e-16 projection in PROFILE.md.
+    k-chip mesh (a projection, not a measurement).
 """
 
 from __future__ import annotations

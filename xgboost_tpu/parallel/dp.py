@@ -17,11 +17,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from xgboost_tpu.models.tree import (GrowConfig, grow_tree,
                                      table_lookup)
-from xgboost_tpu.parallel.mesh import DATA_AXIS, shard_map
+from xgboost_tpu.parallel.mesh import DATA_AXIS
 
 
 def _psum_data(x):

@@ -251,7 +251,7 @@ def sketch_cuts_global(mesh, values_dev, weights_dev,
     from xgboost_tpu.binning import pack_cuts
 
     K = max(8, int(sketch_ratio / max(sketch_eps, 1.0 / max_bin)))
-    from xgboost_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     fn = shard_map(
         functools.partial(_sketch_shard, K=K, max_bin=max_bin,
                           axis_name="data"),

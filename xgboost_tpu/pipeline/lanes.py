@@ -29,10 +29,13 @@ Contracts:
 - **Per-tenant isolation.**  Only the boosting rounds stack; gate,
   publish, ledger, quarantine and checkpoints stay host-side per lane
   (zero-ungated-served holds PER TENANT).  A lane whose unpack or
-  checkpoint callback raises keeps its error to itself; a failure of
-  the stacked dispatch itself drops every affected lane back to the
-  solo path — loudly (``xgbtpu_lane_solo_total`` + ``lanes.solo``
-  events).
+  checkpoint callback raises keeps its error to itself.  A failure of
+  the stacked dispatch itself (the scan refusing to compile or run) is
+  NOT a tenant fault: every lane of the bucket fails with a
+  :class:`~xgboost_tpu.pipeline.trainer.FatalCycleError`
+  (``lanes.stack_error`` event) and the run reports them as errors —
+  re-running them solo would end ``status: ok`` with the stacked
+  program never having worked.
 - **When the host loop still wins.**  Heterogeneous shapes (every lane
   its own bucket), ``subsample < 1`` with unequal row counts (N-shaped
   RNG draws forbid row padding), or one huge tenant dominating the
@@ -51,7 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from xgboost_tpu.obs import event, lane_metrics, span
-from xgboost_tpu.pipeline.trainer import ContinuousTrainer
+from xgboost_tpu.pipeline.trainer import (ContinuousTrainer,
+                                          FatalCycleError)
 
 __all__ = ["LaneGang", "GangTrainer", "run_tenant_lanes_stacked"]
 
@@ -99,7 +103,6 @@ class _Arrival:
         self.spec = spec
         self.segment_callback = segment_callback
         self.done = False
-        self.fallback = False   # stacked dispatch failed: run solo
         self.exc: Optional[BaseException] = None
 
 
@@ -189,11 +192,6 @@ class LaneGang:
                     for a in batch:
                         a.done = True
                     self._cv.notify_all()
-        if arr.fallback:
-            lane_metrics().solo.inc("stack_error")
-            bst.update_many(dtrain, it0, n_rounds,
-                            segment_callback=segment_callback)
-            return
         if arr.exc is not None:
             raise arr.exc
 
@@ -209,11 +207,16 @@ class LaneGang:
             arrs.sort(key=lambda a: a.name)
             try:
                 self._dispatch_bucket(key, arrs)
-            except Exception as e:  # whole-bucket failure: solo, loudly
+            except Exception as e:
+                # the stacked program failed, not a tenant: fail every
+                # lane of the bucket (other buckets still dispatch)
                 event("lanes.stack_error", lanes=[a.name for a in arrs],
                       error=f"{type(e).__name__}: {e}")
                 for arr in arrs:
-                    arr.fallback = True
+                    arr.exc = FatalCycleError(
+                        f"stacked dispatch failed: "
+                        f"{type(e).__name__}: {e}")
+                    arr.exc.__cause__ = e
 
     def _dispatch_bucket(self, key, arrs: List[_Arrival]) -> None:
         from xgboost_tpu.models.gbtree import (_scan_rounds_lanes,

@@ -53,6 +53,13 @@ _CANDIDATE = "candidate.model"
 _GATED_LOG = "gated.log"
 
 
+class FatalCycleError(RuntimeError):
+    """A cycle failed for a reason no retry of THIS lane can repair —
+    the device program itself (not the lane's data, gate or publish)
+    failed.  :meth:`ContinuousTrainer.run` re-raises it instead of
+    counting it and trying the next cycle."""
+
+
 class ContinuousTrainer:
     """Owns one publish path: warm-starts from it, appends trees on
     fresh data, and republishes through the gate."""
@@ -534,13 +541,16 @@ class ContinuousTrainer:
         """Drive ``cycles`` cycles (0 = forever).  Per-cycle exceptions
         are contained: the error is logged + counted and the loop
         continues — the persisted phase means the next attempt resumes
-        (or re-gates) instead of redoing finished work."""
+        (or re-gates) instead of redoing finished work.  A
+        :class:`FatalCycleError` ends the run."""
         summary = {"cycles": 0, "published": 0, "gate_failed": 0,
                    "publish_rejected": 0, "idle": 0, "errors": 0}
         while cycles <= 0 or summary["cycles"] < cycles:
             summary["cycles"] += 1
             try:
                 out = self.run_cycle()
+            except FatalCycleError:
+                raise
             except Exception as e:
                 summary["errors"] += 1
                 self._event("pipeline.cycle_error",

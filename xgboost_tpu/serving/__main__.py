@@ -32,6 +32,12 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = _build_parser().parse_args(argv)
+    if argv is None:
+        # process entry point: a restarted replica reloads its bucket
+        # executables from the persistent jit cache instead of
+        # recompiling (compile_cache.py)
+        from xgboost_tpu.compile_cache import configure_compile_cache
+        configure_compile_cache()
     from xgboost_tpu.serving import run_server
     run_server(args.model, host=args.host, port=args.port,
                min_bucket=args.min_bucket, max_bucket=args.max_bucket,
