@@ -45,6 +45,26 @@ def test_every_pallas_call_site_lowers_for_tpu():
     assert not failed, "\n".join(failed)
 
 
+@pytest.mark.parametrize("kind,kernel", [
+    ("solo", "hist_level_rows"), ("batched", "hist_level_trees"),
+    ("lanes", "hist_level_lanes"), ("node-stats", "hist_level_node_stats")])
+def test_each_pallas_call_site_names_its_kernel(kind, kernel):
+    """Every ``pallas_call`` passes ``name=``: the name a device trace
+    shows for the kernel (``%<name>.<n>``) is the program's own choice,
+    not the enclosing jit's (OBSERVABILITY.md, kernel names)."""
+    import re
+
+    import jax
+
+    import chip_smoke
+    name, build = next(c for c in chip_smoke.kernel_cases(5000)
+                       if c[0].split()[0] == kind)
+    fn, args, _ = build(False)
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert set(re.findall(r"hist_level_\w+", text)) == {kernel}, name
+
+
 # ------------------------------------------------------ (b) compile cache
 @pytest.fixture
 def config_updates(monkeypatch):

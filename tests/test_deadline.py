@@ -31,7 +31,7 @@ import pytest
 
 import xgboost_tpu as xgb
 from xgboost_tpu.fleet import FleetRouter
-from xgboost_tpu.profiling import reliability_metrics
+from xgboost_tpu.obs import reliability_metrics
 from xgboost_tpu.reliability.deadline import (DEADLINE_HEADER, Deadline,
                                               DeadlineExceeded,
                                               backoff_delay, jittered)

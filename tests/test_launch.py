@@ -154,7 +154,7 @@ def test_watchdog_kills_and_restarts_stalled_gang(tmp_path, capfd):
     trial — stall-detection keepalive, the allreduce_robust timeout
     analog.  Counter- and event-verified."""
     from xgboost_tpu.parallel.launch import launch_local
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     script = tmp_path / "staller.py"
     script.write_text(_STALL_SCRIPT)
     rm = reliability_metrics()
@@ -306,7 +306,7 @@ def test_host_loss_degrades_gang_and_grow_back_restores(
     import threading
 
     from xgboost_tpu.parallel.launch import launch_local
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     monkeypatch.setenv("XGBTPU_FAULTS", "host_loss@t0.r0.v1.")
     gang_dir = tmp_path / "gang"
     gang_dir.mkdir()
@@ -358,7 +358,7 @@ def test_partition_window_self_fence_and_restart(
     FENCE_RC, no further writes) and keepalive restarts the gang —
     reason ``fence``, counter-verified on the launcher side."""
     from xgboost_tpu.parallel.launch import launch_local
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     monkeypatch.setenv("XGBTPU_FAULTS", "partition=30.0@t0.r0.v1.")
     rm = reliability_metrics()
     base = rm.launch_restarts.value("fence")

@@ -13,7 +13,7 @@ Acceptance criteria covered here:
     seam's collective-call count;
 (d) the Prometheus exposition output lints (HELP/TYPE per family,
     cumulative histogram buckets ending at ``+Inf == _count``);
-(e) ``from xgboost_tpu.profiling import ...`` compat survives.
+(e) ``xgboost_tpu.obs`` is the one import path of the primitives.
 """
 
 import json
@@ -89,7 +89,7 @@ def test_histogram_quantile_edge_cases():
 
 
 def test_round_profiler_summary_no_division_by_zero():
-    from xgboost_tpu.profiling import RoundProfiler
+    from xgboost_tpu.obs import RoundProfiler
     prof = RoundProfiler(level=0)
     # a round whose phases all measured 0.0s must not raise
     prof.rounds.append({"round": 0, "phases": {"grow": 0.0}, "t0": None})
@@ -175,7 +175,7 @@ def test_exposition_lint_full_registry():
 
 
 def test_exposition_lint_serving_metrics():
-    from xgboost_tpu.profiling import ServingMetrics
+    from xgboost_tpu.obs import ServingMetrics
     m = ServingMetrics()
     m.latency.observe(0.003)
     m.latency.observe(0.3)
@@ -430,14 +430,18 @@ def test_obs_report_selftest():
     assert "obs_report selftest: OK" in out.stdout
 
 
-def test_profiling_compat_shim():
-    from xgboost_tpu.profiling import (Counter, Gauge,  # noqa: F401
-                                       Histogram, ReliabilityMetrics,
-                                       RoundProfiler, ServingMetrics,
-                                       reliability_metrics)
+def test_obs_is_the_one_import_path():
+    """The ``xgboost_tpu.profiling`` shim is gone (PR 28): the package
+    boundary of the metric primitives and the profiler is ``obs``."""
+    from xgboost_tpu.obs import (Counter, Gauge,  # noqa: F401
+                                 Histogram, ReliabilityMetrics,
+                                 RoundProfiler, ServingMetrics,
+                                 reliability_metrics)
     from xgboost_tpu.obs.profiler import RoundProfiler as ObsRP
     assert RoundProfiler is ObsRP
     assert reliability_metrics() is obs.reliability_metrics()
+    with pytest.raises(ImportError):
+        import xgboost_tpu.profiling  # noqa: F401
 
 
 # ------------------------------------------------------ multi-process

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import xgboost_tpu as xgb
-from xgboost_tpu.profiling import ServingMetrics, reliability_metrics
+from xgboost_tpu.obs import ServingMetrics, reliability_metrics
 from xgboost_tpu.reliability import faults
 from xgboost_tpu.reliability.integrity import (ModelIntegrityError,
                                                add_footer, atomic_write,

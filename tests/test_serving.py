@@ -154,7 +154,7 @@ def test_warmup_does_not_pollute_row_counters(model):
     """Warmup rows are synthetic: rows_total/padded_rows_total must stay
     at zero (dashboards count caller-supplied rows), while
     compiles_total records the warmup's compiles."""
-    from xgboost_tpu.profiling import ServingMetrics
+    from xgboost_tpu.obs import ServingMetrics
     _, _, _, path = model
     m = ServingMetrics()
     eng = PredictEngine(path, min_bucket=8, max_bucket=32, metrics=m,
@@ -222,7 +222,7 @@ def test_batcher_coalesces_concurrent_requests():
 
 
 def test_batcher_backpressure_queuefull():
-    from xgboost_tpu.profiling import ServingMetrics
+    from xgboost_tpu.obs import ServingMetrics
     release = threading.Event()
     metrics = ServingMetrics()
 

@@ -29,7 +29,7 @@ import numpy as np  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import xgboost_tpu as xgb  # noqa: E402
-from xgboost_tpu.profiling import ServingMetrics  # noqa: E402
+from xgboost_tpu.obs import ServingMetrics  # noqa: E402
 from xgboost_tpu.serving import MicroBatcher, PredictEngine  # noqa: E402
 
 ROWS_PER_REQ = (1, 8, 64, 512)

@@ -2042,7 +2042,7 @@ def main(argv=None) -> int:
         return train_stall_mode(args)
 
     from xgboost_tpu.cli import main as cli_main
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     from xgboost_tpu.reliability import faults
 
     work = args.workdir or tempfile.mkdtemp(prefix="xgbtpu_chaos_")

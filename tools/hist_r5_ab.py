@@ -1,6 +1,6 @@
 """Round-5 histogram-kernel A/B: the one-hot build is the bound.
 
-hlo_stats of the fused round (tools/trace_round.py) shows the int8
+hlo_stats of the fused round showed the int8
 kernel at ~1.84 ms/level FLAT in node count — the MXU floor is ~0.6 ms
 and the rest is VPU one-hot construction (B x R compares + i8 convert
 per feature).  Variants:

@@ -336,7 +336,7 @@ class BoostLearnTask:
         the multi-host launcher, so timelines never interleave), and
         ``metrics_port=`` serves live ``/metrics`` + ``/healthz`` from
         a daemon thread (rank r binds port+r — per-rank export of the
-        collective stats).  Env equivalents: XGBTPU_OBS_LOG, XGBTPU_OBS.
+        collective stats).  Env equivalent: XGBTPU_OBS_LOG.
         """
         from xgboost_tpu import obs
         params = self._params_dict()
@@ -927,7 +927,7 @@ def _load_checkpoint(ckpt_dir: str, bst, params: dict):
                   "ring member (file left in place)", file=sys.stderr)
             continue
         except Exception as e:
-            from xgboost_tpu.profiling import reliability_metrics
+            from xgboost_tpu.obs import reliability_metrics
             from xgboost_tpu.reliability.integrity import quarantine
             try:
                 qpath = quarantine(path)

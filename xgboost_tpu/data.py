@@ -29,6 +29,19 @@ from typing import Any, Optional, Sequence
 import numpy as np
 
 
+def upload(host, put=None):
+    """Hand one host array to the device under an ``ingest.upload`` span
+    [bytes].  The span covers the host side of the put only: no barrier
+    is added, the copy may still be in flight when it returns.  ``put``
+    places the array where ``jnp.asarray`` would not (a mesh sharding)."""
+    from xgboost_tpu.obs import span
+    with span("ingest.upload", bytes=int(getattr(host, "nbytes", 0))):
+        if put is None:
+            import jax.numpy as jnp
+            put = jnp.asarray
+        return put(host)
+
+
 class MetaInfo:
     """Per-row (and per-group) metadata (reference src/learner/dmatrix.h:18-145)."""
 
@@ -56,16 +69,14 @@ class MetaInfo:
     def label_dev(self):
         """Device-resident label, cached until the field changes."""
         if "label" not in self._dev_cache:
-            import jax.numpy as jnp
-            self._dev_cache["label"] = jnp.asarray(self.label)
+            self._dev_cache["label"] = upload(self.label)
         return self._dev_cache["label"]
 
     def weight_dev(self, n_rows: int):
         """Device-resident per-row weight (ones when unset), cached."""
         key = ("weight", n_rows)
         if key not in self._dev_cache:
-            import jax.numpy as jnp
-            self._dev_cache[key] = jnp.asarray(self.get_weight(n_rows))
+            self._dev_cache[key] = upload(self.get_weight(n_rows))
         return self._dev_cache[key]
 
     def check_once(self, mark: str, fn) -> None:
@@ -176,40 +187,44 @@ class DMatrix:
         self._lazy_lock = threading.Lock()
         self._nnz: Optional[int] = None
 
-        if isinstance(data, str):
-            from xgboost_tpu.io.dispatch import load_dmatrix_into
-            load_dmatrix_into(self, data, silent=silent)
-        elif isinstance(data, tuple) and len(data) == 4:
-            self.indptr, self.indices, self.values, self._num_col = data
-            self.indptr = np.asarray(self.indptr, dtype=np.int64)
-            self.indices = np.asarray(self.indices, dtype=np.int32)
-            self.values = np.asarray(self.values, dtype=np.float32)
-        elif _is_scipy_sparse(data):
-            csr = data.tocsr()
-            self.indptr = csr.indptr.astype(np.int64)
-            self.indices = csr.indices.astype(np.int32)
-            self.values = csr.data.astype(np.float32)
-            self._num_col = csr.shape[1]
-        else:
-            arr = np.asarray(data, dtype=np.float32)
-            if arr.ndim != 2:
-                raise ValueError("expected 2D array")
-            self._lazy_dense = (arr, missing)
-            self._num_col = arr.shape[1]
+        from xgboost_tpu.obs import span
+        with span("ingest.dmatrix") as sp:
+            if isinstance(data, str):
+                from xgboost_tpu.io.dispatch import load_dmatrix_into
+                load_dmatrix_into(self, data, silent=silent)
+            elif isinstance(data, tuple) and len(data) == 4:
+                self.indptr, self.indices, self.values, self._num_col = data
+                self.indptr = np.asarray(self.indptr, dtype=np.int64)
+                self.indices = np.asarray(self.indices, dtype=np.int32)
+                self.values = np.asarray(self.values, dtype=np.float32)
+            elif _is_scipy_sparse(data):
+                csr = data.tocsr()
+                self.indptr = csr.indptr.astype(np.int64)
+                self.indices = csr.indices.astype(np.int32)
+                self.values = csr.data.astype(np.float32)
+                self._num_col = csr.shape[1]
+            else:
+                arr = np.asarray(data, dtype=np.float32)
+                if arr.ndim != 2:
+                    raise ValueError("expected 2D array")
+                self._lazy_dense = (arr, missing)
+                self._num_col = arr.shape[1]
 
-        if num_col is not None:
-            self._num_col = max(num_col, getattr(self, "_num_col", 0))
-        elif not hasattr(self, "_num_col") or self._num_col is None:
-            self._num_col = int(self.indices.max()) + 1 if len(self.indices) else 0
+            if num_col is not None:
+                self._num_col = max(num_col, getattr(self, "_num_col", 0))
+            elif not hasattr(self, "_num_col") or self._num_col is None:
+                self._num_col = int(self.indices.max()) + 1 if len(self.indices) else 0
 
-        if label is not None:
-            self.info.set_field("label", label)
-        if weight is not None:
-            self.info.set_field("weight", weight)
-        if base_margin is not None:
-            self.info.set_field("base_margin", base_margin)
-        if group is not None:
-            self.info.set_field("group", group)
+            if label is not None:
+                self.info.set_field("label", label)
+            if weight is not None:
+                self.info.set_field("weight", weight)
+            if base_margin is not None:
+                self.info.set_field("base_margin", base_margin)
+            if group is not None:
+                self.info.set_field("group", group)
+            sp.set("rows", self.num_row)
+            sp.set("cols", self._num_col)
 
     # ------------------------------------------------------------------
     def _from_dense_locked(self, arr: np.ndarray, missing: float) -> None:
@@ -234,13 +249,20 @@ class DMatrix:
         in order — arrays first, the ``_lazy_dense = None`` "done" mark
         last — so a lock-free property read that sees the mark cleared
         also sees complete arrays (GIL ordering)."""
+        if self._lazy_dense is None:
+            return
+        from xgboost_tpu.obs import span
         with self._lazy_lock:
             if self._lazy_dense is None:
-                return  # another thread won the race (or nothing lazy)
+                return  # another thread won the race
             arr, missing = self._lazy_dense
-            nc = self._num_col  # num_col= widening must survive rebuild
-            self._from_dense_locked(arr, missing)
-            self._num_col = max(nc, self._num_col)
+            # the dense -> CSR half of ingest.dmatrix, deferred to here
+            with span("ingest.dmatrix", rows=int(arr.shape[0]),
+                      cols=int(arr.shape[1])) as sp:
+                nc = self._num_col  # num_col= widening must survive
+                self._from_dense_locked(arr, missing)
+                self._num_col = max(nc, self._num_col)
+                sp.set("nnz", len(self._values))
             self._lazy_dense = None
 
     @property

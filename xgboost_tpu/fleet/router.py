@@ -342,7 +342,7 @@ class _RouterHandler(BaseHTTPRequestHandler):
             dl = Deadline(rt.deadline_ms)
         if dl is not None and dl.expired():
             # reject before any dispatch: nobody is waiting for this
-            from xgboost_tpu.profiling import reliability_metrics
+            from xgboost_tpu.obs import reliability_metrics
             reliability_metrics().deadline_rejected.inc()
             self._send_json(504, {"error": "deadline expired before "
                                            "dispatch",
@@ -401,7 +401,7 @@ class _RouterHandler(BaseHTTPRequestHandler):
             except NoReplica:
                 self._send_json(503, {"error": "no replica available"})
             except DeadlineExceeded as e:
-                from xgboost_tpu.profiling import reliability_metrics
+                from xgboost_tpu.obs import reliability_metrics
                 reliability_metrics().deadline_rejected.inc()
                 self._send_json(504, {"error": str(e),
                                       "deadline_exceeded": True})

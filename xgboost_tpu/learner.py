@@ -26,9 +26,10 @@ import numpy as np
 from xgboost_tpu.binning import _rank0 as _is_rank0
 from xgboost_tpu.binning import bin_matrix, compute_cuts
 from xgboost_tpu.config import TrainParam
-from xgboost_tpu.data import DMatrix, MetaInfo
+from xgboost_tpu.data import DMatrix, MetaInfo, upload
 from xgboost_tpu.metrics import create_metric
 from xgboost_tpu.objectives import create_objective
+from xgboost_tpu.obs import span, training_metrics
 
 _MAGIC = "xgbtpu001"
 
@@ -218,97 +219,15 @@ class Booster:
                 self.gbtree = GBLinear(self.param, dtrain.num_col)
             else:
                 from xgboost_tpu.models.gbtree import GBTree
-                from xgboost_tpu.models.updaters import parse_updaters
                 self.num_feature = dtrain.num_col
-                if getattr(dtrain, "is_sharded", False):
-                    # per-rank split loading: no process holds full
-                    # columns, so the cut proposal MUST be the device
-                    # sketch over the global mesh (SURVEY.md §5.8)
-                    if self.param.dsplit == "col":
-                        raise NotImplementedError(
-                            "ShardedDMatrix is row-block loaded; "
-                            "dsplit=col needs feature-shard loading "
-                            "(load replicated for column split)")
-                    if "grow_colmaker" in parse_updaters(self.param.updater):
-                        raise NotImplementedError(
-                            "updater=grow_colmaker (exact greedy) needs "
-                            "cuts at every distinct value, which no "
-                            "process can propose from a row shard; load "
-                            "replicated for exact-greedy training")
-                    if self.param.objective.startswith("rank:"):
-                        raise NotImplementedError(
-                            "ranking objectives need global group "
-                            "structure, which row-block split loading "
-                            "cannot provide; load replicated for "
-                            "rank:* training")
-                    from xgboost_tpu.parallel.sketch_device import \
-                        sketch_cuts_global
-                    self._mesh = dtrain.mesh
-                    vals, w = dtrain.device_raw()
-                    cuts = sketch_cuts_global(
-                        self._mesh, vals, w, self.param.max_bin,
-                        self.param.sketch_eps, self.param.sketch_ratio)
-                    del vals, w  # transient raw floats: free before binning
-                elif getattr(dtrain, "is_external", False):
-                    # streaming sketch over raw pages (SURVEY.md §5.7);
-                    # paged matrices always use the histogram method, as
-                    # in the reference (learner-inl.hpp:263-267) — even
-                    # for updater=grow_colmaker (exact_raw is cleared
-                    # below: paged training is binned end to end)
-                    cuts = dtrain.sketch_cuts(self.param.max_bin,
-                                              self.param.sketch_eps,
-                                              self.param.sketch_ratio)
-                elif ("grow_colmaker" in parse_updaters(self.param.updater)
-                        and self.param.dsplit == "row"):
-                    # dsplit=row exact: cuts at every distinct value up
-                    # to max_exact_bin (the reference itself switches
-                    # away from exact under row split,
-                    # learner-inl.hpp:91-93 — this quantized form is
-                    # already more than it offers there)
-                    from xgboost_tpu.binning import compute_cuts_exact
-                    cuts = compute_cuts_exact(dtrain,
-                                              self.param.max_exact_bin)
-                elif "grow_colmaker" in parse_updaters(self.param.updater):
-                    # TRUE exact-greedy (models/colmaker.py): bin-free —
-                    # sorted raw-value scans at ANY cardinality; the
-                    # CutMatrix is a placeholder (nothing is quantized).
-                    # Under dsplit=col each shard scans its own raw
-                    # columns (colsplit.grow_tree_exact_colsplit — the
-                    # DistColMaker analog, exact at any cardinality,
-                    # round 5; previously capped at max_exact_bin cuts)
-                    from xgboost_tpu.binning import CutMatrix
-                    cuts = CutMatrix(
-                        np.full((dtrain.num_col, 1), np.inf, np.float32),
-                        np.zeros(dtrain.num_col, np.int32))
-                elif self.param.dsplit == "row" and (
-                        self.param.device_sketch > 0
-                        or (self.param.device_sketch < 0
-                            and jax.process_count() > 1)):
-                    # distributed cut proposal: per-shard device sketches
-                    # merged over the mesh axis — no host needs a full
-                    # column (SerializeReducer analog, SURVEY.md §5.8)
-                    from xgboost_tpu.parallel import mesh as pmesh
-                    from xgboost_tpu.parallel.sketch_device import \
-                        sketch_cuts_mesh
-                    if self._mesh is None:
-                        self._mesh = (pmesh.get_mesh()
-                                      or pmesh.data_parallel_mesh())
-                    cuts = sketch_cuts_mesh(
-                        self._mesh, dtrain.to_dense(), dtrain.info.weight,
-                        self.param.max_bin, self.param.sketch_eps,
-                        self.param.sketch_ratio)
-                else:
-                    # explicit hist_bin_align>0 lifts the trim-margin
-                    # cap (unconditional alignment); auto keeps
-                    # binning.DEFAULT_TRIM_MARGIN
-                    margin_kw = ({"bin_align_margin": None}
-                                 if int(self.param.hist_bin_align) > 0
-                                 else {})
-                    cuts = compute_cuts(dtrain, self.param.max_bin,
-                                        self.param.sketch_eps,
-                                        self.param.sketch_ratio,
-                                        bin_align=self._bin_align(),
-                                        **margin_kw)
+                if isinstance(dtrain, DMatrix):
+                    # a dense-input matrix defers its CSR to first use:
+                    # build it under its own ingest.dmatrix span, not
+                    # under cuts'
+                    dtrain._materialize()
+                with span("ingest.cuts", features=dtrain.num_col,
+                          max_bin=self.param.max_bin):
+                    cuts = self._propose_cuts(dtrain)
                 self.gbtree = GBTree(self.param, cuts)
                 if getattr(dtrain, "is_external", False):
                     # paged matrices route through the binned pipeline
@@ -338,6 +257,102 @@ class Booster:
             self._entry(d)
         self._pending_cache = []
 
+    def _propose_cuts(self, dtrain: DMatrix):
+        """The ``CutMatrix`` of a fresh gbtree model: whichever cut
+        proposal the matrix kind, the updater and the split mode call
+        for (may resolve ``self._mesh`` on the way)."""
+        from xgboost_tpu.models.updaters import parse_updaters
+        if getattr(dtrain, "is_sharded", False):
+            # per-rank split loading: no process holds full
+            # columns, so the cut proposal MUST be the device
+            # sketch over the global mesh (SURVEY.md §5.8)
+            if self.param.dsplit == "col":
+                raise NotImplementedError(
+                    "ShardedDMatrix is row-block loaded; "
+                    "dsplit=col needs feature-shard loading "
+                    "(load replicated for column split)")
+            if "grow_colmaker" in parse_updaters(self.param.updater):
+                raise NotImplementedError(
+                    "updater=grow_colmaker (exact greedy) needs "
+                    "cuts at every distinct value, which no "
+                    "process can propose from a row shard; load "
+                    "replicated for exact-greedy training")
+            if self.param.objective.startswith("rank:"):
+                raise NotImplementedError(
+                    "ranking objectives need global group "
+                    "structure, which row-block split loading "
+                    "cannot provide; load replicated for "
+                    "rank:* training")
+            from xgboost_tpu.parallel.sketch_device import \
+                sketch_cuts_global
+            self._mesh = dtrain.mesh
+            vals, w = dtrain.device_raw()
+            cuts = sketch_cuts_global(
+                self._mesh, vals, w, self.param.max_bin,
+                self.param.sketch_eps, self.param.sketch_ratio)
+            del vals, w  # transient raw floats: free before binning
+        elif getattr(dtrain, "is_external", False):
+            # streaming sketch over raw pages (SURVEY.md §5.7);
+            # paged matrices always use the histogram method, as
+            # in the reference (learner-inl.hpp:263-267) — even
+            # for updater=grow_colmaker (exact_raw is cleared
+            # below: paged training is binned end to end)
+            cuts = dtrain.sketch_cuts(self.param.max_bin,
+                                      self.param.sketch_eps,
+                                      self.param.sketch_ratio)
+        elif ("grow_colmaker" in parse_updaters(self.param.updater)
+                and self.param.dsplit == "row"):
+            # dsplit=row exact: cuts at every distinct value up
+            # to max_exact_bin (the reference itself switches
+            # away from exact under row split,
+            # learner-inl.hpp:91-93 — this quantized form is
+            # already more than it offers there)
+            from xgboost_tpu.binning import compute_cuts_exact
+            cuts = compute_cuts_exact(dtrain,
+                                      self.param.max_exact_bin)
+        elif "grow_colmaker" in parse_updaters(self.param.updater):
+            # TRUE exact-greedy (models/colmaker.py): bin-free —
+            # sorted raw-value scans at ANY cardinality; the
+            # CutMatrix is a placeholder (nothing is quantized).
+            # Under dsplit=col each shard scans its own raw
+            # columns (colsplit.grow_tree_exact_colsplit — the
+            # DistColMaker analog, exact at any cardinality,
+            # round 5; previously capped at max_exact_bin cuts)
+            from xgboost_tpu.binning import CutMatrix
+            cuts = CutMatrix(
+                np.full((dtrain.num_col, 1), np.inf, np.float32),
+                np.zeros(dtrain.num_col, np.int32))
+        elif self.param.dsplit == "row" and (
+                self.param.device_sketch > 0
+                or (self.param.device_sketch < 0
+                    and jax.process_count() > 1)):
+            # distributed cut proposal: per-shard device sketches
+            # merged over the mesh axis — no host needs a full
+            # column (SerializeReducer analog, SURVEY.md §5.8)
+            from xgboost_tpu.parallel import mesh as pmesh
+            from xgboost_tpu.parallel.sketch_device import \
+                sketch_cuts_mesh
+            if self._mesh is None:
+                self._mesh = (pmesh.get_mesh()
+                              or pmesh.data_parallel_mesh())
+            cuts = sketch_cuts_mesh(
+                self._mesh, dtrain.to_dense(), dtrain.info.weight,
+                self.param.max_bin, self.param.sketch_eps,
+                self.param.sketch_ratio)
+        else:
+            # explicit hist_bin_align>0 lifts the trim-margin
+            # cap (unconditional alignment); auto keeps
+            # binning.DEFAULT_TRIM_MARGIN
+            margin_kw = ({"bin_align_margin": None}
+                         if int(self.param.hist_bin_align) > 0
+                         else {})
+            cuts = compute_cuts(dtrain, self.param.max_bin,
+                                self.param.sketch_eps,
+                                self.param.sketch_ratio,
+                                bin_align=self._bin_align(),
+                                **margin_kw)
+        return cuts
+
     @property
     def _K(self) -> int:
         return max(1, self.param.num_output_group)
@@ -345,7 +360,7 @@ class Booster:
     def _base_margin_of(self, dmat: DMatrix, n: int) -> jax.Array:
         bm = dmat.info.base_margin
         if bm is not None:
-            return jnp.asarray(np.asarray(bm, np.float32).reshape(n, self._K))
+            return upload(np.asarray(bm, np.float32).reshape(n, self._K))
         base = self.obj.prob_to_margin(self.param.base_score)
         return jnp.full((n, self._K), base, jnp.float32)
 
@@ -435,8 +450,13 @@ class Booster:
             elif self._rank_pad_ok(dmat):
                 self._cache[key] = self._make_rank_padded_entry(dmat)
             else:
-                binned_host = bin_matrix(dmat, self.gbtree.cuts)
-                binned = jnp.asarray(binned_host)
+                # the deferred dense -> CSR under its own ingest.dmatrix
+                # span, not under bin's
+                dmat._materialize()
+                bin_span = dict(rows=dmat.num_row, features=dmat.num_col)
+                with span("ingest.bin", **bin_span):
+                    binned_host = bin_matrix(dmat, self.gbtree.cuts)
+                binned = upload(binned_host)
                 if self._col_mesh is not None:
                     # pad the feature axis ONCE per matrix (padding per
                     # boosting round would re-copy the whole matrix)
@@ -453,13 +473,14 @@ class Booster:
                     # per-round transpose/layout-copy cost; see
                     # pallas_hist.host_transpose_bins) — single-chip
                     # pallas path only: sharded paths re-transpose and
-                    # the scatter fallback never reads it
+                    # the scatter fallback never reads it.  After the
+                    # put above, so that copy overlaps the transpose
                     from xgboost_tpu.ops.pallas_hist import \
                         host_transpose_bins
-                    bt = host_transpose_bins(binned_host,
-                                             self.gbtree.cfg.n_bin)
-                    entry.binned_t = None if bt is None \
-                        else jnp.asarray(bt)
+                    with span("ingest.bin", **bin_span):
+                        bt = host_transpose_bins(binned_host,
+                                                 self.gbtree.cfg.n_bin)
+                    entry.binned_t = None if bt is None else upload(bt)
                 self._cache[key] = entry
             self._attach_root(self._cache[key], dmat)
             self._cache[key].model_gen = self._model_gen
@@ -501,10 +522,9 @@ class Booster:
         r = np.zeros(n_dev, np.int32)
         r[:len(ri)] = np.asarray(ri, np.int64).astype(np.int32)
         if self._mesh is not None and not getattr(dmat, "is_sharded", False):
-            from xgboost_tpu.parallel.dp import shard_rows
-            entry.root = shard_rows(self._mesh, r)
+            entry.root = upload(r, self._shard_rows)
         else:
-            entry.root = jnp.asarray(r)
+            entry.root = upload(r)
 
     def _build_ext_entry(self, dmat) -> _CacheEntry:
         """Entry for an external-memory matrix (not necessarily cached)."""
@@ -535,7 +555,7 @@ class Booster:
             if self._mesh is not None:
                 return self._make_sharded_entry(dmat, binned_np=binned_np)
             return _CacheEntry(
-                dmat, jnp.asarray(binned_np),
+                dmat, upload(binned_np),
                 jnp.asarray(self._base_margin_of(dmat, dmat.num_row)))
         return _CacheEntry(
             dmat, None, np.asarray(self._base_margin_of(dmat, dmat.num_row)),
@@ -548,33 +568,41 @@ class Booster:
         reference's per-rank row-shard loading, simple_dmatrix-inl.hpp:89-96,
         realized as device placement under one controller).  ``binned_np``
         skips re-binning (in-budget external matrices pass their memmap)."""
-        from xgboost_tpu.parallel.dp import shard_rows
+        shard = self._shard_rows
         n = dmat.num_row
         pad = (-n) % self._mesh.size
         if binned_np is None:
-            binned_np = bin_matrix(dmat, self.gbtree.cuts)
-        if pad:
-            binned_np = np.pad(binned_np, ((0, pad), (0, 0)))
+            dmat._materialize()   # under ingest.dmatrix, not under bin
+        with span("ingest.bin", rows=n, features=dmat.num_col):
+            if binned_np is None:
+                binned_np = bin_matrix(dmat, self.gbtree.cuts)
+            if pad:
+                binned_np = np.pad(binned_np, ((0, pad), (0, 0)))
         # host numpy -> global sharding directly: in multi-process mode
         # every process holds the full (replicated) host copy and
         # device_put places only its addressable shards
-        binned = shard_rows(self._mesh, binned_np)
-        row_valid = shard_rows(self._mesh, np.arange(n + pad) < n)
+        binned = upload(binned_np, shard)
+        row_valid = upload(np.arange(n + pad) < n, shard)
         info = _pad_info(dmat.info, n, pad, self._K)
         # device-resident SHARDED gradient inputs (row-aligned with the
         # margin); also avoids re-uploading label/weight every round
         if info.label is not None:
-            info._dev_cache["label"] = shard_rows(
-                self._mesh, np.asarray(info.label, np.float32))
-        info._dev_cache[("weight", n + pad)] = shard_rows(
-            self._mesh, np.asarray(info.get_weight(n + pad), np.float32))
+            info._dev_cache["label"] = upload(
+                np.asarray(info.label, np.float32), shard)
+        info._dev_cache[("weight", n + pad)] = upload(
+            np.asarray(info.get_weight(n + pad), np.float32), shard)
         base = np.broadcast_to(
             np.asarray(self._base_margin_of(dmat, n)), (n, self._K))
         base = np.concatenate(
             [base, np.zeros((pad, self._K), np.float32)]) if pad else base
-        base = shard_rows(self._mesh, np.asarray(base, np.float32))
+        base = upload(np.asarray(base, np.float32), shard)
         return _CacheEntry(dmat, binned, base, info=info,
                            row_valid=row_valid, n_real=n)
+
+    def _shard_rows(self, host):
+        """Place a host array with its rows sharded over the mesh."""
+        from xgboost_tpu.parallel.dp import shard_rows
+        return shard_rows(self._mesh, host)
 
     def _make_shard_loaded_entry(self, dmat) -> _CacheEntry:
         """Entry for a per-rank split-loaded matrix: every process bins
@@ -594,8 +622,11 @@ class Booster:
                 "replicated for rank:*")
         n_loc = dmat.local_num_row
         K = self._K
-        binned_local = bin_matrix(dmat._local, self.gbtree.cuts)
-        binned = dmat.make_global(dmat.pad_local(binned_local))
+        dmat._local._materialize()
+        with span("ingest.bin", rows=n_loc, features=dmat.num_col):
+            binned_local = dmat.pad_local(
+                bin_matrix(dmat._local, self.gbtree.cuts))
+        binned = upload(binned_local, dmat.make_global)
         row_valid = dmat.row_valid_global()
 
         # the entry's info snapshot holds LOCAL host metadata (for label
@@ -606,11 +637,13 @@ class Booster:
         info.weight = dmat.info.weight
         info.base_margin = dmat.info.base_margin
         if info.label is not None:
-            info._dev_cache["label"] = dmat.make_global(
-                dmat.pad_local(np.asarray(info.label, np.float32)))
-        info._dev_cache[("weight", dmat.padded_global_rows)] = \
-            dmat.make_global(dmat.pad_local(
-                np.asarray(dmat.info.get_weight(n_loc), np.float32)))
+            info._dev_cache["label"] = upload(
+                dmat.pad_local(np.asarray(info.label, np.float32)),
+                dmat.make_global)
+        info._dev_cache[("weight", dmat.padded_global_rows)] = upload(
+            dmat.pad_local(
+                np.asarray(dmat.info.get_weight(n_loc), np.float32)),
+            dmat.make_global)
 
         if getattr(dmat, "_full_base_margin", None) is not None:
             # sidecar base_margin holds GLOBAL (N, K) values; slice rows
@@ -625,7 +658,7 @@ class Booster:
             base_local = np.full(
                 (n_loc, K), self.obj.prob_to_margin(self.param.base_score),
                 np.float32)
-        base = dmat.make_global(dmat.pad_local(base_local))
+        base = upload(dmat.pad_local(base_local), dmat.make_global)
         entry = _CacheEntry(dmat, binned, base, info=info,
                             row_valid=row_valid, n_real=dmat.global_num_row)
         return entry
@@ -728,25 +761,29 @@ class Booster:
         occupied = prep.pad_map >= 0                      # (n_slots,)
         src = prep.pad_map[occupied]
 
-        binned_host = bin_matrix(dmat, self.gbtree.cuts)
-        binned_pad = np.zeros((n_slots, binned_host.shape[1]),
-                              binned_host.dtype)
-        binned_pad[occupied] = binned_host[src]
+        dmat._materialize()       # under ingest.dmatrix, not under bin
+        bin_span = dict(rows=dmat.num_row, features=dmat.num_col)
+        with span("ingest.bin", **bin_span):
+            binned_host = bin_matrix(dmat, self.gbtree.cuts)
+            binned_pad = np.zeros((n_slots, binned_host.shape[1]),
+                                  binned_host.dtype)
+            binned_pad[occupied] = binned_host[src]
         base = np.asarray(self._base_margin_of(dmat, dmat.num_row))
         base_pad = np.full((n_slots, self._K),
                            float(base.reshape(-1)[0]) if base.size
                            else 0.0, np.float32)
         base_pad[occupied] = base.reshape(dmat.num_row, self._K)[src]
         entry = _CacheEntry(
-            dmat, jnp.asarray(binned_pad), jnp.asarray(base_pad),
-            row_valid=jnp.asarray(occupied), n_real=dmat.num_row)
+            dmat, upload(binned_pad), upload(base_pad),
+            row_valid=upload(occupied), n_real=dmat.num_row)
         entry.rank_pad_prep = prep
         from xgboost_tpu.ops.histogram import hist_backend
         if hist_backend(self.param.hist_precision
                         ).impl.startswith("pallas"):
             from xgboost_tpu.ops.pallas_hist import host_transpose_bins
-            bt = host_transpose_bins(binned_pad, self.gbtree.cfg.n_bin)
-            entry.binned_t = None if bt is None else jnp.asarray(bt)
+            with span("ingest.bin", **bin_span):
+                bt = host_transpose_bins(binned_pad, self.gbtree.cfg.n_bin)
+            entry.binned_t = None if bt is None else upload(bt)
         return entry
 
     def _raw_dense(self, dmat, pad_multiple: int = 1):
@@ -852,8 +889,8 @@ class Booster:
     def profiler(self):
         """Lazily created RoundProfiler when param profile>=1 (the
         report_stats analog, SURVEY.md §5.1) — or, at level 0, when the
-        observability layer is on (``obs_log=``/``metrics_port=``/
-        ``XGBTPU_OBS=1``): phase spans, the event-log timeline and the
+        observability layer is on (``obs_log=``/``metrics_port=``):
+        phase spans, the event-log timeline and the
         live training metrics all need the per-phase boundaries, which
         also means per-round host control (no fused multi-round launch)
         and a device barrier per phase — the same cost contract as
@@ -1071,7 +1108,7 @@ class Booster:
         if not fused_ok or k <= 0:
             if n_rounds > 1 and self.param.booster == "gbtree":
                 why = blockers or ["rounds_per_dispatch_0"]
-                from xgboost_tpu.obs import trace, training_metrics
+                from xgboost_tpu.obs import trace
                 training_metrics().fused_fallback.inc(why[0])
                 trace.event("train.fused_fallback", reasons=why,
                             first_iteration=first_iteration,
@@ -1132,6 +1169,7 @@ class Booster:
                     e.screen_key = screen
                 return e.screen_binned
         align = max(0, int(boundary_align))
+        tm = training_metrics()
         done = 0
         while done < n_rounds:
             first = first_iteration + done
@@ -1141,57 +1179,70 @@ class Booster:
                 # see the model at exactly that round (segment lengths
                 # stay O(distinct) -> bounded scan compiles)
                 seg = min(seg, align - first % align)
-            margin_f, emargins_f, eouts = self.gbtree.do_boost_fused(
-                _screened(entry) if screen is not None else entry.binned,
-                entry.margin, entry.info, fgrad(),
-                first, seg, row_valid=entry.row_valid, mesh=self._mesh,
-                binned_t=(None if screen is not None
-                          else getattr(entry, "binned_t", None)),
-                eval_binned=tuple(
-                    (_screened(e) if screen is not None else e.binned)
-                    for _, _, e, t in espec if not t),
-                eval_margins=tuple(e.margin for _, _, e, t in espec
-                                   if not t),
-                eval_is_train=tuple(t for _, _, _, t in espec),
-                etransform=etransform,
-                rowwise_grad=entry.rank_pad_prep is None,
-                feature_screen=screen)
-            entry.margin = margin_f
-            entry.applied = self.gbtree.num_trees
-            ei = 0
-            for _, _, e, is_train in espec:
-                if is_train:
-                    continue
-                e.margin = emargins_f[ei]
-                e.applied = self.gbtree.num_trees
-                ei += 1
-            if espec:
-                # eval lines for every round of the segment, from the
-                # ONE dispatch's stacked outputs
-                from xgboost_tpu.obs import training_metrics
-                for r in range(seg):
-                    parts = [f"[{first + r}]"]
-                    for si, (dmat, name, e, _) in enumerate(espec):
-                        if getattr(dmat, "is_sharded", False):
-                            # split-loaded set: metric partials on the
-                            # LOCAL shard of the round's transformed
-                            # outputs, reduced via allsum — no process
-                            # ever holds the full prediction vector
-                            local = dmat.local_block_of(eouts[si][r])
-                            self._eval_parts_sharded(
-                                dmat, name,
-                                local[:dmat.local_num_row], parts)
-                            continue
-                        tr = e.user_rows(np.asarray(self._replicated(
-                            eouts[si][r])))
-                        self._eval_parts(dmat, name, tr, parts, feval)
-                    msg = "\t".join(parts)
-                    training_metrics().observe_eval(_parse_eval(msg))
-                    if eval_callback is not None:
-                        eval_callback(first + r, msg)
+            with span("train.segment", first_round=first, n_rounds=seg):
+                margin_f, emargins_f, eouts = self.gbtree.do_boost_fused(
+                    _screened(entry) if screen is not None
+                    else entry.binned,
+                    entry.margin, entry.info, fgrad(),
+                    first, seg, row_valid=entry.row_valid,
+                    mesh=self._mesh,
+                    binned_t=(None if screen is not None
+                              else getattr(entry, "binned_t", None)),
+                    eval_binned=tuple(
+                        (_screened(e) if screen is not None else e.binned)
+                        for _, _, e, t in espec if not t),
+                    eval_margins=tuple(e.margin for _, _, e, t in espec
+                                       if not t),
+                    eval_is_train=tuple(t for _, _, _, t in espec),
+                    etransform=etransform,
+                    rowwise_grad=entry.rank_pad_prep is None,
+                    feature_screen=screen)
+                entry.margin = margin_f
+                entry.applied = self.gbtree.num_trees
+                ei = 0
+                for _, _, e, is_train in espec:
+                    if is_train:
+                        continue
+                    e.margin = emargins_f[ei]
+                    e.applied = self.gbtree.num_trees
+                    ei += 1
+                if espec:
+                    with span("train.eval", slots=len(espec),
+                              rows=sum(int(o.shape[1]) for o in eouts)):
+                        self._segment_eval_lines(espec, eouts, first, seg,
+                                                 feval, eval_callback)
+                # live progress on the fused path too (the per-round
+                # profiler, which used to be their only feeder, never
+                # runs here)
+                tm.rounds.inc(seg)
+                tm.round.set(first + seg - 1)
             done += seg
             if segment_callback is not None:
                 segment_callback(first + seg - 1)
+
+    def _segment_eval_lines(self, espec, eouts, first: int, seg: int,
+                            feval, eval_callback) -> None:
+        """Eval lines for every round of one fused segment, from the ONE
+        dispatch's stacked per-round outputs (device -> host here)."""
+        for r in range(seg):
+            parts = [f"[{first + r}]"]
+            for si, (dmat, name, e, _) in enumerate(espec):
+                if getattr(dmat, "is_sharded", False):
+                    # split-loaded set: metric partials on the LOCAL
+                    # shard of the round's transformed outputs, reduced
+                    # via allsum — no process ever holds the full
+                    # prediction vector
+                    local = dmat.local_block_of(eouts[si][r])
+                    self._eval_parts_sharded(
+                        dmat, name, local[:dmat.local_num_row], parts)
+                    continue
+                tr = e.user_rows(np.asarray(self._replicated(
+                    eouts[si][r])))
+                self._eval_parts(dmat, name, tr, parts, feval)
+            msg = "\t".join(parts)
+            training_metrics().observe_eval(_parse_eval(msg))
+            if eval_callback is not None:
+                eval_callback(first + r, msg)
 
     def fused_lane_spec(self, dtrain: DMatrix, first_iteration: int,
                         n_rounds: int, rounds_per_dispatch=None):
@@ -1761,7 +1812,6 @@ class Booster:
         # latest eval scores ride the training metrics as gauges
         # (xgbtpu_training_eval_score{key="train-error"}), scrapeable
         # mid-run via metrics_port= (OBSERVABILITY.md)
-        from xgboost_tpu.obs import training_metrics
         training_metrics().observe_eval(_parse_eval(msg))
         return msg
 
@@ -1878,14 +1928,12 @@ class Booster:
                 sys.stdout.buffer.write(payload)
                 sys.stdout.buffer.flush()
                 return
-        from xgboost_tpu.obs import span
         from xgboost_tpu.reliability.integrity import (add_footer,
                                                        atomic_write)
         with span("model.save", path=path, bytes=len(payload)):
             atomic_write(path, add_footer(payload))
 
     def load_model(self, path: str):
-        from xgboost_tpu.obs import span
         from xgboost_tpu.reliability.integrity import (read_file,
                                                        verify_model_bytes)
         with span("model.load", path=path):
@@ -1930,7 +1978,7 @@ class Booster:
             # unparseable npz: for a footer-less file this is the only
             # torn-write signal there is — type it so recovery paths
             # (checkpoint-ring fallback, registry poisoning) can react
-            from xgboost_tpu.profiling import reliability_metrics
+            from xgboost_tpu.obs import reliability_metrics
             reliability_metrics().integrity_failures.inc()
             raise ModelIntegrityError(
                 f"{path} is not an xgboost_tpu model file: {e}")

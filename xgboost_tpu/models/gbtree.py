@@ -121,6 +121,12 @@ def _scan_rounds_impl(binned, margin, label, weight, base_key,
     stacked trees (n_rounds, K*npar, ...),
     per-round transformed eval outputs (one (n_rounds, N_e, K) per
     watchlist slot))``.
+
+    The round's parts carry ``jax.named_scope`` names — ``round.gradient``,
+    ``round.margin``, ``round.eval`` here, ``grow.*`` in
+    :func:`~xgboost_tpu.models.tree.grow_tree` — which land in the
+    compiled module's ``op_name`` metadata and nowhere else: the names
+    are a contract with whoever reads a device trace (OBSERVABILITY.md).
     """
     T_pr = K * npar
     group_pr = jnp.asarray([j // npar for j in range(T_pr)], jnp.int32)
@@ -145,7 +151,8 @@ def _scan_rounds_impl(binned, margin, label, weight, base_key,
     def body(carry, i):
         margin, emargins = carry
         key = jax.random.fold_in(base_key, i)
-        gh = grad_fn(margin, label, weight, i)           # (N, K, 2)
+        with jax.named_scope("round.gradient"):
+            gh = grad_fn(margin, label, weight, i)       # (N, K, 2)
         if T_pr > 1:
             # ensemble axis vmapped: the batched shared-onehot histogram
             # kernel + broadcast-compare lookups make this the fast path
@@ -156,28 +163,31 @@ def _scan_rounds_impl(binned, margin, label, weight, base_key,
                 [j // npar for j in range(T_pr)], jnp.int32),
                 axis=1).transpose(1, 0, 2)               # (T, N, 2)
             stacked, ds = jax.vmap(grow_one)(tkeys, gh_t)
-            delta = jnp.zeros_like(margin)
-            for j in range(T_pr):
-                delta = delta.at[:, j // npar].add(ds[j])
-            margin = margin + delta
+            with jax.named_scope("round.margin"):
+                delta = jnp.zeros_like(margin)
+                for j in range(T_pr):
+                    delta = delta.at[:, j // npar].add(ds[j])
+                margin = margin + delta
         else:
             tree, d = grow_one(jax.random.fold_in(key, 0), gh[:, 0, :])
             stacked = jax.tree.map(lambda x: x[None], tree)
-            margin = margin + d[:, None]
+            with jax.named_scope("round.margin"):
+                margin = margin + d[:, None]
         eouts, new_em = [], []
         ei = 0
-        for is_train in eval_is_train:
-            if is_train:
-                eouts.append(etransform(margin))
-                continue
-            em = (predict_margin_binned(
-                stacked, group_pr, eval_binned[ei],
-                jnp.zeros((), jnp.float32), cfg.max_depth, K,
-                root=None, n_roots=cfg.n_roots,
-                tree_chunk=pred_chunk) + emargins[ei])
-            new_em.append(em)
-            eouts.append(etransform(em))
-            ei += 1
+        with jax.named_scope("round.eval"):
+            for is_train in eval_is_train:
+                if is_train:
+                    eouts.append(etransform(margin))
+                    continue
+                em = (predict_margin_binned(
+                    stacked, group_pr, eval_binned[ei],
+                    jnp.zeros((), jnp.float32), cfg.max_depth, K,
+                    root=None, n_roots=cfg.n_roots,
+                    tree_chunk=pred_chunk) + emargins[ei])
+                new_em.append(em)
+                eouts.append(etransform(em))
+                ei += 1
         return (margin, tuple(new_em)), (stacked, tuple(eouts))
 
     iters = first_iteration + jnp.arange(n_rounds)
@@ -821,8 +831,6 @@ class GBTree:
         """
         K = max(1, self.param.num_output_group)
         npar = max(1, self.param.num_parallel_tree)
-        label = info.label_dev()
-        weight = info.weight_dev(margin.shape[0])
         if donate is None:
             env = os.environ.get("XGBTPU_FUSED_DONATE")
             if env not in (None, ""):
@@ -845,70 +853,79 @@ class GBTree:
         # compute and belongs to xgbtpu_train_dispatch_seconds alone.
         from xgboost_tpu.obs import span, training_metrics
         from xgboost_tpu.parallel import mock
-        cut_vals, cut_ns = self.cut_values_dev, self.n_cuts_dev
-        kept_dev = None
-        if feature_screen is not None:
-            kept = tuple(int(i) for i in feature_screen)
-            cache = self._screen_cut_cache
-            if cache is None or cache[0] != kept:
-                kidx = jnp.asarray(kept, jnp.int32)
-                cache = (kept, jnp.take(self.cut_values_dev, kidx, axis=0),
-                         jnp.take(self.n_cuts_dev, kidx), kidx)
-                self._screen_cut_cache = cache
-            _, cut_vals, cut_ns, kept_dev = cache
-        comm_nbytes = self._comm_bytes(binned.shape[1], mesh)
-        for r in range(n_rounds):
-            mock.begin_round(first_iteration + r)
-            for _ in range(K * npar):
-                if mesh_scan:
-                    mock.collective("psum", nbytes=comm_nbytes,
-                                    count=self.cfg.max_depth)
-                else:
-                    mock.collective(nbytes=comm_nbytes)
-        if mesh_scan:
-            scan = _scan_rounds_mesh_donated if donate \
-                else _scan_rounds_mesh
-        else:
-            scan = _scan_rounds_donated if donate else _scan_rounds
         with span("train.dispatch", first_round=first_iteration,
                   n_rounds=n_rounds, donated=bool(donate),
-                  mesh_fused=bool(mesh_scan)):
-            _t_launch = time.perf_counter()
-            margin_f, emargins_f, stacks, eouts = scan(
-                binned, margin, label, weight,
-                self.base_key(),
-                jnp.int32(first_iteration), cut_vals,
-                cut_ns, row_valid, binned_t,
-                tuple(eval_binned), tuple(eval_margins),
-                n_rounds=n_rounds, K=K, npar=npar, cfg=self.cfg,
-                split_finder=self._split_finder(), grad_fn=grad_fn,
-                mesh=mesh, eval_is_train=tuple(eval_is_train),
-                etransform=etransform, pred_chunk=self.pred_chunk)
-            # block at the segment boundary: the driver pulls eval lines
-            # / checkpoint bytes from this dispatch next, and the
-            # histogram must record device wall time, not async dispatch
-            jax.block_until_ready(margin_f)
-            _dt = time.perf_counter() - _t_launch
+                  mesh_fused=bool(mesh_scan)) as dispatch:
+            with span("train.launch"):
+                label = info.label_dev()
+                weight = info.weight_dev(margin.shape[0])
+                cut_vals, cut_ns = self.cut_values_dev, self.n_cuts_dev
+                kept_dev = None
+                if feature_screen is not None:
+                    kept = tuple(int(i) for i in feature_screen)
+                    cache = self._screen_cut_cache
+                    if cache is None or cache[0] != kept:
+                        kidx = jnp.asarray(kept, jnp.int32)
+                        cache = (kept,
+                                 jnp.take(self.cut_values_dev, kidx,
+                                          axis=0),
+                                 jnp.take(self.n_cuts_dev, kidx), kidx)
+                        self._screen_cut_cache = cache
+                    _, cut_vals, cut_ns, kept_dev = cache
+                comm_nbytes = self._comm_bytes(binned.shape[1], mesh)
+                for r in range(n_rounds):
+                    mock.begin_round(first_iteration + r)
+                    for _ in range(K * npar):
+                        if mesh_scan:
+                            mock.collective("psum", nbytes=comm_nbytes,
+                                            count=self.cfg.max_depth)
+                        else:
+                            mock.collective(nbytes=comm_nbytes)
+                if mesh_scan:
+                    scan = _scan_rounds_mesh_donated if donate \
+                        else _scan_rounds_mesh
+                else:
+                    scan = _scan_rounds_donated if donate \
+                        else _scan_rounds
+                margin_f, emargins_f, stacks, eouts = scan(
+                    binned, margin, label, weight,
+                    self.base_key(),
+                    jnp.int32(first_iteration), cut_vals,
+                    cut_ns, row_valid, binned_t,
+                    tuple(eval_binned), tuple(eval_margins),
+                    n_rounds=n_rounds, K=K, npar=npar, cfg=self.cfg,
+                    split_finder=self._split_finder(), grad_fn=grad_fn,
+                    mesh=mesh, eval_is_train=tuple(eval_is_train),
+                    etransform=etransform, pred_chunk=self.pred_chunk)
+            with span("train.wait"):
+                # block at the segment boundary: the driver pulls eval
+                # lines / checkpoint bytes from this dispatch next, and
+                # the histogram must record device wall time, not async
+                # dispatch
+                jax.block_until_ready(margin_f)
         tm = training_metrics()
-        tm.dispatch_seconds.observe(_dt)
+        tm.dispatch_seconds.observe(dispatch.seconds)
         tm.rounds_per_dispatch.set(float(n_rounds))
-        # flatten (n_rounds, K*npar, ...) -> (T_new, ...) and install the
-        # full-ensemble stack cache directly: prediction then reuses the
-        # scan's own output instead of re-stacking T per-tree slices
-        flat = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]),
-                            stacks)
-        if kept_dev is not None:
-            # grown trees speak the SCREENED feature space; remap split
-            # ids back to the full space before anything concatenates,
-            # persists or predicts (thresholds/cut indices already match
-            # the full space: screened rows are whole full-space rows)
-            f = flat.feature
-            flat = flat._replace(feature=jnp.where(
-                f >= 0,
-                jnp.take(kept_dev,
-                         jnp.clip(f, 0, kept_dev.shape[0] - 1)),
-                f))
-        self._append_flat_trees(flat, n_rounds)
+        with span("train.absorb"):
+            # flatten (n_rounds, K*npar, ...) -> (T_new, ...) and install
+            # the full-ensemble stack cache directly: prediction then
+            # reuses the scan's own output instead of re-stacking T
+            # per-tree slices
+            flat = jax.tree.map(
+                lambda x: x.reshape((-1,) + x.shape[2:]), stacks)
+            if kept_dev is not None:
+                # grown trees speak the SCREENED feature space; remap
+                # split ids back to the full space before anything
+                # concatenates, persists or predicts (thresholds/cut
+                # indices already match the full space: screened rows
+                # are whole full-space rows)
+                f = flat.feature
+                flat = flat._replace(feature=jnp.where(
+                    f >= 0,
+                    jnp.take(kept_dev,
+                             jnp.clip(f, 0, kept_dev.shape[0] - 1)),
+                    f))
+            self._append_flat_trees(flat, n_rounds)
         return margin_f, emargins_f, eouts
 
     def _append_flat_trees(self, flat, n_rounds: int) -> None:

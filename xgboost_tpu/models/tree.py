@@ -344,8 +344,9 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
     # pre-transposed u8 operand: zero per-round transpose AND none of
     # the per-pallas-call layout copies an in-graph transpose incurs
     from xgboost_tpu.ops.histogram import prepare_hist
-    hist_prep = prepare_hist(binned, gh_used, cfg.n_bin,
-                             cfg.hist_precision, binned_t=binned_t)
+    with jax.named_scope("grow.operand"):
+        hist_prep = prepare_hist(binned, gh_used, cfg.n_bin,
+                                 cfg.hist_precision, binned_t=binned_t)
     # kernel-NATIVE histogram layout (F, B, 2, n_node): the split
     # finder consumes the kernel's own output order, skipping the
     # per-level relayout transpose (~0.47 ms/round at 1M x 28 —
@@ -354,113 +355,128 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
     use_native = (default_finder and hist_prep is not None
                   and not cfg.hist_subtraction)
 
+    from xgboost_tpu.ops.histogram import stats_from_histogram_native
     for depth in range(d0, d0 + D + 1):
         n_node = 1 << depth
         base = n_node - 1  # global index of first node at this level
+        terminal = depth == d0 + D
+        native = use_native and n_node <= 64
 
-        if depth == d0 + D:
-            # terminal level: everything still active becomes a leaf.
-            # Node stats DERIVE from the parent's chosen split (left
-            # child = winner's left sums, right = parent - left) when
-            # the finder provides them — a full node_stats pass over
-            # the rows costs ~4.4 ms at 1M rows (v5e, round 3)
-            if prev is not None and prev[0].left_g is not None:
-                p_best, p_nst, p_split = prev
-                gl = jnp.where(p_split, p_best.left_g, 0.0)
-                hl = jnp.where(p_split, p_best.left_h, 0.0)
-                gr = jnp.where(p_split, p_nst[:, 0] - p_best.left_g, 0.0)
-                hr = jnp.where(p_split, p_nst[:, 1] - p_best.left_h, 0.0)
-                nst = jnp.stack(
-                    [jnp.stack([gl, gr], 1).reshape(-1),
-                     jnp.stack([hl, hr], 1).reshape(-1)], axis=1)
-            else:
-                nst = dequantize_hist(red(node_stats(
-                    gh_used, pos, n_node,
-                    cfg.hist_precision)))  # (n_node, 2)
-            make_leaf = jnp.ones(n_node, jnp.bool_)
-            best = None
-        else:
-            native = use_native and n_node <= 64
-            if cfg.hist_subtraction and hist_prev is not None:
-                hist = _subtracted_level_hist(binned, gh_used, pos,
-                                              n_node, cfg, red, hist_prev,
-                                              prev[2])
-            else:
-                hist = dequantize_hist(
-                    red(build_level_histogram(binned, gh_used, pos,
-                                              n_node, cfg.n_bin,
-                                              cfg.hist_precision,
-                                              prep=hist_prep,
-                                              native=native)))
+        if not terminal:
+            with jax.named_scope("grow.hist"):
+                if cfg.hist_subtraction and hist_prev is not None:
+                    hist = _subtracted_level_hist(binned, gh_used, pos,
+                                                  n_node, cfg, red,
+                                                  hist_prev, prev[2])
+                else:
+                    hist = dequantize_hist(
+                        red(build_level_histogram(binned, gh_used, pos,
+                                                  n_node, cfg.n_bin,
+                                                  cfg.hist_precision,
+                                                  prep=hist_prep,
+                                                  native=native)))
             hist_prev = hist if cfg.hist_subtraction else None
-            # node totals fall out of the histogram (bin sums of any one
-            # feature) — saves a per-level pass over all rows
-            from xgboost_tpu.ops.histogram import stats_from_histogram_native
-            nst = (stats_from_histogram_native(hist) if native
-                   else stats_from_histogram(hist))
-            fmask = feat_mask_tree
-            if cfg.colsample_bylevel < 1.0:
-                fmask = fmask & feat_sampler(
-                    jax.random.fold_in(key_flevel, depth),
-                    cfg.colsample_bylevel, binned)
-            if native:
-                from xgboost_tpu.ops.split import find_best_splits_native
-                best = _wrap_best(
-                    find_best_splits_native(hist, nst, n_cuts,
-                                            cfg.split, fmask),
-                    cut_values)
-            else:
-                best = split_finder(hist, nst, n_cuts, cut_values, fmask,
-                                    cfg.split)
-            # cannot_split (param.h:174): too little hessian mass to split
-            can_try = nst[:, 1] >= 2.0 * cfg.split.min_child_weight
-            do_split = best.valid & can_try
-            make_leaf = ~do_split
-            prev = (best, nst, do_split)
 
-        tree = apply_level(tree, depth, nst, best, make_leaf, cfg.split)
-        # the level's would-be leaf weights (same expression apply_level
-        # writes — CSE'd, bitwise identical): parked rows record their
-        # value here instead of a post-growth leaf_value[row_leaf] pass
-        leaf_w = calc_weight(nst[:, 0], nst[:, 1], cfg.split) \
-            * cfg.split.eta
+        with jax.named_scope("grow.split"):
+            if terminal:
+                # terminal level: everything still active becomes a
+                # leaf.  Node stats DERIVE from the parent's chosen
+                # split (left child = winner's left sums, right =
+                # parent - left) when the finder provides them — a full
+                # node_stats pass over the rows costs ~4.4 ms at 1M rows
+                # (v5e, round 3)
+                if prev is not None and prev[0].left_g is not None:
+                    p_best, p_nst, p_split = prev
+                    gl = jnp.where(p_split, p_best.left_g, 0.0)
+                    hl = jnp.where(p_split, p_best.left_h, 0.0)
+                    gr = jnp.where(p_split,
+                                   p_nst[:, 0] - p_best.left_g, 0.0)
+                    hr = jnp.where(p_split,
+                                   p_nst[:, 1] - p_best.left_h, 0.0)
+                    nst = jnp.stack(
+                        [jnp.stack([gl, gr], 1).reshape(-1),
+                         jnp.stack([hl, hr], 1).reshape(-1)], axis=1)
+                else:
+                    nst = dequantize_hist(red(node_stats(
+                        gh_used, pos, n_node,
+                        cfg.hist_precision)))  # (n_node, 2)
+                make_leaf = jnp.ones(n_node, jnp.bool_)
+                best = None
+            else:
+                # node totals fall out of the histogram (bin sums of any
+                # one feature) — saves a per-level pass over all rows
+                nst = (stats_from_histogram_native(hist) if native
+                       else stats_from_histogram(hist))
+                fmask = feat_mask_tree
+                if cfg.colsample_bylevel < 1.0:
+                    fmask = fmask & feat_sampler(
+                        jax.random.fold_in(key_flevel, depth),
+                        cfg.colsample_bylevel, binned)
+                if native:
+                    from xgboost_tpu.ops.split import \
+                        find_best_splits_native
+                    best = _wrap_best(
+                        find_best_splits_native(hist, nst, n_cuts,
+                                                cfg.split, fmask),
+                        cut_values)
+                else:
+                    best = split_finder(hist, nst, n_cuts, cut_values,
+                                        fmask, cfg.split)
+                # cannot_split (param.h:174): too little hessian mass
+                can_try = nst[:, 1] >= 2.0 * cfg.split.min_child_weight
+                do_split = best.valid & can_try
+                make_leaf = ~do_split
+                prev = (best, nst, do_split)
+
+            tree = apply_level(tree, depth, nst, best, make_leaf,
+                               cfg.split)
+            # the level's would-be leaf weights (same expression
+            # apply_level writes — CSE'd, bitwise identical): parked rows
+            # record their value here instead of a post-growth
+            # leaf_value[row_leaf] pass
+            leaf_w = calc_weight(nst[:, 0], nst[:, 1], cfg.split) \
+                * cfg.split.eta
 
         # park rows whose node became a leaf; route the rest to children
-        active = pos >= 0
-        node_of_row = jnp.clip(pos, 0, n_node - 1)
-        if best is None:
-            # terminal level: make_leaf is constant-true — no lookup
-            row_is_leaf = active
-            val_row = table_lookup(leaf_w, node_of_row)
-        elif router is _default_router and n_node <= 1024:
-            # ONE (N, n_node) one-hot compare serves all five per-node
-            # channels (routing feature/cut/default + park flag + leaf
-            # value): XLA multi-output-fuses the masked sums over the
-            # shared compare, replacing 4 separate lookup fusions
-            ids = jnp.arange(n_node, dtype=jnp.int32)
-            sel = node_of_row[:, None] == ids             # (N, M)
+        with jax.named_scope("grow.route"):
+            active = pos >= 0
+            node_of_row = jnp.clip(pos, 0, n_node - 1)
+            if best is None:
+                # terminal level: make_leaf is constant-true — no lookup
+                row_is_leaf = active
+                val_row = table_lookup(leaf_w, node_of_row)
+            elif router is _default_router and n_node <= 1024:
+                # ONE (N, n_node) one-hot compare serves all five
+                # per-node channels (routing feature/cut/default + park
+                # flag + leaf value): XLA multi-output-fuses the masked
+                # sums over the shared compare, replacing 4 separate
+                # lookup fusions
+                ids = jnp.arange(n_node, dtype=jnp.int32)
+                sel = node_of_row[:, None] == ids             # (N, M)
 
-            def pick(v):
-                return jnp.where(sel, v[None, :], 0.0).sum(axis=1)
-            f_row = pick(best.feature.astype(jnp.float32)
-                         ).astype(jnp.int32)
-            j1_row = pick(best.cut_index.astype(jnp.float32) + 1.0)
-            dl_row = pick(best.default_left.astype(jnp.float32)) != 0.0
-            leaf_row = pick(make_leaf.astype(jnp.float32)) != 0.0
-            val_row = pick(leaf_w)
-            row_is_leaf = active & leaf_row
-            b = bin_of_feature(binned, f_row)
-            go_left = jnp.where(b == 0, dl_row,
-                                b.astype(jnp.float32) <= j1_row)
-        else:
-            row_is_leaf = active & table_lookup(make_leaf, node_of_row)
-            val_row = table_lookup(leaf_w, node_of_row)
-            go_left = router(best, node_of_row, binned)
-        row_leaf = jnp.where(row_is_leaf, base + pos, row_leaf)
-        row_val = jnp.where(row_is_leaf, val_row, row_val)
-        if best is not None:
-            new_pos = 2 * pos + (~go_left).astype(jnp.int32)
-            pos = jnp.where(active & ~row_is_leaf, new_pos, -1)
+                def pick(v):
+                    return jnp.where(sel, v[None, :], 0.0).sum(axis=1)
+                f_row = pick(best.feature.astype(jnp.float32)
+                             ).astype(jnp.int32)
+                j1_row = pick(best.cut_index.astype(jnp.float32) + 1.0)
+                dl_row = pick(
+                    best.default_left.astype(jnp.float32)) != 0.0
+                leaf_row = pick(make_leaf.astype(jnp.float32)) != 0.0
+                val_row = pick(leaf_w)
+                row_is_leaf = active & leaf_row
+                b = bin_of_feature(binned, f_row)
+                go_left = jnp.where(b == 0, dl_row,
+                                    b.astype(jnp.float32) <= j1_row)
+            else:
+                row_is_leaf = active & table_lookup(make_leaf,
+                                                    node_of_row)
+                val_row = table_lookup(leaf_w, node_of_row)
+                go_left = router(best, node_of_row, binned)
+            row_leaf = jnp.where(row_is_leaf, base + pos, row_leaf)
+            row_val = jnp.where(row_is_leaf, val_row, row_val)
+            if best is not None:
+                new_pos = 2 * pos + (~go_left).astype(jnp.int32)
+                pos = jnp.where(active & ~row_is_leaf, new_pos, -1)
 
     return tree, row_leaf, row_val
 

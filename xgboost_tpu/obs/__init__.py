@@ -4,9 +4,11 @@ Four pieces, one package:
 
 - **tracing spans** (:mod:`~xgboost_tpu.obs.trace`): ``span(name,
   **attrs)`` with thread-local parent linkage and per-request /
-  per-round trace ids, wired through the learner's round phases, the
-  serving path (``X-Request-Id`` in -> batcher -> engine -> response
-  header out) and checkpoint save/load;
+  per-round trace ids, wired through ingest (``ingest.*``), the fused
+  segment driver (``train.*``), the serving path (``X-Request-Id`` in
+  -> batcher -> engine -> response header out) and checkpoint
+  save/load; each also feeds the always-on span totals and, under a
+  JAX profiler session, a ``TraceAnnotation`` on the device's clock;
 - **structured event log** (:mod:`~xgboost_tpu.obs.events`): spans and
   discrete events (reload, drain, integrity failure, fault injection)
   append to a crash-safe JSONL file (``obs_log=`` / ``XGBTPU_OBS_LOG``)
@@ -22,8 +24,11 @@ Four pieces, one package:
   accounts each allreduce/allgather per round and per rank — the
   reference's ``report_stats`` (``allreduce_mock.h:52-56,87-95``).
 
-``xgboost_tpu.profiling`` remains as a compatibility shim re-exporting
-the metric primitives and :class:`RoundProfiler` from here.
+Cost contract (OBSERVABILITY.md): spans, their always-on totals and
+their profiler annotations cost microseconds and change nothing the
+program does; the per-round :class:`RoundProfiler` (``profile>=1``,
+``obs_log=``, ``metrics_port=``) puts a device barrier at each phase
+and keeps the round loop on the host.
 """
 
 from xgboost_tpu.obs import comm  # noqa: F401
@@ -38,7 +43,7 @@ from xgboost_tpu.obs.metrics import (Counter, Gauge,  # noqa: F401
                                      TrainingMetrics, lane_metrics,
                                      pipeline_metrics,
                                      predict_metrics, registry,
-                                     reliability_metrics,
+                                     reliability_metrics, span_totals,
                                      training_metrics)
 from xgboost_tpu.obs.profiler import RoundProfiler  # noqa: F401
 from xgboost_tpu.obs.server import (get_metrics_server,  # noqa: F401
@@ -50,10 +55,10 @@ from xgboost_tpu.obs.trace import (current_trace_id, event,  # noqa: F401
 
 def phases_enabled() -> bool:
     """True when round-phase instrumentation should run even without
-    ``profile>=1``: the event log is configured, the metrics server is
-    up, or ``XGBTPU_OBS=1``.  Phase timing forces device barriers at
-    phase boundaries (and keeps the round loop on the host), so it is
-    opt-in — the same cost contract as ``profile=1``.
+    ``profile>=1``: the event log is configured or the metrics server
+    is up.  Phase timing forces device barriers at phase boundaries
+    (and keeps the round loop on the host), so it is opt-in — the same
+    cost contract as ``profile=1``.
 
     ``XGBTPU_OBS_PHASES=0`` keeps a configured event log / metrics
     server WITHOUT the phase barriers: discrete events and dispatch
@@ -64,9 +69,7 @@ def phases_enabled() -> bool:
     import os
     if os.environ.get("XGBTPU_OBS_PHASES", "") == "0":
         return False
-    if get_log() is not None or get_metrics_server() is not None:
-        return True
-    return os.environ.get("XGBTPU_OBS", "") not in ("", "0")
+    return get_log() is not None or get_metrics_server() is not None
 
 
 __all__ = [
@@ -78,7 +81,7 @@ __all__ = [
     "PredictMetrics", "predict_metrics",
     "PipelineMetrics", "pipeline_metrics",
     "LaneMetrics", "lane_metrics",
-    "reliability_metrics", "training_metrics",
+    "reliability_metrics", "training_metrics", "span_totals",
     "RoundProfiler",
     "start_metrics_server", "get_metrics_server", "stop_metrics_server",
     "phases_enabled",

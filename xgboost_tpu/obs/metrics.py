@@ -12,8 +12,9 @@ are active.  The reference's analog is ``report_stats``
 (``subtree/rabit/src/allreduce_mock.h:52-56,87-95``): one place that
 accounts for allreduce time and checkpoint cost per version.
 
-``xgboost_tpu.profiling`` re-exports everything here for backward
-compatibility.
+The always-on totals of every :func:`~xgboost_tpu.obs.trace.span`
+(:class:`SpanTotals`) live here too: what an operator scrapes, and what
+the benchmark reads for host work done before any profiler starts.
 """
 
 from __future__ import annotations
@@ -338,6 +339,50 @@ def swallowed_error(site: str, exc: Optional[BaseException] = None,
         pass
 
 
+# ------------------------------------------------------------ span totals
+class SpanTotals:
+    """Seconds and exits of every ``obs.span`` by name, ALWAYS on: no
+    log, profiler or switch is needed, so these read the path a dark
+    run takes (``xgbtpu_span_seconds_total{span}``,
+    ``xgbtpu_span_total{span}``).  Durations are inclusive: a parent's
+    seconds hold its children's."""
+
+    def __init__(self):
+        self.seconds = LabeledCounter(
+            "xgbtpu_span_seconds_total", "span",
+            "cumulative wall seconds inside obs.span(name), children "
+            "included")
+        self.count = LabeledCounter(
+            "xgbtpu_span_total", "span", "obs.span(name) exits")
+        self._lock = threading.Lock()
+        registry().register("spans", self.render)
+
+    def observe(self, name: str, seconds: float) -> None:
+        # every span exit in the process comes through here: one lock
+        # for both families, not one each (this is their only writer)
+        secs, exits = self.seconds._v, self.count._v
+        with self._lock:
+            secs[name] = secs.get(name, 0.0) + seconds
+            exits[name] = exits.get(name, 0.0) + 1.0
+
+    def render(self) -> str:
+        return self.seconds.render() + self.count.render()
+
+
+_SPANS: Optional[SpanTotals] = None
+_SPANS_LOCK = threading.Lock()
+
+
+def span_totals() -> SpanTotals:
+    """The process-wide SpanTotals singleton."""
+    global _SPANS
+    if _SPANS is None:
+        with _SPANS_LOCK:
+            if _SPANS is None:
+                _SPANS = SpanTotals()
+    return _SPANS
+
+
 # ------------------------------------------------------------- reliability
 class ReliabilityMetrics:
     """Process-wide failure-path accounting (RELIABILITY.md): how often
@@ -445,7 +490,8 @@ class TrainingMetrics:
     of a long run, scrapeable mid-run via the ``metrics_port=`` daemon
     (obs/server.py).  One instance per process
     (:func:`training_metrics`), fed by the round profiler
-    (obs/profiler.py), the eval path, and the CLI checkpoint loop."""
+    (obs/profiler.py), the fused segment driver (learner.update_many),
+    the eval path, and the CLI checkpoint loop."""
 
     def __init__(self, prefix: str = "xgbtpu_training"):
         p = prefix

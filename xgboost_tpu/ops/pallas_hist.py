@@ -27,6 +27,16 @@ tile's output block accumulates across row tiles in VMEM.
 
 The XLA scatter in :mod:`xgboost_tpu.ops.histogram` remains the portable
 fallback (CPU mesh tests, interpret-free debugging).
+
+Kernel names (each ``pallas_call`` passes ``name=``, which becomes the
+HLO instruction name ``%<name>.<n>`` a device trace shows; a contract,
+OBSERVABILITY.md): ``hist_level_rows`` — one tree, one dataset
+(:func:`_hist_pallas_pre`, the training scan's kernel);
+``hist_level_lanes`` — tenant lanes stacked along the row grid
+(:func:`_hist_pallas_lanes_pre`); ``hist_level_trees`` — an ensemble
+axis sharing one one-hot (:func:`_hist_pallas_batched_pre`);
+``hist_level_node_stats`` — per-node (G, H) sums without bins
+(:func:`node_stats_pallas`).
 """
 
 from __future__ import annotations
@@ -261,6 +271,7 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
         out_shape=jax.ShapeDtypeStruct((n_m_tiles, f_pad * n_bin, 2 * m_pad),
                                        out_dtype),
         interpret=interpret,
+        name="hist_level_rows",
     )(binned_t, pos_t, gh_t)
 
     if native:
@@ -329,6 +340,7 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
         out_shape=jax.ShapeDtypeStruct(
             (L * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
         interpret=interpret,
+        name="hist_level_lanes",
     )(bt, pos_t, gh_t)
 
     out = out.reshape(L, n_m_tiles, f_pad, n_bin, 2, m_pad)
@@ -588,6 +600,7 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
         out_shape=jax.ShapeDtypeStruct(
             (n_m_tiles, t_tiles, f_pad * n_bin, lanes), out_dtype),
         interpret=interpret,
+        name="hist_level_trees",
     )(binned_t, pos_t,
       gh_flat if precision == "int8" else gh_flat.astype(jnp.float32))
 
@@ -659,5 +672,6 @@ def node_stats_pallas(gh: jax.Array, pos: jax.Array, n_node: int,
         out_specs=pl.BlockSpec((8, 2 * n_node), lambda ri: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, 2 * n_node), jnp.float32),
         interpret=interpret,
+        name="hist_level_node_stats",
     )(pos.reshape(-1, 1).astype(jnp.int32), gh.astype(jnp.float32))
     return out[0].reshape(2, n_node).T  # (n_node, 2)

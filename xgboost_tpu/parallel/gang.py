@@ -195,7 +195,7 @@ def on_round(version: int) -> bool:
     if status == "fence":
         _fenced = True
         from xgboost_tpu.obs import trace
-        from xgboost_tpu.profiling import reliability_metrics
+        from xgboost_tpu.obs import reliability_metrics
         reliability_metrics().launch_fences.inc()
         trace.event("gang.fence", rank=rank, trial=trial,
                     version=version, partition_sec=partition_sec)

@@ -387,7 +387,7 @@ def launch_local(n: int, cmd: List[str], keepalive: bool = False,
     """
     from xgboost_tpu.obs import event
     from xgboost_tpu.parallel import gang as gangmod
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     from xgboost_tpu.reliability.deadline import backoff_delay
 
     rm = reliability_metrics()

@@ -240,7 +240,7 @@ def _take(kinds, path: Optional[str], seam: str = "") -> List[_Fault]:
                 out.append(f)
     if out:
         from xgboost_tpu.obs import event
-        from xgboost_tpu.profiling import reliability_metrics
+        from xgboost_tpu.obs import reliability_metrics
         reliability_metrics().faults_injected.inc(len(out))
         for f in out:
             # each fired fault lands in the event-log timeline (fault

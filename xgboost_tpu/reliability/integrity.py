@@ -113,7 +113,7 @@ def verify_model_bytes(raw: bytes, name: str = "<buffer>",
 def _count_integrity_failure(name: str = "<buffer>",
                              reason: str = "") -> None:
     from xgboost_tpu.obs import event
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     reliability_metrics().integrity_failures.inc()
     event("integrity.failure", file=name, reason=reason)
 
@@ -205,7 +205,7 @@ def quarantine(path: Union[str, os.PathLike]) -> str:
     finally:
         os.close(dfd)
     from xgboost_tpu.obs import event
-    from xgboost_tpu.profiling import reliability_metrics
+    from xgboost_tpu.obs import reliability_metrics
     reliability_metrics().quarantines.inc()
     event("integrity.quarantine", file=path, quarantined_as=dest)
     return dest

@@ -275,7 +275,7 @@ class MicroBatcher:
         expired = [r for r in batch if not r.abandoned
                    and r.deadline is not None and r.deadline.expired()]
         if expired:
-            from xgboost_tpu.profiling import reliability_metrics
+            from xgboost_tpu.obs import reliability_metrics
             from xgboost_tpu.reliability.deadline import DeadlineExceeded
             reliability_metrics().deadline_dropped.inc(len(expired))
             for r in expired:
@@ -288,7 +288,7 @@ class MicroBatcher:
         # result nobody is waiting on
         live = [r for r in batch if not r.abandoned]
         if len(live) < len(batch):
-            from xgboost_tpu.profiling import reliability_metrics
+            from xgboost_tpu.obs import reliability_metrics
             reliability_metrics().shed_requests.inc(
                 len(batch) - len(live) - len(expired))
             for r in batch:

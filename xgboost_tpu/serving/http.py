@@ -401,7 +401,7 @@ class _Handler(BaseHTTPRequestHandler):
         any parsing/device cost is spent on it (admission by deadline,
         RELIABILITY.md stall matrix).  Counter-backed so 'rejected
         early ≫ completed late' is assertable from /metrics."""
-        from xgboost_tpu.profiling import reliability_metrics
+        from xgboost_tpu.obs import reliability_metrics
         reliability_metrics().deadline_rejected.inc()
         if sp is not None:
             sp.set("status", 504)
@@ -930,7 +930,7 @@ class PredictServer:
         """Stop admitting predictions, wait (bounded by ``grace``) for
         in-flight ones to finish, then shut down.  Returns the drain
         duration in seconds (also on the ``drain_seconds`` gauge)."""
-        from xgboost_tpu.profiling import reliability_metrics
+        from xgboost_tpu.obs import reliability_metrics
         grace = self.drain_grace if grace is None else float(grace)
         t0 = time.perf_counter()
         deadline = t0 + grace
@@ -1065,7 +1065,7 @@ def run_server(model_path: str = "", host: str = "127.0.0.1",
     With ``block=False`` the server runs on a background thread and the
     :class:`PredictServer` is returned (tests, embedding)."""
     from xgboost_tpu.catalog import ModelCatalog, parse_manifest
-    from xgboost_tpu.profiling import ServingMetrics
+    from xgboost_tpu.obs import ServingMetrics
     metrics = ServingMetrics()
     manifest = parse_manifest(catalog) if catalog else {}
     default_name = catalog_default or ("default" if model_path

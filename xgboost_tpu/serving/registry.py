@@ -280,7 +280,7 @@ class ModelRegistry:
 
     @staticmethod
     def _count_poisoned_skip() -> None:
-        from xgboost_tpu.profiling import reliability_metrics
+        from xgboost_tpu.obs import reliability_metrics
         reliability_metrics().poisoned_reloads.inc()
 
     def rollback(self) -> bool:
