@@ -113,9 +113,10 @@ def test_totals_advance_with_no_profiler_and_no_log():
     after = span_totals().count.values()
     grew = {k: after[k] - before.get(k, 0) for k in after}
     assert {k: grew[k] for k in SEGMENT} == dict.fromkeys(SEGMENT, 2)
-    # two matrices: constructor + deferred CSR each; one sketch; two
-    # entries binned; bin ids x 2, labels and weights of the train set
-    assert grew["ingest.dmatrix"] == 4 and grew["ingest.cuts"] == 1
+    # two matrices: their constructors, and no CSR built from the
+    # ndarrays; one sketch; two entries binned; bin ids x 2, labels and
+    # weights of the train set
+    assert grew["ingest.dmatrix"] == 2 and grew["ingest.cuts"] == 1
     assert grew["ingest.bin"] == 2 and grew["ingest.upload"] == 4
     secs = span_totals().seconds.values()
     assert all(secs[k] > secs0.get(k, 0.0) for k in INGEST + SEGMENT)
@@ -126,6 +127,35 @@ def test_totals_advance_with_no_profiler_and_no_log():
     text = obs.registry().render()
     assert 'xgbtpu_span_total{span="train.segment"}' in text
     assert 'xgbtpu_span_seconds_total{span="ingest.bin"}' in text
+
+
+@pytest.mark.parametrize("kind,dense", [("ndarray", 1), ("csr_tuple", 0)])
+def test_cuts_and_bin_spans_say_whether_the_dense_source_was_read(
+        tmp_path, kind, dense):
+    import json
+    X, y, _, _ = _data(1504)
+    path = str(tmp_path / "spans.jsonl")
+    obs.configure_log(path)
+    try:
+        dtrain = xgb.DMatrix(X, label=y)
+        if kind == "csr_tuple":
+            dtrain = xgb.DMatrix((dtrain.indptr, dtrain.indices,
+                                  dtrain.values, dtrain.num_col), label=y)
+        xgb.Booster(dict(PARAMS), cache=[dtrain]).update(dtrain, 0)
+    finally:
+        obs.configure_log(None)
+    with open(path) as f:
+        spans = [r for r in map(json.loads, f) if r["kind"] == "span"]
+    ingest = [r for r in spans if r["name"] in INGEST]
+    for name in ("ingest.cuts", "ingest.bin"):
+        found = [r["attrs"]["dense"] for r in ingest if r["name"] == name]
+        assert found and set(found) == {dense}, (name, found)
+    # the deferred CSR build is nobody's child: the four never nest
+    ids = {r["span"] for r in ingest}
+    assert {r["name"] for r in ingest} == set(INGEST)
+    assert not [r for r in ingest if r.get("parent") in ids]
+    built = [r for r in ingest if r["name"] == "ingest.dmatrix"]
+    assert len(built) == (1 if dense else 3)
 
 
 @pytest.fixture(scope="module")

@@ -20,7 +20,9 @@ Binning scheme:
 from __future__ import annotations
 
 import dataclasses
+import os
 import sys
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
@@ -34,6 +36,49 @@ from xgboost_tpu.sketch import (QuantileSummary, make_summary, prune_summary,
 # align_cut_lists).  Single source of truth — the learner and
 # compute_cuts both defer to this default.
 DEFAULT_TRIM_MARGIN = 4
+
+# A matrix that still holds the ndarray it was built from
+# (``DMatrix.dense_source``) is read column by column (cut proposal)
+# and row block by row block (bin ids) from that array: no CSR, no
+# column cache.  Columns and blocks are independent and ``np.sort`` /
+# ``np.searchsorted`` release the GIL, so they map over this many
+# threads; the result is the serial loop's, byte for byte.
+_THREADS = max(1, min(8, (os.cpu_count() or 2) - 1))
+_BIN_BLOCK = 1 << 18    # rows of one binning task (29 MB of float32
+#                         at 28 columns: read once, column by column)
+_SKETCH_MIN = 1 << 16   # longer columns go through sketch_column
+
+
+def _dense_source(dmat) -> Optional[tuple]:
+    """``(arr, missing)`` of a matrix that holds its dense source, None
+    for every other kind (CSR tuple, scipy, file, external, sharded)."""
+    get = getattr(dmat, "dense_source", None)
+    return None if get is None else get()
+
+
+def holds_dense(dmat) -> int:
+    """1 where ``compute_cuts`` / ``bin_matrix`` read ``dmat``'s dense
+    source, 0 where they read CSR: the ``dense`` attribute of the
+    ``ingest.cuts`` and ``ingest.bin`` spans."""
+    return int(_dense_source(dmat) is not None)
+
+
+def _map(fn, tasks) -> list:
+    tasks = list(tasks)
+    if _THREADS == 1 or len(tasks) < 2:
+        return [fn(t) for t in tasks]
+    with ThreadPoolExecutor(min(_THREADS, len(tasks))) as pool:
+        return list(pool.map(fn, tasks))
+
+
+def _dense_column(arr: np.ndarray, missing: float, f: int) -> np.ndarray:
+    """Column ``f`` of a dense source less its missing cells, in row
+    order: what ``column_values(f)`` returns of the CSR built from it."""
+    if f >= arr.shape[1]:       # num_col= wider than the array
+        return np.zeros(0, np.float32)
+    col = np.ascontiguousarray(arr[:, f])
+    present = ~np.isnan(col) if np.isnan(missing) else col != missing
+    return col if present.all() else col[present]
 
 
 @dataclasses.dataclass
@@ -71,19 +116,27 @@ def compute_cuts(dmat: DMatrix, max_bin: int = 256, sketch_eps: float = 0.03,
     (learner-selected on TPU) aligns the bin count for the int8
     histogram kernel — see :func:`align_cut_lists`.
     """
-    F = dmat.num_col
-    per_feature = []
-    for f in range(F):
-        rows, vals = dmat.column_values(f)
-        w = None if hess_weights is None else hess_weights[rows]
-        if len(vals) > (1 << 16):
+    # dense input with no per-row weights never leaves its array
+    src = None if hess_weights is not None else _dense_source(dmat)
+    small = max(2, int(sketch_ratio / max(sketch_eps, 1.0 / max_bin)))
+
+    def column_cuts(f: int) -> np.ndarray:
+        if src is not None:
+            vals, w = _dense_column(*src, f), None
+        else:
+            rows, vals = dmat.column_values(f)
+            w = None if hess_weights is None else hess_weights[rows]
+        if len(vals) > _SKETCH_MIN:
             summary = sketch_column(vals, w, sketch_eps, sketch_ratio)
         else:
-            summary = prune_summary(
-                make_summary(vals, w),
-                max(2, int(sketch_ratio / max(sketch_eps, 1.0 / max_bin))))
-        cuts = propose_cuts(summary, max_bin - 1)  # leave room for missing bin
-        per_feature.append(cuts)
+            summary = prune_summary(make_summary(vals, w), small)
+        return propose_cuts(summary, max_bin - 1)  # room for missing bin
+
+    F = dmat.num_col
+    if src is not None and src[0].shape[0] > _SKETCH_MIN:
+        per_feature = _map(column_cuts, range(F))
+    else:
+        per_feature = [column_cuts(f) for f in range(F)]
     return pack_cuts(align_cut_lists(per_feature, bin_align,
                                      bin_align_margin))
 
@@ -206,6 +259,10 @@ def bin_matrix(dmat: DMatrix, cuts: CutMatrix) -> np.ndarray:
     n, F = dmat.num_row, cuts.num_feature
     dtype = np.uint8 if cuts.max_bin <= 256 else np.uint16
     out = np.zeros((n, F), dtype=dtype)
+    src = _dense_source(dmat)
+    if src is not None:
+        _bin_dense_into(out, src[0], cuts, src[1])
+        return out
     rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(dmat.indptr))
     cols = dmat.indices
     # explicitly-stored NaNs are missing (bin 0) — same as an absent CSR
@@ -245,15 +302,36 @@ def bin_dense_device(X, cut_values):
     return b.astype(jnp.uint8 if cv.shape[1] + 2 <= 256 else jnp.uint16)
 
 
+def _bin_dense_into(out: np.ndarray, X: np.ndarray, cuts: CutMatrix,
+                    missing: float) -> None:
+    """``out[i, f] = 1 + searchsorted(cuts_f, X[i, f], "right")`` for
+    every present cell; missing cells and stored NaNs keep bin 0 (as an
+    absent CSR entry and ``bin_dense_device``'s isnan mask do), ±inf
+    goes where ``searchsorted`` sends it.  Row blocks, so that a
+    block's columns are read from cache and tasks write apart."""
+    width = min(X.shape[1], out.shape[1], cuts.num_feature)
+
+    def block(start: int) -> None:
+        rows = slice(start, start + _BIN_BLOCK)
+        for f in range(width):
+            col, dst = X[rows, f], out[rows, f]
+            present = ~np.isnan(col)
+            if not np.isnan(missing):
+                present &= col != missing
+            cut = cuts.cut_values[f, :cuts.n_cuts[f]]
+            if present.all():
+                dst[:] = 1 + np.searchsorted(cut, col, side="right")
+            else:
+                dst[present] = 1 + np.searchsorted(cut, col[present],
+                                                   side="right")
+
+    _map(block, range(0, X.shape[0], _BIN_BLOCK))
+
+
 def bin_dense(X: np.ndarray, cuts: CutMatrix, missing: float = np.nan) -> np.ndarray:
     """Quantize a dense float matrix directly (prediction-time fast path)."""
     n, F = X.shape
     dtype = np.uint8 if cuts.max_bin <= 256 else np.uint16
     out = np.zeros((n, F), dtype=dtype)
-    for f in range(min(F, cuts.num_feature)):
-        col = X[:, f]
-        present = ~np.isnan(col) if np.isnan(missing) else col != missing
-        b = 1 + np.searchsorted(cuts.cut_values[f, :cuts.n_cuts[f]],
-                                col[present], side="right")
-        out[present, f] = b.astype(dtype)
+    _bin_dense_into(out, X, cuts, missing)
     return out

@@ -128,10 +128,12 @@ class DMatrix:
     scipy CSR/CSC, or a (indptr, indices, values, num_col) CSR tuple.
 
     Dense ndarray input is held by REFERENCE and CSR is built lazily on
-    first ``values``/``indices``/``indptr`` access (a one-off predict
-    never builds it — the fused path uploads views of the caller's
-    buffer).  Consequence: mutating the source array between
-    construction and first use changes what this matrix sees — and only
+    first ``values``/``indices``/``indptr`` access (histogram training
+    and a one-off predict never build it: cuts and bin ids are read
+    column by column from the array, ``dense_source``, and the fused
+    predict path uploads views of the caller's buffer).  Consequence:
+    mutating the source array between construction and first use
+    changes what this matrix sees — and only
     for float32 input (``np.asarray`` copies while converting any other
     dtype); snapshot with ``DMatrix(arr.copy())`` when the buffer will
     be reused.
@@ -178,10 +180,12 @@ class DMatrix:
         # CSR storage is LAZY for dense ndarray input: a one-off
         # ``DMatrix(arr)`` predict never touches values/indices/indptr
         # (the fused path uploads views of ``arr`` itself and the
-        # density gate reads num_nonmissing()), so the ~2x host copy is
-        # only built when something actually iterates CSR (training,
-        # sparse binning, slicing...).  The properties below
-        # materialize on first access — transparent to every consumer.
+        # density gate reads num_nonmissing()), and cut proposal and
+        # binning read the array's columns (dense_source()), so the ~2x
+        # host copy is only built when something actually iterates CSR
+        # (slicing, save_binary, gblinear, exact mode...).  The
+        # properties below materialize on first access — transparent
+        # to every consumer.
         self._indptr = self._indices = self._values = None
         self._lazy_dense: Optional[tuple] = None  # (arr, missing)
         self._lazy_lock = threading.Lock()
@@ -318,6 +322,14 @@ class DMatrix:
                     total += int(np.count_nonzero(chunk != missing))
             self._nnz = total
         return self._nnz
+
+    def dense_source(self) -> Optional[tuple]:
+        """``(arr, missing)`` while this matrix still holds the 2-D
+        float32 array it was built from and has built no CSR from it,
+        else None.  Cut proposal and binning read their columns from
+        it (binning.py), so training from an ndarray never builds CSR
+        or the column cache; sparse, file and tuple input have none."""
+        return self._lazy_dense  # one read: may be cleared concurrently
 
     def predict_dense_src(self) -> Optional[np.ndarray]:
         """The dense f32 NaN-missing buffer this matrix wraps, when CSR

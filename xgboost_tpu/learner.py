@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from xgboost_tpu.binning import _rank0 as _is_rank0
-from xgboost_tpu.binning import bin_matrix, compute_cuts
+from xgboost_tpu.binning import bin_matrix, compute_cuts, holds_dense
 from xgboost_tpu.config import TrainParam
 from xgboost_tpu.data import DMatrix, MetaInfo, upload
 from xgboost_tpu.metrics import create_metric
@@ -220,14 +220,10 @@ class Booster:
             else:
                 from xgboost_tpu.models.gbtree import GBTree
                 self.num_feature = dtrain.num_col
-                if isinstance(dtrain, DMatrix):
-                    # a dense-input matrix defers its CSR to first use:
-                    # build it under its own ingest.dmatrix span, not
-                    # under cuts'
-                    dtrain._materialize()
                 with span("ingest.cuts", features=dtrain.num_col,
-                          max_bin=self.param.max_bin):
+                          max_bin=self.param.max_bin) as sp:
                     cuts = self._propose_cuts(dtrain)
+                    sp.set("dense", holds_dense(dtrain))
                 self.gbtree = GBTree(self.param, cuts)
                 if getattr(dtrain, "is_external", False):
                     # paged matrices route through the binned pipeline
@@ -450,10 +446,8 @@ class Booster:
             elif self._rank_pad_ok(dmat):
                 self._cache[key] = self._make_rank_padded_entry(dmat)
             else:
-                # the deferred dense -> CSR under its own ingest.dmatrix
-                # span, not under bin's
-                dmat._materialize()
-                bin_span = dict(rows=dmat.num_row, features=dmat.num_col)
+                bin_span = dict(rows=dmat.num_row, features=dmat.num_col,
+                                dense=holds_dense(dmat))
                 with span("ingest.bin", **bin_span):
                     binned_host = bin_matrix(dmat, self.gbtree.cuts)
                 binned = upload(binned_host)
@@ -571,9 +565,8 @@ class Booster:
         shard = self._shard_rows
         n = dmat.num_row
         pad = (-n) % self._mesh.size
-        if binned_np is None:
-            dmat._materialize()   # under ingest.dmatrix, not under bin
-        with span("ingest.bin", rows=n, features=dmat.num_col):
+        with span("ingest.bin", rows=n, features=dmat.num_col,
+                  dense=0 if binned_np is not None else holds_dense(dmat)):
             if binned_np is None:
                 binned_np = bin_matrix(dmat, self.gbtree.cuts)
             if pad:
@@ -622,8 +615,8 @@ class Booster:
                 "replicated for rank:*")
         n_loc = dmat.local_num_row
         K = self._K
-        dmat._local._materialize()
-        with span("ingest.bin", rows=n_loc, features=dmat.num_col):
+        with span("ingest.bin", rows=n_loc, features=dmat.num_col,
+                  dense=holds_dense(dmat._local)):
             binned_local = dmat.pad_local(
                 bin_matrix(dmat._local, self.gbtree.cuts))
         binned = upload(binned_local, dmat.make_global)
@@ -761,8 +754,8 @@ class Booster:
         occupied = prep.pad_map >= 0                      # (n_slots,)
         src = prep.pad_map[occupied]
 
-        dmat._materialize()       # under ingest.dmatrix, not under bin
-        bin_span = dict(rows=dmat.num_row, features=dmat.num_col)
+        bin_span = dict(rows=dmat.num_row, features=dmat.num_col,
+                        dense=holds_dense(dmat))
         with span("ingest.bin", **bin_span):
             binned_host = bin_matrix(dmat, self.gbtree.cuts)
             binned_pad = np.zeros((n_slots, binned_host.shape[1]),

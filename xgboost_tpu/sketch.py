@@ -89,15 +89,21 @@ def make_summary(values: np.ndarray, weights: np.ndarray | None = None) -> Quant
     Equivalent to pushing every element into the reference's
     WQuantileSketch and taking the unpruned summary.
     """
-    values = np.asarray(values, dtype=np.float64).ravel()
     if weights is None:
         # unweighted fast path: a plain value sort + run-length counts;
         # the general path's stable argsort + ufunc.at dominated
-        # external-memory sketch ingest (~8x slower per column)
-        values = values[np.isfinite(values)]
+        # external-memory sketch ingest (~8x slower per column).
+        # float32 input is sorted as it is and widened after: widening
+        # is exact and monotone, so the sorted float64 array is the same
+        values = np.asarray(values).ravel()
+        if values.dtype != np.float32:
+            values = values.astype(np.float64, copy=False)
+        finite = np.isfinite(values)
+        if not finite.all():
+            values = values[finite]
         if values.size == 0:
             return empty_summary()
-        v = np.sort(values)
+        v = np.sort(values).astype(np.float64, copy=False)
         edges = np.flatnonzero(
             np.concatenate([[True], v[1:] != v[:-1]]))
         gv = v[edges]
@@ -105,6 +111,7 @@ def make_summary(values: np.ndarray, weights: np.ndarray | None = None) -> Quant
             [edges, [v.size]])).astype(np.float64)
         rmax = np.cumsum(gw)
         return QuantileSummary(gv, rmax - gw, rmax, gw)
+    values = np.asarray(values, dtype=np.float64).ravel()
     weights = np.asarray(weights, dtype=np.float64).ravel()
     mask = np.isfinite(values) & (weights > 0)
     values, weights = values[mask], weights[mask]
@@ -210,13 +217,16 @@ def sketch_column(values: np.ndarray, weights: np.ndarray | None,
     at bounded size.
     """
     maxsize = max(2, int(sketch_ratio / eps))
-    values = np.asarray(values, dtype=np.float64).ravel()
-    if weights is None:
-        weights = np.ones_like(values)
+    values = np.asarray(values).ravel()
+    if weights is not None:
+        weights = np.asarray(weights)
     acc = empty_summary()
     for start in range(0, max(len(values), 1), chunk):
+        # weights=None stays None: make_summary's unweighted branch
+        # gives the summary its weighted one gives for all-one weights
         part = make_summary(values[start:start + chunk],
-                            np.asarray(weights)[start:start + chunk])
+                            None if weights is None
+                            else weights[start:start + chunk])
         part = prune_summary(part, maxsize)
         acc = prune_summary(merge_summaries(acc, part), maxsize)
     return acc
