@@ -480,7 +480,13 @@ def kernel_cases(n_rows: int = 70_000):
 
     cases = []
 
-    def solo(N, F, B, M, precision, operand, native):
+    def solo(N, F, B, M, precision, operand, native, chunks=1):
+        # chunks > 1: the int8 row chunks a job past 16.7M rows takes,
+        # forced at this size through the kernel's private rows_per_acc
+        r_tile, _, n_pad, _ = ph._tiling(N, F, B)
+        rows_per_acc = (None if chunks == 1 else
+                        -(-(n_pad // r_tile) // chunks) * r_tile)
+
         def build(interpret):
             binned, gh, pos = _dyadic_case(N, F, B, M, 3, 0.1)
             bt = None
@@ -498,7 +504,7 @@ def kernel_cases(n_rows: int = 70_000):
                     bt = ph.transpose_bins(binned, B)
                 return ph._hist_pallas_pre(
                     bt, gh_in, scale, pos, (N, F), M, B, precision,
-                    interpret, native=native)
+                    interpret, native=native, rows_per_acc=rows_per_acc)
 
             def verify(out):
                 out = np.asarray(out)
@@ -509,7 +515,8 @@ def kernel_cases(n_rows: int = 70_000):
             return fn, (jnp.asarray(binned), bt, jnp.asarray(gh),
                         jnp.asarray(pos)), verify
         name = (f"solo N={N} F={F} B={B} M={M} {precision} "
-                f"{operand}-operand {'native' if native else 'standard'}")
+                f"{operand}-operand {'native' if native else 'standard'}"
+                + (f" {chunks} row chunks" if chunks > 1 else ""))
         cases.append((name, build))
 
     # the training path: every level of depth 6, resident u8 operand,
@@ -533,6 +540,10 @@ def kernel_cases(n_rows: int = 70_000):
     for precision in ("int8", "fp32", "bf16"):
         solo(n_rows, 28, 256, 64, precision, "int32", False)
     solo(n_rows, 28, 256, 1, "int8", "int32", True)
+    # the Airline shape past one int32 accumulator's rows: F=13 in two
+    # feature tiles of 8, three row chunks, both layouts
+    solo(n_rows, 13, 256, 32, "int8", "int32", True, chunks=3)
+    solo(n_rows, 13, 256, 128, "int8", "int32", False, chunks=3)
 
     def batched(T, N, F, B, M, precision):
         def build(interpret):

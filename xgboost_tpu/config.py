@@ -81,8 +81,9 @@ class TrainParam:
     # Split-loaded matrices (parallel/sharded.py) always device-sketch.
     device_sketch: int = -1
     # histogram accumulation precision (recorded in saved models):
-    # "auto" = bf16 MXU kernel on TPU / exact scatter elsewhere;
-    # "fp32" forces exact-f32 histograms; "bf16" forces the MXU pass;
+    # "auto" = int8 MXU kernel on TPU, at any row count / exact
+    # scatter elsewhere; "int8" names that mode; "fp32" forces
+    # exact-f32 histograms; "bf16" forces the bf16 MXU pass;
     # "fixed" forces int32 fixed-point scatter accumulation (exactly
     # associative -> model bytes bitwise invariant to the data-mesh
     # device count; ops/histogram.FIXED_SCALE documents resolution).

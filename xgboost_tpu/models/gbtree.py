@@ -29,6 +29,7 @@ from xgboost_tpu.models.tree import (GrowConfig, TreeArrays, grow_tree,
                                      predict_margin_binned,
                                      predict_margin_fused, table_lookup,
                                      tree_capacity)
+from xgboost_tpu.ops.histogram import kernel_mode
 from xgboost_tpu.ops.split import SplitConfig
 
 
@@ -856,7 +857,8 @@ class GBTree:
         with span("train.dispatch", first_round=first_iteration,
                   n_rounds=n_rounds, donated=bool(donate),
                   mesh_fused=bool(mesh_scan)) as dispatch:
-            with span("train.launch"):
+            with span("train.launch",
+                      hist_mode=kernel_mode(self.cfg.hist_precision)):
                 label = info.label_dev()
                 weight = info.weight_dev(margin.shape[0])
                 cut_vals, cut_ns = self.cut_values_dev, self.n_cuts_dev

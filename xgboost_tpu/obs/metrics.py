@@ -533,6 +533,14 @@ class TrainingMetrics:
             "xgbtpu_train_rounds_per_dispatch",
             "rounds covered by the most recent fused training dispatch "
             "(segment size; stays 0 on the per-round path)")
+        # trace-time gauge (ops/pallas_hist._sum_chunks): 1 while a
+        # job's rows fit one int32 accumulator block, 3 at 40M rows
+        self.hist_row_chunks = Gauge(
+            "xgbtpu_hist_row_chunks",
+            "row chunks of the most recently traced Pallas level "
+            "histogram: int8 sums at most 2^24 rows per int32 "
+            "accumulator block and adds the chunks in float32 "
+            "(0 until a Pallas histogram is traced)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -548,7 +556,8 @@ class TrainingMetrics:
                      self.phase_seconds, self.eval_score,
                      self.checkpoints, self.checkpoint_seconds,
                      self.device_memory, self.dispatch_seconds,
-                     self.rounds_per_dispatch, self.fused_fallback)
+                     self.rounds_per_dispatch, self.hist_row_chunks,
+                     self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

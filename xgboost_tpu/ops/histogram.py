@@ -60,11 +60,28 @@ def hist_backend(precision: str = "auto") -> HistBackend:
     # parameter, not an env-var default): fp32 selects exact-f32
     # histograms; bf16 takes the bf16 MXU pass; int8 — the TPU auto
     # default — quantizes gradients to 8 bits per call with
-    # int32-exact accumulation.
+    # int32-exact accumulation.  The row count changes none of this:
+    # past 16.7M rows int8 sums row chunks of 2^24 rows in int32 blocks
+    # of their own (pallas_hist._acc_tiles).  kernel_mode() names what
+    # runs, for the spans.
     if not on_tpu:
         return HistBackend("scatter", False)
     return HistBackend({"fp32": "pallas", "bf16": "pallas_bf16"}.get(
         precision, "pallas_int8"), False)
+
+
+_KERNEL_MODE = {"pallas": "fp32", "pallas_bf16": "bf16",
+                "pallas_int8": "int8"}
+
+
+def kernel_mode(precision: str = "auto") -> str:
+    """The histogram mode that runs in this process for the TrainParam
+    ``hist_precision``: ``int8`` | ``bf16`` | ``fp32`` (the Pallas
+    kernel's), ``fixed`` or ``scatter`` (XLA scatter, int32 fixed-point
+    or float32 cells).  The ``hist_mode`` attribute of ``train.launch``."""
+    if precision == "fixed":
+        return "fixed"
+    return _KERNEL_MODE.get(hist_backend(precision).impl, "scatter")
 
 
 @functools.lru_cache(maxsize=None)
@@ -145,7 +162,9 @@ class HistPrep(NamedTuple):
     binned_t: jax.Array          # (f_pad, n_pad) int32 kernel operand
     gh_in: jax.Array             # (N, 2) f32 | int32
     scale: object                # (2,) f32 in int8 mode, else None
-    precision: str               # resolved mode: fp32 | bf16 | int8
+    precision: str               # the kernel mode hist_precision
+    #                              resolved to: fp32 | bf16 | int8
+    #                              (kernel_mode; never by the row count)
 
 
 def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
@@ -155,13 +174,10 @@ def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
     :func:`build_level_histogram`).  ``binned_t`` is an optional
     RESIDENT pre-transposed operand (pallas_hist.host_transpose_bins,
     built once per dataset by the learner entry)."""
-    impl = hist_backend(precision).impl
-    if not impl.startswith("pallas"):
+    mode = _KERNEL_MODE.get(hist_backend(precision).impl)
+    if mode is None:
         return None
     from xgboost_tpu.ops import pallas_hist as ph
-    mode = {"pallas_bf16": "bf16", "pallas_int8": "int8",
-            "pallas": "fp32"}[impl]
-    mode = ph.resolve_precision(mode, binned.shape[0])
     if mode == "int8":
         gh_in, scale = ph.quantize_gh(gh)
     else:
@@ -286,10 +302,9 @@ def build_level_histogram(binned: jax.Array, gh: jax.Array, pos: jax.Array,
         return fn(prep.binned, prep.binned_t, prep.gh_in, pos)
     assert not native, "native layout requires the pallas prep path"
     impl, interpret = hist_backend(precision)
-    if impl.startswith("pallas"):
-        precision = {"pallas_bf16": "bf16", "pallas_int8": "int8",
-                     "pallas": "fp32"}[impl]
-        fn = _pallas_hist_vmappable(n_node, n_bin, precision, interpret)
+    if impl in _KERNEL_MODE:
+        fn = _pallas_hist_vmappable(n_node, n_bin, _KERNEL_MODE[impl],
+                                    interpret)
         return fn(binned, gh, pos)
     N, F = binned.shape
     f_ids = jnp.arange(F, dtype=jnp.int32)[None, :]

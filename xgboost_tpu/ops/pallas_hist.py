@@ -55,7 +55,7 @@ def _round_up(x: int, m: int) -> int:
 
 def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                  n_bin: int, m_pad: int, f_tile: int, precision_mode: str,
-                 rpl: int):
+                 rpl: int, rpa: int):
     """One (node_tile, feature_tile, row_tile) grid step.
 
     binned_ref: (f_tile, R) u8|int32 bin ids, feature-major
@@ -65,13 +65,18 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                 nodes of THIS node tile (grid dim 0) — deep levels
                 (n_node > m_pad) tile the node dim so the block never
                 outgrows VMEM.
-    rpl:        row tiles per accumulator block.  The solo call passes
-                its whole row-tile count (init fires once, at row tile
-                0); the LANE-stacked call (gang-batched multi-tenant
-                training, _hist_pallas_lanes_pre) packs L tenants'
-                rows end-to-end along the row grid with one output
-                block per (lane, node tile) — init fires at each
-                lane's first row tile.
+    rpl:        row tiles per lane.  The solo call passes its whole
+                row-tile count; the LANE-stacked call (gang-batched
+                multi-tenant training, _hist_pallas_lanes_pre) packs L
+                tenants' rows end-to-end along the row grid.
+    rpa:        row tiles per accumulator block (:func:`_acc_tiles`):
+                the block is zeroed at each lane's first row tile and
+                then every ``rpa`` tiles of that lane.  ``rpa == rpl``
+                (one block per lane and node tile) in the float modes
+                and wherever a lane's rows fit one int32 accumulator;
+                an int8 job past 16.7M rows sums each CHUNK of ``rpa``
+                tiles exactly into a block of its own, and the caller
+                widens and adds the chunks (:func:`_sum_chunks`).
 
     EVERY per-row operand keeps rows in the LANE dim: TPU arrays tile
     to (8, 128), so (N, 1)/(N, 2) operands are physically inflated
@@ -84,7 +89,11 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
     m2 = 2 * m_pad
     m_base = pl.program_id(0) * m_pad  # first global node of this tile
 
-    @pl.when(pl.program_id(2) % rpl == 0)
+    tile = pl.program_id(2) % rpl          # row tile within this lane
+    if rpa < rpl:
+        tile = tile % rpa
+
+    @pl.when(tile == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
@@ -133,11 +142,45 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
         out_ref[0, f * n_bin:(f + 1) * n_bin, :] += acc
 
 
-def resolve_precision(precision: str, n_rows: int) -> str:
-    """int8 needs int32-safe cell accumulators (N * 127 < 2^31)."""
-    if precision == "int8" and n_rows * 127 >= 2 ** 31:
-        return "bf16"
-    return precision
+def _rows_per_acc(r_tile: int) -> int:
+    """Rows one int32 accumulator block may sum in int8 mode: every row
+    adds at most 127 to a cell, so ``rows * 127 < 2^31``.  The largest
+    power of two under that bound (2^24 = 16,777,216), in whole row
+    tiles: 8192 tiles at the default ``r_tile`` of 2048."""
+    bound = (2 ** 31 - 1) // 127
+    return max(r_tile, (1 << (bound.bit_length() - 1)) // r_tile * r_tile)
+
+
+def _acc_tiles(n_tiles: int, r_tile: int, precision: str,
+               rows_per_acc=None) -> tuple:
+    """``(rpa, n_chunks)``: row tiles per accumulator block and the
+    blocks a lane's ``n_tiles`` take.  One block where the sums are
+    float32; at most :func:`_rows_per_acc` rows' worth a block where
+    they are int32.  No mode changes with the row count: int8 past
+    16.7M rows takes more blocks, not another precision.
+    ``rows_per_acc`` is for tests (a small job in several chunks)."""
+    if precision != "int8":
+        return n_tiles, 1
+    if rows_per_acc is None:
+        rows_per_acc = _rows_per_acc(r_tile)
+    assert rows_per_acc % r_tile == 0 and rows_per_acc * 127 < 2 ** 31
+    rpa = min(n_tiles, rows_per_acc // r_tile)
+    return rpa, -(-n_tiles // rpa)
+
+
+def _sum_chunks(out: jax.Array, n_chunks: int, axis: int) -> jax.Array:
+    """Widen the exact int32 sums of ``n_chunks`` row chunks, laid along
+    ``axis``, to float32 and add them; traced once per level, so the
+    gauge ``xgbtpu_hist_row_chunks`` holds the last level's count.  One
+    chunk (every job under 16.7M rows) passes through untouched: no op
+    is added to the program."""
+    from xgboost_tpu.obs import training_metrics
+    training_metrics().hist_row_chunks.set(float(n_chunks))
+    if n_chunks == 1:
+        return out
+    with jax.named_scope("grow.widen"):
+        shape = out.shape[:axis] + (n_chunks, -1) + out.shape[axis + 1:]
+        return out.reshape(shape).astype(jnp.float32).sum(axis=axis)
 
 
 def _tiling(N: int, F: int, n_bin: int):
@@ -218,12 +261,14 @@ def build_level_histogram_pallas(binned: jax.Array, gh: jax.Array,
     "bf16" (DEFAULT, ~3x faster; operands truncated to bf16 inside the
     MXU, accumulation still f32), or "int8" (gradients quantized per
     call to 8 bits, int32-exact accumulation, ~9x the bf16 kernel —
-    element error ~s/254 vs bf16's ~0.2% relative truncation).
+    element error ~s/254 vs bf16's ~0.2% relative truncation).  The mode
+    asked for is the mode that runs at ANY row count: past 16.7M rows
+    int8 sums row chunks of at most 2^24 rows each exactly in int32 and
+    adds the chunks in float32 (:func:`_acc_tiles`).
 
     Returns (n_node, F, n_bin, 2) float32.
     """
     N, F = binned.shape
-    precision = resolve_precision(precision, N)
     binned_t = transpose_bins(binned, n_bin)
     if precision == "int8":
         gh_in, scale = quantize_gh(gh)
@@ -235,16 +280,19 @@ def build_level_histogram_pallas(binned: jax.Array, gh: jax.Array,
 
 def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
                      n_bin: int, precision: str, interpret: bool,
-                     native: bool = False) -> jax.Array:
+                     native: bool = False, rows_per_acc=None) -> jax.Array:
     """Kernel invocation on PREPARED operands (transpose_bins /
     quantize_gh hoisted to once per tree/round by the grow loop).
 
     ``native=True`` returns the kernel's own ``(F, B, 2, n_node)``
     layout (node minor) without the relayout transpose — consumed by
     split.find_best_splits_native; callers gate on n_node <= 64
-    (single node tile)."""
+    (single node tile).  ``rows_per_acc`` (tests only) forces the int8
+    row chunks of :func:`_acc_tiles` at a small size."""
     N, F = nf
     r_tile, f_tile, n_pad, f_pad = _tiling(N, F, n_bin)
+    n_tiles = n_pad // r_tile
+    rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
     # deep levels tile the node dim at 64 (lane dim 2*64 = one full MXU
     # pass) so the accumulator block stays VMEM-bounded at any depth
     m_pad = min(n_node, 64)
@@ -257,22 +305,30 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
                                f_tile=f_tile, precision_mode=precision,
-                               rpl=n_pad // r_tile)
+                               rpl=n_tiles, rpa=rpa)
+    # a chunk's blocks follow the previous chunk's along the node-tile
+    # axis; with one chunk the index map is the plain one
+    if n_chunks == 1:
+        def out_index(mi, fi, ri):
+            return (mi, fi, 0)
+    else:
+        def out_index(mi, fi, ri):
+            return (ri // rpa * n_m_tiles + mi, fi, 0)
     out = pl.pallas_call(
         kernel,
-        grid=(n_m_tiles, f_pad // f_tile, n_pad // r_tile),
+        grid=(n_m_tiles, f_pad // f_tile, n_tiles),
         in_specs=[
             pl.BlockSpec((f_tile, r_tile), lambda mi, fi, ri: (fi, ri)),
             pl.BlockSpec((1, r_tile), lambda mi, fi, ri: (0, ri)),
             pl.BlockSpec((2, r_tile), lambda mi, fi, ri: (0, ri)),
         ],
-        out_specs=pl.BlockSpec((1, f_tile * n_bin, 2 * m_pad),
-                               lambda mi, fi, ri: (mi, fi, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_m_tiles, f_pad * n_bin, 2 * m_pad),
-                                       out_dtype),
+        out_specs=pl.BlockSpec((1, f_tile * n_bin, 2 * m_pad), out_index),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
         interpret=interpret,
         name="hist_level_rows",
     )(binned_t, pos_t, gh_t)
+    out = _sum_chunks(out, n_chunks, 0)
 
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
@@ -294,7 +350,8 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
 
 def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
                            n_bin: int, precision: str, interpret: bool,
-                           native: bool = False) -> jax.Array:
+                           native: bool = False,
+                           rows_per_acc=None) -> jax.Array:
     """LANE-stacked kernel invocation: a leading axis L batches WHOLE
     tenant datasets (gang-batched multi-tenant training — each lane has
     its own bins, so the tree-batched kernel's shared one-hot does not
@@ -304,7 +361,9 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     exactly the row-tile sequence (content, order, and tile grouping)
     of that lane's solo :func:`_hist_pallas_pre` call, so per-lane
     results are BITWISE identical to solo — including signed zeros —
-    in every precision mode.  One launch, L x the solo grid.
+    in every precision mode.  One launch, L x the solo grid.  A lane
+    past one int32 accumulator's rows takes the solo call's row chunks
+    (:func:`_acc_tiles`): more blocks along the same axis.
 
     binned_t (L, f_pad, n_pad); gh_in (L, N, 2) f32|int32;
     scale (L, 2) f32 in int8 mode else None; pos (L, N) int32.
@@ -315,7 +374,8 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     r_tile, f_tile, n_pad, f_pad = _tiling(N, F, n_bin)
     m_pad = min(n_node, 64)
     n_m_tiles = -(-n_node // m_pad)
-    rpl = n_pad // r_tile  # row tiles per lane == per accumulator block
+    rpl = n_pad // r_tile  # row tiles per lane
+    rpa, n_chunks = _acc_tiles(rpl, r_tile, precision, rows_per_acc)
     pos_t = jnp.pad(pos.astype(jnp.int32), ((0, 0), (0, n_pad - N)),
                     constant_values=-1).reshape(1, L * n_pad)
     gh_t = jnp.pad(gh_in, ((0, 0), (0, n_pad - N), (0, 0)))
@@ -325,7 +385,12 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
                                f_tile=f_tile, precision_mode=precision,
-                               rpl=rpl)
+                               rpl=rpl, rpa=rpa)
+
+    def acc_of(ri):                 # lane-major, a lane's chunks within
+        if n_chunks == 1:
+            return ri // rpl
+        return ri // rpl * n_chunks + ri % rpl // rpa
     out = pl.pallas_call(
         kernel,
         grid=(n_m_tiles, f_pad // f_tile, L * rpl),
@@ -336,13 +401,15 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
         ],
         out_specs=pl.BlockSpec(
             (1, f_tile * n_bin, 2 * m_pad),
-            lambda mi, fi, ri: (ri // rpl * n_m_tiles + mi, fi, 0)),
+            lambda mi, fi, ri: (acc_of(ri) * n_m_tiles + mi, fi, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (L * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
+            (L * n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
         interpret=interpret,
         name="hist_level_lanes",
     )(bt, pos_t, gh_t)
 
+    out = _sum_chunks(out.reshape(L, n_chunks * n_m_tiles, -1, 2 * m_pad),
+                      n_chunks, 1)
     out = out.reshape(L, n_m_tiles, f_pad, n_bin, 2, m_pad)
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
@@ -374,7 +441,6 @@ def build_level_histogram_pallas_lanes(binned: jax.Array, gh: jax.Array,
     ``jax.vmap`` over tenant lanes (gang-batched multi-tenant
     training)."""
     L, N, F = binned.shape
-    precision = resolve_precision(precision, N)
     binned_t = jax.vmap(lambda b: transpose_bins(b, n_bin))(binned)
     if precision == "int8":
         gh_in, scale = quantize_gh(gh)               # per-lane (L, 2)
@@ -386,7 +452,7 @@ def build_level_histogram_pallas_lanes(binned: jax.Array, gh: jax.Array,
 
 def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                          n_bin: int, m_pad: int, f_tile: int, t_tile: int,
-                         precision_mode: str):
+                         precision_mode: str, rpa: int):
     """Tree-batched variant of :func:`_hist_kernel`: the (B, R) one-hot
     is built ONCE per (feature, row tile) and contracted against a
     (R, t_tile*2M) operand whose lane l encodes (tree, grad/hess, node):
@@ -401,7 +467,8 @@ def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
 
     binned_ref: (f_tile, R) int32;  pos_ref: (t_tile, R) int32;
     gh_ref: (2*t_tile, R) f32|int32, per-tree (g_t, h_t) sublane pairs.
-    out_ref: (1, 1, f_tile*n_bin, t_tile*2*m_pad).
+    out_ref: (1, 1, f_tile*n_bin, t_tile*2*m_pad), zeroed every ``rpa``
+    row tiles (one int8 row chunk, :func:`_acc_tiles`).
     Rows ride the LANE dim of every per-row operand and gh_exp is
     (lanes, R) with an NT dot, for the same physical-tiling reason as
     :func:`_hist_kernel`.
@@ -411,7 +478,7 @@ def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
     lanes = t_tile * m2
     m_base = pl.program_id(0) * m_pad
 
-    @pl.when(pl.program_id(3) == 0)
+    @pl.when(pl.program_id(3) % rpa == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
@@ -476,7 +543,6 @@ def build_level_histogram_pallas_batched(binned: jax.Array, gh: jax.Array,
     """
     T, N, _ = gh.shape
     F = binned.shape[1]
-    precision = resolve_precision(precision, N)
     if precision == "int8":
         gh, scale = quantize_gh(gh)                  # per-tree (T, 2)
     else:
@@ -556,7 +622,8 @@ def _tiling_batched(N, F, n_bin, T, m_pad, precision):
 def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
                              n_bin: int, precision: str,
                              interpret: bool,
-                             native: bool = False) -> jax.Array:
+                             native: bool = False,
+                             rows_per_acc=None) -> jax.Array:
     N, F = nf
     T = gh.shape[0]
     m_pad = min(n_node, 64)
@@ -566,6 +633,8 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
         N, F, n_bin, T, m_pad, precision)
     t_tiles = -(-T // t_tile)
     T_pad = t_tiles * t_tile
+    n_tiles = n_pad // r_tile
+    rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
     if n_pad != N or T_pad != T:
         gh = jnp.pad(gh, ((0, T_pad - T), (0, n_pad - N), (0, 0)))
         pos = jnp.pad(pos, ((0, T_pad - T), (0, n_pad - N)),
@@ -583,11 +652,11 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
 
     kernel = functools.partial(_batched_hist_kernel, n_bin=n_bin,
                                m_pad=m_pad, f_tile=f_tile, t_tile=t_tile,
-                               precision_mode=precision)
+                               precision_mode=precision, rpa=rpa)
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     out = pl.pallas_call(
         kernel,
-        grid=(n_m_tiles, t_tiles, f_pad // f_tile, n_pad // r_tile),
+        grid=(n_m_tiles, t_tiles, f_pad // f_tile, n_tiles),
         in_specs=[
             pl.BlockSpec((f_tile, r_tile), lambda mi, ti, fi, ri: (fi, ri)),
             pl.BlockSpec((None, t_tile, r_tile),
@@ -595,14 +664,16 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
             pl.BlockSpec((None, 2 * t_tile, r_tile),
                          lambda mi, ti, fi, ri: (ti, 0, ri)),
         ],
-        out_specs=pl.BlockSpec((1, 1, f_tile * n_bin, lanes),
-                               lambda mi, ti, fi, ri: (mi, ti, fi, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, f_tile * n_bin, lanes),
+            lambda mi, ti, fi, ri: (ri // rpa * n_m_tiles + mi, ti, fi, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (n_m_tiles, t_tiles, f_pad * n_bin, lanes), out_dtype),
+            (n_chunks * n_m_tiles, t_tiles, f_pad * n_bin, lanes), out_dtype),
         interpret=interpret,
         name="hist_level_trees",
     )(binned_t, pos_t,
       gh_flat if precision == "int8" else gh_flat.astype(jnp.float32))
+    out = _sum_chunks(out, n_chunks, 0)
 
     # (m_tiles, t_tiles, f_pad*B, t_tile*2M) -> (T, m_tiles*M, F, B, 2)
     out = out.reshape(n_m_tiles, t_tiles, f_pad, n_bin, t_tile, 2, m_pad)
