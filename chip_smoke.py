@@ -576,6 +576,8 @@ def kernel_cases(n_rows: int = 70_000):
         batched(9, n_rows, 93, 64, M, "int8")       # otto: 9 x 93
     batched(6, n_rows, 28, 64, 64, "fp32")
     batched(9, n_rows, 93, 64, 64, "fp32")
+    # 256 bins: f_tile=8 < F=28, the last tile's 4 padded slots guarded
+    batched(6, n_rows, 28, 256, 64, "int8")
 
     def lanes(L, N, F, B, M, precision):
         def build(interpret):
@@ -609,6 +611,8 @@ def kernel_cases(n_rows: int = 70_000):
         for M in (1, 4):
             lanes(L, 64, 4, 32, M, "int8")
         lanes(L, 64, 4, 32, 4, "fp32")
+    # 256 bins: F=13 in two feature tiles of 8, 3 padded slots guarded
+    lanes(2, 3000, 13, 256, 8, "int8")
 
     def nstats(N, M):
         def build(interpret):

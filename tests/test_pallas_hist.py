@@ -97,6 +97,109 @@ def test_pallas_histogram_odd_feature_tiling(N, F, B, M):
     np.testing.assert_array_equal(got, want)
 
 
+def _spy_on_pallas_call(monkeypatch):
+    """Collect what every ``pallas_call`` of ops/pallas_hist returns: the
+    kernel's own block, before the caller cuts the padding away."""
+    from xgboost_tpu.ops import pallas_hist as ph
+    raw, real = [], ph.pl.pallas_call
+
+    def pallas_call(*args, **kwargs):
+        call = real(*args, **kwargs)
+
+        def run(*operands):
+            raw.append(call(*operands))
+            return raw[-1]
+        return run
+    monkeypatch.setattr(ph.pl, "pallas_call", pallas_call)
+    return raw
+
+
+@pytest.mark.parametrize("precision", ["int8", "bf16", "fp32"])
+@pytest.mark.parametrize("F,B", [(13, 256), (28, 256), (34, 40)])
+@pytest.mark.parametrize("kernel", ["rows", "lanes", "trees"])
+def test_padded_feature_slots_run_no_dot(kernel, F, B, precision,
+                                         monkeypatch):
+    """The feature slots that only pad the last feature tile (3 of 16 at
+    13 features and 256 bins, 4 of 32 at 28) run no one-hot and no dot:
+    (a) the histogram is the XLA scatter's, bitwise; (b) whatever bin
+    ids stand in the padded rows of the prepared operand, the kernel's
+    UNCUT block is zero in the padded slots (the parent's held bin 0's
+    sums there, cut away afterwards) and the cut histogram does not
+    move; (c) the gauge reads the dots one row tile runs: F.  At 40 bins
+    the rows and lanes kernels have ``f_tile == F`` and nothing to skip;
+    the trees kernel's own tiling pads 34 to 48."""
+    from xgboost_tpu import obs
+    from xgboost_tpu.ops import pallas_hist as ph
+
+    N = 300
+    M = 64 if (kernel == "trees" or B == 40) else 4
+    K = 6 if (kernel == "trees" and B == 40) else 2     # lanes / trees
+    rng = np.random.RandomState(32)
+    binned = rng.randint(0, B, (K, N, F)).astype(np.uint8)
+    gh = (rng.randint(-512, 512, (K, N, 2)) / 256.0).astype(np.float32)
+    pos = rng.randint(0, M, (K, N)).astype(np.int32)
+    pos[rng.rand(K, N) < 0.2] = -1
+    if kernel != "lanes":               # one dataset
+        binned = binned[:1]
+    if kernel == "rows":
+        gh, pos = gh[:1], pos[:1]
+    gh_j = jnp.asarray(gh)
+    if precision == "int8":
+        gh_in, scale = ph.quantize_gh(gh_j)
+        gh_ref = np.asarray(gh_in, np.float32)      # exact integer sums
+    else:
+        gh_in, scale = gh_j, None
+        gh_ref = np.asarray(gh_j.astype(jnp.bfloat16).astype(jnp.float32)
+                            if precision == "bf16" else gh_j)
+
+    if kernel == "trees":
+        bt = ph.transpose_bins_batched(jnp.asarray(binned[0]), B, K, M,
+                                       precision)
+    else:
+        bt = jax.vmap(lambda b: ph.transpose_bins(b, B))(
+            jnp.asarray(binned))
+        bt = bt[0] if kernel == "rows" else bt
+    f_pad = bt.shape[-2]
+    assert f_pad > F or (B == 40 and kernel != "trees")
+    junk = jnp.asarray(rng.randint(0, B, bt.shape).astype(np.int32))
+    is_pad = (jnp.arange(f_pad) >= F)[:, None]
+    bt_junk = jnp.where(is_pad, junk, bt)
+
+    def run(operand):
+        nf = (N, F)
+        if kernel == "rows":
+            return ph._hist_pallas_pre(
+                operand, gh_in[0], None if scale is None else scale[0],
+                jnp.asarray(pos[0]), nf, M, B, precision, True)[None]
+        pre = (ph._hist_pallas_lanes_pre if kernel == "lanes"
+               else ph._hist_pallas_batched_pre)
+        return pre(operand, gh_in, scale, jnp.asarray(pos), nf, M, B,
+                   precision, True)
+
+    raw = _spy_on_pallas_call(monkeypatch)
+    feature_dots = obs.training_metrics().hist_feature_dots
+    feature_dots.set(0.0)
+    got = np.asarray(run(bt))
+    assert feature_dots.value == F                              # (c)
+    got_junk = np.asarray(run(bt_junk))
+    assert len(raw) == 2
+    for block in raw:                                           # (b)
+        block = np.asarray(block)
+        assert block.shape[-2] == f_pad * B
+        assert not block[..., F * B:, :].any()
+        assert block[..., :F * B, :].any()
+    np.testing.assert_array_equal(got_junk, got)
+
+    assert got.shape == (gh.shape[0], M, F, B, 2)               # (a)
+    for k in range(gh.shape[0]):
+        want = np.asarray(build_level_histogram(
+            jnp.asarray(binned[0 if kernel == "trees" else k]),
+            jnp.asarray(gh_ref[k]), jnp.asarray(pos[k]), M, B))
+        if precision == "int8":
+            want = want * np.asarray(scale[k] / 127.0)
+        np.testing.assert_array_equal(got[k], want)
+
+
 @pytest.mark.parametrize("T,N,F,B,M", [
     (3, 500, 5, 16, 8),
     (6, 257, 4, 67, 64),   # bench-like bins, node-tiled level

@@ -541,6 +541,15 @@ class TrainingMetrics:
             "histogram: int8 sums at most 2^24 rows per int32 "
             "accumulator block and adds the chunks in float32 "
             "(0 until a Pallas histogram is traced)")
+        # trace-time gauge (ops/pallas_hist._feature_dots): F, not the
+        # padded f_pad, since the slots that only pad the last feature
+        # tile run no dot
+        self.hist_feature_dots = Gauge(
+            "xgbtpu_hist_feature_dots",
+            "per-feature one-hot dots one row tile runs at the most "
+            "recently traced Pallas level histogram, summed over its "
+            "feature tiles: the feature count F (0 until a Pallas "
+            "histogram is traced)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -557,7 +566,7 @@ class TrainingMetrics:
                      self.checkpoints, self.checkpoint_seconds,
                      self.device_memory, self.dispatch_seconds,
                      self.rounds_per_dispatch, self.hist_row_chunks,
-                     self.fused_fallback)
+                     self.hist_feature_dots, self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

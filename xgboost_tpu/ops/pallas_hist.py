@@ -53,9 +53,71 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _mxu_mode(precision_mode: str) -> tuple:
+    """``(operand dtype, accumulator dtype, dot precision)`` of a
+    histogram mode.  TPU matmul default precision truncates f32
+    operands to bf16; fp32 mode must request HIGHEST for exact
+    (parity-testable) histograms (HIGH: unsupported by Mosaic).  In
+    bf16 mode the operands are materialized in bf16 up front: the MXU
+    would truncate them anyway, and halving the one-hot's VMEM
+    footprint is a measured ~20% kernel win (tools/hist_microbench.py).
+    int8 mode (gh arrives PRE-QUANTIZED as int32, one-hot is int8,
+    products accumulate exactly in int32): the v5e MXU streams int8
+    rows at 2x the bf16 rate — 1.88x measured on the level kernel
+    (tools/hist_dots_probe.py, PR 32)."""
+    if precision_mode == "int8":
+        return jnp.int8, jnp.int32, jax.lax.Precision.DEFAULT
+    if precision_mode == "fp32":
+        return jnp.float32, jnp.float32, jax.lax.Precision.HIGHEST
+    return jnp.bfloat16, jnp.float32, jax.lax.Precision.DEFAULT
+
+
+def _feature_dots(bins, gh_exp, out_ref, lead: tuple, fi, *, n_feat: int,
+                  n_bin: int, f_tile: int, precision_mode: str):
+    """The per-feature loop of every level kernel: one (B, R) one-hot
+    and one ``(B, R) @ (R, lanes)`` dot per REAL feature of this feature
+    tile, added into rows ``f * n_bin`` of ``out_ref[lead]``.
+
+    A slot that only pads the feature tile (``fi * f_tile + f >=
+    n_feat``: 4 of 32 at 28 features and 256 bins, 3 of 16 at 13; its
+    bin ids are the zeros :func:`transpose_bins` pads with) runs no
+    one-hot and no MXU pass; its accumulator rows stay at the zeros
+    ``_init`` wrote and the caller cuts them away (``[:F]``).  Such
+    slots sit at the end of the LAST feature tile only, so they share
+    one ``pl.when`` on the tile index and every other slot is the
+    straight-line code it always was: where F fills its tiles (or
+    ``f_tile == F``, the 64-bin jobs) no guard is in the program.
+
+    bins: (f_tile, R) int32; gh_exp: (lanes, R); fi: this step's index
+    along the feature-tile grid axis.  Traced once per level, so the
+    gauge ``xgbtpu_hist_feature_dots`` holds the dots one row tile of
+    the last level traced runs over all its feature tiles: F."""
+    from xgboost_tpu.obs import training_metrics
+    training_metrics().hist_feature_dots.set(float(n_feat))
+    hot_dtype, acc_dtype, prec = _mxu_mode(precision_mode)
+    r_tile = bins.shape[1]
+    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_bin, r_tile), 0)
+    last = (n_feat - 1) // f_tile           # index of the last tile
+    n_real = n_feat - last * f_tile         # its slots that hold a feature
+
+    def slots(lo, hi):
+        for f in range(lo, hi):
+            onehot = (bins[f:f + 1, :] == bin_ids).astype(hot_dtype)  # (B, R)
+            acc = jax.lax.dot_general(
+                onehot, gh_exp, (((1,), (1,)), ((), ())),
+                precision=prec,
+                preferred_element_type=acc_dtype)            # (B, lanes)
+            out_ref[lead + (slice(f * n_bin, (f + 1) * n_bin),
+                            slice(None))] += acc
+
+    slots(0, n_real)
+    if n_real < f_tile:
+        pl.when(fi < last)(lambda: slots(n_real, f_tile))
+
+
 def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
-                 n_bin: int, m_pad: int, f_tile: int, precision_mode: str,
-                 rpl: int, rpa: int):
+                 n_bin: int, m_pad: int, f_tile: int, n_feat: int,
+                 precision_mode: str, rpl: int, rpa: int):
     """One (node_tile, feature_tile, row_tile) grid step.
 
     binned_ref: (f_tile, R) u8|int32 bin ids, feature-major
@@ -65,6 +127,8 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                 nodes of THIS node tile (grid dim 0) — deep levels
                 (n_node > m_pad) tile the node dim so the block never
                 outgrows VMEM.
+    n_feat:     the real feature count F: slots past it in the last
+                feature tile run no dot (:func:`_feature_dots`).
     rpl:        row tiles per lane.  The solo call passes its whole
                 row-tile count; the LANE-stacked call (gang-batched
                 multi-tenant training, _hist_pallas_lanes_pre) packs L
@@ -103,43 +167,15 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
     node_of_sub = m_base + jnp.where(sub < m_pad, sub, sub - m_pad)
     ghsel = jnp.where(sub < m_pad, gh_ref[0:1, :], gh_ref[1:2, :])
     active = (pos == node_of_sub)                            # (2M, R)
-
-    # TPU matmul default precision truncates f32 operands to bf16; fp32
-    # mode must request HIGHEST for exact (parity-testable) histograms.
-    # In bf16 mode, materialize the operands in bf16 up front: the MXU
-    # would truncate them anyway, and halving the one-hot's VMEM
-    # footprint is a measured ~20% kernel win (tools/hist_microbench.py).
-    # int8 mode (gh arrives PRE-QUANTIZED as int32, one-hot is int8,
-    # products accumulate exactly in int32): the v5e MXU runs int8 at
-    # 2x the bf16 rate with half the operand bytes — measured ~9x on
-    # the kernel, 0.55 vs ~4.7 ms/level (tools/hist_int8_proto.py).
-    if precision_mode == "int8":
-        gh_exp = jnp.where(active, ghsel, 0).astype(jnp.int8)
-        prec = jax.lax.Precision.DEFAULT
-        hot_dtype = jnp.int8
-        acc_dtype = jnp.int32
-    elif precision_mode == "fp32":
-        gh_exp = jnp.where(active, ghsel, 0.0)
-        prec = jax.lax.Precision.HIGHEST  # HIGH: unsupported by Mosaic
-        hot_dtype = jnp.float32
-        acc_dtype = jnp.float32
-    else:
-        gh_exp = jnp.where(active, ghsel, 0.0).astype(jnp.bfloat16)
-        prec = jax.lax.Precision.DEFAULT
-        hot_dtype = jnp.bfloat16
-        acc_dtype = jnp.float32
+    gh_exp = jnp.where(active, ghsel,
+                       0).astype(_mxu_mode(precision_mode)[0])
     # bins may arrive u8 (the entry's resident pre-transposed operand —
     # zero per-round transpose/layout-copy cost) or int32 (the
     # in-graph transpose fallback); widen in-register either way
     bins = binned_ref[:].astype(jnp.int32)                   # (f_tile, R)
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_bin, r_tile), 0)
-    for f in range(f_tile):
-        onehot = (bins[f:f + 1, :] == bin_ids).astype(hot_dtype)  # (B, R)
-        acc = jax.lax.dot_general(
-            onehot, gh_exp, (((1,), (1,)), ((), ())),
-            precision=prec,
-            preferred_element_type=acc_dtype)                # (B, 2M)
-        out_ref[0, f * n_bin:(f + 1) * n_bin, :] += acc
+    _feature_dots(bins, gh_exp, out_ref, (0,), pl.program_id(1),
+                  n_feat=n_feat, n_bin=n_bin, f_tile=f_tile,
+                  precision_mode=precision_mode)
 
 
 def _rows_per_acc(r_tile: int) -> int:
@@ -304,7 +340,8 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
-                               f_tile=f_tile, precision_mode=precision,
+                               f_tile=f_tile, n_feat=F,
+                               precision_mode=precision,
                                rpl=n_tiles, rpa=rpa)
     # a chunk's blocks follow the previous chunk's along the node-tile
     # axis; with one chunk the index map is the plain one
@@ -384,7 +421,8 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
-                               f_tile=f_tile, precision_mode=precision,
+                               f_tile=f_tile, n_feat=F,
+                               precision_mode=precision,
                                rpl=rpl, rpa=rpa)
 
     def acc_of(ri):                 # lane-major, a lane's chunks within
@@ -451,8 +489,8 @@ def build_level_histogram_pallas_lanes(binned: jax.Array, gh: jax.Array,
 
 
 def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
-                         n_bin: int, m_pad: int, f_tile: int, t_tile: int,
-                         precision_mode: str, rpa: int):
+                         n_bin: int, m_pad: int, f_tile: int, n_feat: int,
+                         t_tile: int, precision_mode: str, rpa: int):
     """Tree-batched variant of :func:`_hist_kernel`: the (B, R) one-hot
     is built ONCE per (feature, row tile) and contracted against a
     (R, t_tile*2M) operand whose lane l encodes (tree, grad/hess, node):
@@ -500,31 +538,13 @@ def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
         ghsel = jnp.where(sel, gval, ghsel)
         possel = jnp.where(sel, pos_ref[t:t + 1, :], possel)
 
-    if precision_mode == "int8":
-        gh_exp = jnp.where(possel == node_of, ghsel, 0).astype(jnp.int8)
-        prec = jax.lax.Precision.DEFAULT
-        hot_dtype = jnp.int8
-        acc_dtype = jnp.int32
-    elif precision_mode == "fp32":
-        gh_exp = jnp.where(possel == node_of, ghsel, 0.0)
-        prec = jax.lax.Precision.HIGHEST
-        hot_dtype = jnp.float32
-        acc_dtype = jnp.float32
-    else:
-        gh_exp = jnp.where(possel == node_of, ghsel,
-                           0.0).astype(jnp.bfloat16)
-        prec = jax.lax.Precision.DEFAULT
-        hot_dtype = jnp.bfloat16
-        acc_dtype = jnp.float32
+    gh_exp = jnp.where(possel == node_of, ghsel,
+                       0).astype(_mxu_mode(precision_mode)[0])
 
     bins = binned_ref[:].astype(jnp.int32)
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_bin, r_tile), 0)
-    for f in range(f_tile):
-        onehot = (bins[f:f + 1, :] == bin_ids).astype(hot_dtype)
-        acc = jax.lax.dot_general(
-            onehot, gh_exp, (((1,), (1,)), ((), ())),
-            precision=prec, preferred_element_type=acc_dtype)
-        out_ref[0, 0, f * n_bin:(f + 1) * n_bin, :] += acc
+    _feature_dots(bins, gh_exp, out_ref, (0, 0), pl.program_id(2),
+                  n_feat=n_feat, n_bin=n_bin, f_tile=f_tile,
+                  precision_mode=precision_mode)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -651,8 +671,9 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
     pos_t = pos.astype(jnp.int32).reshape(t_tiles, t_tile, n_pad)
 
     kernel = functools.partial(_batched_hist_kernel, n_bin=n_bin,
-                               m_pad=m_pad, f_tile=f_tile, t_tile=t_tile,
-                               precision_mode=precision, rpa=rpa)
+                               m_pad=m_pad, f_tile=f_tile, n_feat=F,
+                               t_tile=t_tile, precision_mode=precision,
+                               rpa=rpa)
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     out = pl.pallas_call(
         kernel,
