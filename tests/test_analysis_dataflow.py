@@ -649,11 +649,12 @@ class TestDonationGuard:
     def test_real_scan_rounds_dispatch_is_donation_clean(self,
                                                          monkeypatch):
         """The runtime cross-check of XGT013 over the REAL fused path:
-        wrap ``_scan_rounds_donated`` so CPU deletes donated buffers
-        like a TPU reuses them, force the donated path on, and train
-        multi-segment with evals — if ``do_boost_fused`` (or anything
-        downstream) read a donated margin after dispatch, this run
-        would raise 'Array has been deleted'."""
+        wrap ``_scan_rounds`` (the one wrapping: its carries are
+        donated on every backend, deleted on this one as a TPU reuses
+        them) and train multi-segment with evals — if
+        ``do_boost_fused`` (or anything downstream) read a donated
+        margin after dispatch, this run would raise 'Array has been
+        deleted'."""
         import xgboost_tpu as xgb
         from xgboost_tpu.analysis.runtime import DonationGuard
         from xgboost_tpu.learner import Booster
@@ -661,9 +662,7 @@ class TestDonationGuard:
 
         guard = DonationGuard(donate_argnums=(1, 11))
         monkeypatch.setattr(
-            gbtree, "_scan_rounds_donated",
-            guard.wrap(gbtree._scan_rounds_donated))
-        monkeypatch.setenv("XGBTPU_FUSED_DONATE", "1")
+            gbtree, "_scan_rounds", guard.wrap(gbtree._scan_rounds))
 
         rng = np.random.RandomState(0)
         X = rng.rand(400, 6).astype(np.float32)

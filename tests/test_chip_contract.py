@@ -126,10 +126,10 @@ def test_cache_path_is_built_from_nothing_that_moves():
 
 
 def test_entry_points_place_the_cache():
-    """cli, serving, bench and the smoke all go through the helper, and
+    """cli, serving and the smoke all go through the helper, and
     nothing else sets the directory."""
     for rel in ("xgboost_tpu/cli.py", "xgboost_tpu/serving/__main__.py",
-                "bench.py", "chip_smoke.py"):
+                "chip_smoke.py"):
         with open(os.path.join(REPO, rel)) as f:
             assert "configure_compile_cache()" in f.read(), rel
     offenders = []

@@ -25,6 +25,9 @@ from xgboost_tpu.compat import load_reference_model, parse_reference_model
 DATA = os.path.join(os.path.dirname(__file__), "data")
 AGARICUS_TEST = "/root/reference/demo/data/agaricus.txt.test"
 AGARICUS_TRAIN = "/root/reference/demo/data/agaricus.txt.train"
+needs_agaricus = pytest.mark.skipif(
+    not os.path.exists(AGARICUS_TRAIN),
+    reason="the reference's demo data is not on this container")
 
 
 @pytest.fixture(scope="module")
@@ -45,6 +48,7 @@ def test_parse_reference_model(ref_model_path):
     assert (stats["sum_hess"] > 0).all()
 
 
+@needs_agaricus
 def test_reference_model_predictions_match(ref_model_path):
     """Predictions from the loaded reference model must equal the
     reference CLI's own pred output."""
@@ -56,6 +60,7 @@ def test_reference_model_predictions_match(ref_model_path):
     np.testing.assert_allclose(preds, ref, rtol=1e-4, atol=1e-5)
 
 
+@needs_agaricus
 def test_reference_bs64_matches_binf():
     b1 = load_reference_model(os.path.join(DATA, "ref_agaricus.model"))
     b2 = load_reference_model(os.path.join(DATA, "ref_agaricus.bs64"))
@@ -63,6 +68,7 @@ def test_reference_bs64_matches_binf():
     np.testing.assert_array_equal(b1.predict(dtest), b2.predict(dtest))
 
 
+@needs_agaricus
 def test_booster_load_model_autodetects_reference(ref_model_path):
     """Booster(model_file=...) must transparently read reference files."""
     bst = xgb.Booster(model_file=ref_model_path)
@@ -71,6 +77,7 @@ def test_booster_load_model_autodetects_reference(ref_model_path):
     np.testing.assert_allclose(bst.predict(dtest), ref, rtol=1e-4, atol=1e-5)
 
 
+@needs_agaricus
 def test_save_base64_roundtrip(tmp_path):
     """Our own bs64 text-safe mode: save -> load -> bit-identical preds,
     and the file must be single-line printable text after the magic."""
@@ -90,6 +97,7 @@ def test_save_base64_roundtrip(tmp_path):
     np.testing.assert_array_equal(bst.predict(dtest), bst2.predict(dtest))
 
 
+@needs_agaricus
 def test_cli_save_base64(tmp_path):
     """CLI save_base64=1 writes the text-safe encoding."""
     from xgboost_tpu.cli import BoostLearnTask
@@ -107,6 +115,7 @@ def test_cli_save_base64(tmp_path):
 
 # ----------------------------------------------------------------- writer
 
+@needs_agaricus
 def test_reference_writer_self_roundtrip(tmp_path):
     """save_reference_model -> our own reference reader reproduces the
     predictions exactly (format-level self-consistency)."""

@@ -129,6 +129,8 @@ def test_binning_missing_bin_zero():
     assert B[1, 1] >= 1
 
 
+@pytest.mark.skipif(not os.path.exists(AGARICUS_TRAIN),
+                    reason="the reference's demo data is not here")
 def test_binning_agaricus_binary_features():
     dm = DMatrix(AGARICUS_TRAIN)
     cuts = compute_cuts(dm, max_bin=256)

@@ -373,7 +373,6 @@ def test_stacked_dispatch_failure_fails_the_run(tmp_path, monkeypatch):
         raise RuntimeError("Mosaic failed to compile the lane kernel")
 
     monkeypatch.setattr(gbtree, "_scan_rounds_lanes", refuse)
-    monkeypatch.setattr(gbtree, "_scan_rounds_lanes_donated", refuse)
     lanes = make_lanes(tmp_path, "refused", 2, cycles=2)
     solo_before = dict(lane_metrics().solo.values())
     res = run_tenant_lanes(lanes, quiet=True, stacked=True,

@@ -4,7 +4,7 @@ Gradients are dyadic rationals (multiples of 1/256, bounded), so f32
 summation is exact in ANY order — bitwise equality between the MXU
 matmul formulation and the scatter-add is required, not just allclose.
 Runs in Pallas interpret mode on the CPU test platform; the same kernels
-compile on TPU (exercised by bench.py / the driver's real-chip run).
+compile on TPU (exercised by chip_smoke.py --kernels and benchmark/).
 """
 
 import numpy as np

@@ -251,8 +251,8 @@ def test_engine_reports_chunk_layout_and_observes_seconds():
 
 def test_chunk_knob_resolution(monkeypatch):
     """XGBTPU_PREDICT_TREE_CHUNK is the end-to-end A/B seam; the -1
-    auto default resolves per backend (scan on CPU — measured slower
-    there, tools/predict_microbench.py); an explicit param forces."""
+    auto default resolves per backend (scan on CPU); an explicit param
+    forces."""
     import jax
     monkeypatch.setenv("XGBTPU_PREDICT_TREE_CHUNK", "8")
     bst, X, _ = _train(rounds=3)

@@ -19,6 +19,14 @@ AGARICUS = "/root/reference/demo/data/agaricus.txt.train"
 
 
 def _head(path, n_lines=400):
+    """The first lines of the reference's agaricus file; where the
+    container has none, as many seeded one-hot libsvm lines."""
+    if not os.path.exists(path):
+        rng = np.random.RandomState(0)
+        return b"".join(
+            b"%d %s\n" % (rng.randint(2), b" ".join(
+                b"%d:1" % j for j in sorted(rng.choice(126, 22, False))))
+            for _ in range(n_lines))
     with open(path, "rb") as f:
         return b"".join(f.readline() for _ in range(n_lines))
 

@@ -135,31 +135,126 @@ def test_watchlist_metrics_multiclass_multi_metric():
     assert bytes(b0.save_raw()) == bytes(b3.save_raw())
 
 
-def test_env_override_forces_per_round(monkeypatch):
-    """XGBTPU_ROUNDS_PER_DISPATCH=0 is the A/B switch: it beats both the
-    param and the call-site override, and the plan reports k=0."""
-    monkeypatch.setenv("XGBTPU_ROUNDS_PER_DISPATCH", "0")
+@pytest.mark.parametrize("keyword,plan", [(0, 0), (None, 8), (3, 3)])
+def test_keyword_beats_parameter(keyword, plan):
+    """K has two sources: ``update_many``'s keyword, else the train
+    parameter.  The keyword wins (0 = the per-round A/B switch); left
+    out, the parameter decides; the plan is reported once."""
     X, y = make_data(n=400)
     d = xgb.DMatrix(X, label=y)
     bst = Booster({**PARAMS, "rounds_per_dispatch": 8}, cache=[d])
     plans = []
     bst.update_many(d, 0, 3, plan_callback=plans.append,
-                    rounds_per_dispatch=16)
-    assert plans == [0]
+                    rounds_per_dispatch=keyword)
+    assert plans == [plan]
     assert bst.gbtree.num_trees == 3
 
 
-def test_auto_plan_from_round_model():
-    """rounds_per_dispatch=-1 (the default) sizes segments from the
-    fitted round model: some k in [1, 64], reported once via
-    plan_callback."""
+@pytest.mark.parametrize("rows,k", [
+    (400, 64), (63_952, 64), (63_953, 63), (100_000, 41), (1_000_000, 5),
+    (2_014_516, 3), (2_014_517, 2), (4_029_033, 2), (4_029_034, 1),
+    (8_400_000, 1), (40_000_000, 1)])
+def test_auto_k_by_rows(rows, k):
+    """rounds_per_dispatch=-1 (the default) is
+    clamp(ceil(AUTO_DISPATCH_ROWS / rows), 1, 64): the values the
+    retired round-model file gave, at each boundary and at the
+    benchmark cells' row counts.  No data of that size: the resolver
+    reads a row count."""
+    bst = Booster(PARAMS)
+    assert bst.param.rounds_per_dispatch == -1
+    assert bst._resolve_rounds_per_dispatch(rows) == k
+
+
+def test_auto_plan_reported_once():
     X, y = make_data(n=400)
     d = xgb.DMatrix(X, label=y)
     bst = Booster(PARAMS, cache=[d])
     plans = []
     bst.update_many(d, 0, 2, plan_callback=plans.append)
-    assert len(plans) == 1 and 1 <= plans[0] <= 64
+    assert plans == [64]
     assert bst.gbtree.num_trees == 2
+
+
+def _block(bst, d, reason, monkeypatch):
+    """Put ``bst`` into the state that raises ``reason`` alone (rows
+    that are parameters are set by the caller)."""
+    bst._lazy_init(d)
+    if reason == "col_split":
+        bst._col_mesh = object()
+    elif reason == "seq_boost_env":
+        monkeypatch.setenv("XGBTPU_SEQ_BOOST", "1")
+    elif reason == "exact":
+        bst.gbtree.exact_raw = True
+    elif reason == "no_fused_grad":
+        monkeypatch.setattr(bst.obj, "fused_grad",
+                            lambda info=None, **kw: None)
+
+
+@pytest.mark.parametrize("reason,extra", [
+    ("external_train", {}), ("col_split", {}), ("seq_boost_env", {}),
+    ("profiler", {"profile": 1}), ("prune", {"gamma": 0.5}),
+    ("multi_root", {"num_roots": 2}), ("exact", {}),
+    ("refresh", {"updater": "grow_histmaker,refresh"}),
+    ("no_grow_updater", {"updater": "prune"}), ("no_fused_grad", {})])
+def test_solo_and_lane_decline_for_the_same_reason(reason, extra,
+                                                   monkeypatch, tmp_path):
+    """The ten eligibility rows a solo run and a gang lane share: the
+    lane is declined with, and the solo run's fallback counter gains,
+    the same first reason.  The per-round fallback itself is stubbed:
+    the decision is under test, not the training it routes to."""
+    from xgboost_tpu.obs import training_metrics
+    monkeypatch.delenv("XGBTPU_SEQ_BOOST", raising=False)
+    X, y = make_data(n=300)
+    if reason == "external_train":
+        from xgboost_tpu.external import ExtMemDMatrix
+        monkeypatch.setenv("XGTPU_EXT_DEVICE_CACHE_MB", "0")  # paged
+        d = ExtMemDMatrix(iter([(X, y)]), cache=str(tmp_path / "c"),
+                          page_rows=128)
+    else:
+        d = xgb.DMatrix(X, label=y)
+    bst = Booster({**PARAMS, **extra}, cache=[d])
+    _block(bst, d, reason, monkeypatch)
+    assert bst.fused_lane_spec(d, 0, 3) == (None, reason)
+    monkeypatch.setattr(Booster, "update", lambda self, *a, **k: None)
+    fb = training_metrics().fused_fallback
+    before = dict(fb.values())
+    plans = []
+    bst.update_many(d, 0, 3, plan_callback=plans.append)
+    grew = {r: v - before.get(r, 0) for r, v in fb.values().items()
+            if v != before.get(r, 0)}
+    assert grew == {reason: 1} and plans == [0]
+
+
+def test_zero_round_call_builds_the_watchlist_entries():
+    """``update_many(d, 0, 0, evals=...)`` is how a caller asks for the
+    device entries of the training set AND of every watchlist member
+    (benchmark/run.py ends its ingest stopwatch on it): both are in
+    ``Booster._cache`` afterwards, binned, and no tree was grown."""
+    X, y = make_data(n=400)
+    Xh, yh = make_data(n=200, seed=9)
+    d, held = xgb.DMatrix(X, label=y), xgb.DMatrix(Xh, label=yh)
+    bst = Booster(PARAMS, cache=[d])
+    assert id(held) not in bst._cache
+    bst.update_many(d, 0, 0, evals=[(held, "t")])
+    assert id(d) in bst._cache and id(held) in bst._cache
+    assert bst._cache[id(held)].binned.shape == (200, 8)
+    assert bst.gbtree.num_trees == 0
+
+
+def test_base_margin_outlives_the_donated_dispatch():
+    """The fused scan donates the entry's margin; the entry's BASE
+    margin has to be another buffer, since an ntree_limit prediction on
+    the cached training matrix (and any margin rebuild) reads it after
+    training.  (They were one array until PR 33: deleted by the first
+    dispatch on a backend that honours donation.)"""
+    X, y = make_data(n=400)
+    d = xgb.DMatrix(X, label=y)
+    bst = Booster(PARAMS, cache=[d])
+    bst.update_many(d, 0, 4, rounds_per_dispatch=2)
+    assert not bst._cache[id(d)].base.is_deleted()
+    np.testing.assert_array_equal(
+        np.asarray(bst.predict(d, ntree_limit=1)),
+        np.asarray(bst.predict(xgb.DMatrix(X), ntree_limit=1)))
 
 
 def test_segment_compile_budget(recompile_guard):

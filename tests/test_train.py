@@ -2,6 +2,8 @@
 tests (SURVEY.md §4: demo/binary_classification mushroom.conf, regression,
 custom objective path)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -10,11 +12,50 @@ import xgboost_tpu as xgb
 AGARICUS_TRAIN = "/root/reference/demo/data/agaricus.txt.train"
 AGARICUS_TEST = "/root/reference/demo/data/agaricus.txt.test"
 
+# the 22 attributes of the UCI mushroom table by number of values: 126
+# one-hot columns, as the reference's agaricus.txt has them
+_CARD = (6, 4, 10, 2, 9, 4, 3, 2, 12, 2, 7, 4, 4, 9, 9, 2, 4, 3, 8, 9, 6, 7)
+_ODOR, _GILL_SIZE, _SPORE = 4, 7, 19
+
+
+def _write_agaricus_like(path, n, rng):
+    """A stand-in for one agaricus file, in libsvm text: every row one
+    value per attribute (skewed frequencies), label "poisonous" by a
+    rule over three attributes — two odors, or one spore print under a
+    narrow gill — with one exception in a thousand, so a few shallow
+    trees separate it as they do the real table."""
+    offs = np.concatenate([[0], np.cumsum(_CARD)[:-1]])
+    vals = np.empty((n, len(_CARD)), np.int64)
+    for a, c in enumerate(_CARD):
+        p = 0.6 ** np.arange(c)
+        vals[:, a] = rng.choice(c, size=n, p=p / p.sum())
+    y = ((vals[:, _ODOR] == 1) | (vals[:, _ODOR] == 2)
+         | ((vals[:, _SPORE] == 1) & (vals[:, _GILL_SIZE] == 1)))
+    y = y ^ (rng.rand(n) < 0.001)
+    with open(path, "w") as f:
+        for lab, row in zip(y.astype(int), vals + offs):
+            f.write(f"{lab} " + " ".join(f"{j}:1" for j in row) + "\n")
+
+
+@pytest.fixture(scope="session")
+def agaricus_files(tmp_path_factory):
+    """(train, test) paths: the reference's demo files where this
+    container has them, else a seeded stand-in of the same size and
+    shape (6,513 + 1,611 rows, 126 one-hot columns) written once."""
+    if os.path.exists(AGARICUS_TRAIN) and os.path.exists(AGARICUS_TEST):
+        return AGARICUS_TRAIN, AGARICUS_TEST
+    d = tmp_path_factory.mktemp("agaricus")
+    rng = np.random.RandomState(20141)
+    train, test = str(d / "agaricus.txt.train"), str(d / "agaricus.txt.test")
+    _write_agaricus_like(train, 6513, rng)
+    _write_agaricus_like(test, 1611, rng)
+    return train, test
+
 
 @pytest.fixture(scope="module")
-def agaricus():
-    dtrain = xgb.DMatrix(AGARICUS_TRAIN)
-    dtest = xgb.DMatrix(AGARICUS_TEST, num_col=dtrain.num_col)
+def agaricus(agaricus_files):
+    dtrain = xgb.DMatrix(agaricus_files[0])
+    dtest = xgb.DMatrix(agaricus_files[1], num_col=dtrain.num_col)
     return dtrain, dtest
 
 
@@ -113,13 +154,14 @@ def test_early_stopping(agaricus):
     assert bst.best_score < 0.1
 
 
-def test_profile_round_breakdown(agaricus, capsys):
+def test_profile_round_breakdown(agaricus, agaricus_files, capsys):
     """profile=1 emits per-round phase timing + summary (SURVEY.md §5.1
     report_stats analog) without changing results."""
     dtrain, dtest = agaricus
     params = {"eta": 1.0, "max_depth": 3, "objective": "binary:logistic"}
     p_plain = xgb.train(params, dtrain, 2, verbose_eval=False).predict(dtest)
-    bst = xgb.train({**params, "profile": 1}, xgb.DMatrix(AGARICUS_TRAIN), 2,
+    bst = xgb.train({**params, "profile": 1},
+                    xgb.DMatrix(agaricus_files[0]), 2,
                     evals=[(dtest, "eval")], verbose_eval=False)
     err = capsys.readouterr().err
     assert "[prof] round 0:" in err and "grow=" in err
@@ -145,7 +187,7 @@ def test_weights_affect_training():
     assert preds[y == 1].mean() > 0.8
 
 
-def test_base_margin(agaricus):
+def test_base_margin(agaricus, agaricus_files):
     """boost_from_prediction demo: margin continuation must equal training
     longer (demo/guide-python/boost_from_prediction.py)."""
     dtrain, _ = agaricus
@@ -155,7 +197,7 @@ def test_base_margin(agaricus):
 
     bst_a = xgb.train(params, dtrain, 2, verbose_eval=False)
     ptrain = bst_a.predict(dtrain, output_margin=True)
-    dtrain2 = xgb.DMatrix(AGARICUS_TRAIN)
+    dtrain2 = xgb.DMatrix(agaricus_files[0])
     dtrain2.set_base_margin(ptrain)
     bst_b = xgb.train(params, dtrain2, 2, verbose_eval=False)
     m2 = bst_b.predict(dtrain2, output_margin=True)

@@ -1,6 +1,8 @@
 """Updater tests: exact colmaker, prune, refresh, distcol (reference
 updater registry src/tree/updater.cpp:18-31)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -274,11 +276,10 @@ def test_multi_root_trees_route_by_root_index():
     assert dumps[0].count(":[") >= 2  # at least one split under each root
 
 
-def test_exact_mode_presence_only_and_agaricus_canonical():
+def test_exact_mode_presence_only():
     """Exact mode proposes missing-vs-present splits (the reference's
     end-of-scan candidates) — the ONLY split on presence-only one-hot
-    columns — and reproduces the reference's canonical exact-greedy
-    agaricus numbers."""
+    columns."""
     import xgboost_tpu as xgb
 
     rng = np.random.RandomState(0)
@@ -296,8 +297,20 @@ def test_exact_mode_presence_only_and_agaricus_canonical():
               verbose_eval=False)
     assert r["train-error"][-1] < 0.01, r
 
-    dtrain = xgb.DMatrix("/root/reference/demo/data/agaricus.txt.train")
-    dtest = xgb.DMatrix("/root/reference/demo/data/agaricus.txt.test",
+
+AGARICUS_TRAIN = "/root/reference/demo/data/agaricus.txt.train"
+AGARICUS_TEST = "/root/reference/demo/data/agaricus.txt.test"
+
+
+@pytest.mark.skipif(not os.path.exists(AGARICUS_TRAIN),
+                    reason="the reference's demo data is not here")
+def test_exact_mode_agaricus_canonical():
+    """Exact mode reproduces the reference's canonical exact-greedy
+    agaricus numbers."""
+    import xgboost_tpu as xgb
+
+    dtrain = xgb.DMatrix(AGARICUS_TRAIN)
+    dtest = xgb.DMatrix(AGARICUS_TEST,
                         num_col=dtrain.num_col)
     r = {}
     xgb.train({"objective": "binary:logistic", "max_depth": 3, "eta": 1.0,
@@ -309,70 +322,3 @@ def test_exact_mode_presence_only_and_agaricus_canonical():
     assert r["test-error"][0] == pytest.approx(0.016139, abs=2e-6)
     assert r["train-error"][1] == pytest.approx(0.001228, abs=2e-6)
     assert r["test-error"][1] == 0.0
-
-
-def test_hist_subtraction_env_gate():
-    """Histogram subtraction is env-gated (XGBTPU_HIST_SUBTRACTION=1),
-    not a config param (measured ~10x regression on TPU — the public
-    surface carries no known-slower knob; advisor round 4).
-
-    The invariant is HISTOGRAM equality (the subtracted sibling differs
-    from the direct build only by accumulation-order noise, ~1e-5);
-    end-to-end predictions may legitimately diverge when that noise
-    crosses a near-tie gain, so the e2e check is metric-level."""
-    import os
-    import numpy as np
-    import jax.numpy as jnp
-    import xgboost_tpu as xgb
-    from xgboost_tpu.models import tree as T
-    from xgboost_tpu.ops.histogram import build_level_histogram
-
-    rng = np.random.RandomState(0)
-    N, F, B = 512, 4, 8
-    binned = jnp.asarray(rng.randint(0, B, (N, F)).astype(np.uint8))
-    gh = jnp.asarray(rng.rand(N, 2).astype(np.float32))
-    pos = jnp.asarray((rng.rand(N) < 0.3).astype(np.int32))
-
-    class Cfg:
-        n_bin = B
-        hist_precision = "fp32"
-
-    hist_parent = build_level_histogram(
-        binned, gh, jnp.zeros(N, jnp.int32), 1, B, "fp32")
-    h_sub = T._subtracted_level_hist(binned, gh, pos, 2, Cfg, lambda x: x,
-                                     hist_parent, jnp.asarray([True]))
-    h_full = build_level_histogram(binned, gh, pos, 2, B, "fp32")
-    np.testing.assert_allclose(np.asarray(h_sub), np.asarray(h_full),
-                               atol=1e-4)
-
-    # e2e: the gated path trains to the same quality; a passed
-    # hist_subtraction param is ignored with a warning (still trains)
-    X = rng.rand(2000, 6).astype(np.float32)
-    y = (X[:, 0] + 0.3 * rng.rand(2000) > 0.6).astype(np.float32)
-
-    def logloss(extra_params=None):
-        d = xgb.DMatrix(X, label=y)
-        p = dict({"objective": "binary:logistic", "max_depth": 4,
-                  "eta": 0.3, "silent": 1}, **(extra_params or {}))
-        pr = xgb.train(p, d, 5).predict(d)
-        eps = 1e-7
-        return float(-np.mean(y * np.log(pr + eps)
-                              + (1 - y) * np.log(1 - pr + eps)))
-
-    base = logloss()
-    os.environ["XGBTPU_HIST_SUBTRACTION"] = "1"
-    try:
-        sub = logloss()
-    finally:
-        del os.environ["XGBTPU_HIST_SUBTRACTION"]
-    assert abs(base - sub) < 1e-3, (base, sub)
-    assert abs(logloss({"hist_subtraction": 1}) - base) < 1e-12
-    # silent=0 actually emits the demotion warning (once per process)
-    import contextlib
-    import io
-    from xgboost_tpu.models import gbtree as GB
-    GB._WARNED.discard("hist_subtraction")
-    err = io.StringIO()
-    with contextlib.redirect_stderr(err):
-        logloss({"hist_subtraction": 1, "silent": 0})
-    assert "hist_subtraction is no longer a parameter" in err.getvalue()

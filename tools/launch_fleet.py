@@ -17,8 +17,8 @@ Usage::
 Ctrl-C drains: replicas get SIGTERM (their drain state machine
 finishes in-flight requests and deregisters), then the router stops.
 
-The :class:`FleetLauncher` class is importable — tools/bench_fleet.py
-and tools/chaos_loop.py ``--fleet`` drive fleets through it.
+The :class:`FleetLauncher` class is importable — tools/chaos_loop.py
+``--fleet`` drives fleets through it.
 """
 
 from __future__ import annotations
@@ -44,8 +44,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 class RetryingPredictClient:
-    """Keep-alive ``POST /predict`` client shared by the fleet drivers
-    (tools/bench_fleet.py, tools/chaos_loop.py ``--fleet``).
+    """Keep-alive ``POST /predict`` client of the fleet drivers
+    (tools/chaos_loop.py ``--fleet``).
 
     A reset/close on a REUSED keep-alive connection is the standard
     retry-safe race (RFC 7230 §6.3.1): every real HTTP client retries

@@ -66,13 +66,12 @@ def _write_libsvm(path: str, X, y, fmt: str = "%.6g"):
 
 
 def make_higgs(workdir: str, n: int, tag: str):
-    """Synthetic Higgs-like binary data (same generator as bench.py)."""
+    """Synthetic Higgs-like binary data (chip_smoke.py's generator)."""
     train = os.path.join(workdir, f"higgs{tag}.train")
     test = os.path.join(workdir, f"higgs{tag}.test")
     if os.path.exists(train) and os.path.exists(test):
         return train, test
-    sys.path.insert(0, REPO)
-    from bench import make_higgs_like
+    from chip_smoke import make_higgs_like
     X, y = make_higgs_like(n + max(50_000, n // 5))
     print(f"[parity] writing {train} ...", flush=True)
     _write_libsvm(train, X[:n], y[:n])
@@ -239,7 +238,7 @@ def write_report(results: dict):
         "Produced by `python tools/parity.py` on this host "
         "(reference built from `/root/reference`, single-core CPU; "
         "ours run with JAX_PLATFORMS=cpu for metric parity — TPU "
-        "throughput is bench.py's job).  Synthetic stand-ins are used "
+        "throughput is benchmark/'s job).  Synthetic stand-ins are used "
         "where the reference demo data is not bundled (higgs/derma/rank); "
         "both sides read the same libsvm files.",
         "",
@@ -260,19 +259,12 @@ def write_report(results: dict):
         b = results["baseline_1m"]
         lines += [
             "",
-            "## Measured CPU baseline (anchors bench.py)",
+            "## Measured CPU baseline",
             "",
             f"Reference CLI, Higgs-1M x 28, depth 6, eta 0.1, "
             f"{b['rounds']} rounds, **1 thread** (this host has 1 core): "
             f"{b['train_sec']:.0f} s -> "
             f"**{b['rows_per_sec_1thread']:,.0f} rows/s/thread**.",
-            "",
-            "bench.py uses this rows/s/thread as the `vs_baseline` "
-            "denominator against our rows/s/chip: with 16 chips per "
-            "v5e-16 pod and 16 threads per CPU socket the factors "
-            "cancel, so the single-chip ratio equals the pod-vs-socket "
-            "wall-clock ratio under (generous) perfect-linear CPU "
-            "scaling.",
         ]
     lines += [
         "",

@@ -41,7 +41,7 @@ def make_data(workdir: str, seed: int, n: int = 250_000, n_test: int = 50_000):
     test = os.path.join(workdir, f"sweep_s{seed}.test")
     if os.path.exists(train) and os.path.exists(test):
         return train, test
-    from bench import make_higgs_like
+    from chip_smoke import make_higgs_like
     X, y = make_higgs_like(n + n_test, seed=seed * 977 + 42)
     _write_libsvm(train, X[:n], y[:n])
     _write_libsvm(test, X[n:], y[n:])

@@ -21,10 +21,11 @@ cause, in real executions under pytest:
 - :class:`DonationGuard` is the runtime twin of the static XGT013
   use-after-donate rule: it wraps a ``donate_argnums`` jitted callable
   and, after each call, DELETES the device buffers the caller handed
-  over at donated positions — which is exactly what donation does on
-  TPU but what CPU silently skips (JAX warns and copies).  A caller
-  that touches a donated buffer post-call then raises loudly under
-  test on any backend, instead of reading garbage only on device.
+  over at donated positions — which is what a donation that the
+  backend took does anyway, and what one it declined (JAX warns and
+  copies) does not.  A caller that touches a donated buffer post-call
+  then raises loudly under test on any backend, instead of reading
+  garbage only on device.
 
 All record violations instead of raising at the fault site, so a
 stress test collects everything and fails once with the full report
@@ -275,14 +276,16 @@ class LockRaceChecker:
 class DonationGuard:
     """Runtime use-after-donate detector (dynamic twin of XGT013).
 
-    ``donate_argnums`` donation is a no-op on CPU — JAX warns once and
-    copies — so the whole tier-1 suite can pass while every donated
-    dispatch reads freed memory on TPU.  This guard makes CPU behave
-    like the device: :meth:`wrap` returns a shim that, after each call
-    completes, ``delete()``-s every jax-array leaf the caller passed at
-    a donated position.  From then on any caller-side touch of that
-    buffer raises JAX's own "Array has been deleted" — the runtime
-    observation of exactly the reads XGT013 flags statically.
+    A backend may decline a ``donate_argnums`` donation (the pinned
+    jax honours it on CPU as on TPU, and warns where it could not use
+    one), and a suite can then pass while a donated dispatch reads
+    freed memory elsewhere.  This guard makes every backend behave
+    like one that reused the buffer: :meth:`wrap` returns a shim that,
+    after each call completes, ``delete()``-s every jax-array leaf the
+    caller passed at a donated position.  From then on any
+    caller-side touch of that buffer raises JAX's own "Array has been
+    deleted" — the runtime observation of exactly the reads XGT013
+    flags statically.
 
     Two hazards are RECORDED rather than raised, so a multi-dispatch
     test collects everything and fails once via :meth:`assert_clean`:
@@ -300,9 +303,9 @@ class DonationGuard:
     Usage (the integration test drives the REAL fused dispatch)::
 
         guard = DonationGuard(donate_argnums=(1, 11))
-        monkeypatch.setattr(gbtree, "_scan_rounds_donated",
-                            guard.wrap(gbtree._scan_rounds_donated))
-        ... run update_many with XGBTPU_FUSED_DONATE=1 ...
+        monkeypatch.setattr(gbtree, "_scan_rounds",
+                            guard.wrap(gbtree._scan_rounds))
+        ... run update_many ...
         assert guard.calls > 0
         guard.assert_clean()
     """

@@ -1,6 +1,6 @@
 """Placement of JAX's persistent compilation cache — one rule for every
 entry point (``python -m xgboost_tpu``, ``python -m xgboost_tpu.serving``,
-``bench.py``, ``chip_smoke.py``).
+``chip_smoke.py``).
 
 The cache directory is part of how a run is deployed, so it is placed
 from OUTSIDE the program: where ``JAX_COMPILATION_CACHE_DIR`` is set,

@@ -89,13 +89,6 @@ class TrainParam:
     # device count; ops/histogram.FIXED_SCALE documents resolution).
     # XGBTPU_HIST remains an env override (test seam).
     hist_precision: str = "auto"
-    # histogram subtraction + row compaction (build only the smaller
-    # child per parent, derive the sibling as parent - small) is NOT a
-    # config param: XLA row compaction cost an order of magnitude
-    # more than the kernel time it saved when it was tried (pre-round
-    # record; PERF.md "Carried over"), so the public surface carries
-    # no known-slower knob.  The A/B stays reachable for kernel work via
-    # env XGBTPU_HIST_SUBTRACTION=1 (numerics tested equal).
     # bin-count alignment quantum for the int8 MXU histogram kernel:
     # the one-hot operand tiles sublanes in 32s, so an unaligned bin
     # count (e.g. 67) pads to the next multiple (96) and wastes up to
@@ -127,8 +120,8 @@ class TrainParam:
     # padded_tree_count ladder so one compilation serves every size in
     # a chunk band.  -1 auto = 32 on TPU (batched compare-selects
     # replace the per-tree chain of dependent level launches), scan on
-    # CPU (measured SLOWER there — tools/predict_microbench.py;
-    # the TPU width is not measured on this machine); 0/1 = force the
+    # CPU (measured slower there in a pre-round A/B; the TPU width is
+    # not measured on this machine); 0/1 = force the
     # sequential scan baseline;
     # >1 = force that chunk width.  XGBTPU_PREDICT_TREE_CHUNK env
     # overrides for A/Bs.
@@ -137,11 +130,10 @@ class TrainParam:
     # rounds run per fused _scan_rounds dispatch — the host is touched
     # only at segment boundaries (eval lines, periodic saves and
     # checkpoints all still land per round / per boundary, bit-identical
-    # to the per-round path).  -1 auto = choose from the fitted round
-    # model (ROUND_MODEL.json: segment long enough that the fixed
-    # per-dispatch cost is <=10% of the dispatch, clamped to [1, 64]);
-    # 0 = per-round dispatch (the A/B baseline); >0 = that segment
-    # size.  XGBTPU_ROUNDS_PER_DISPATCH env overrides for A/Bs.
+    # to the per-round path).  -1 auto = by the training set's rows,
+    # clamped to [1, 64] (Booster.AUTO_DISPATCH_ROWS); 0 = per-round
+    # dispatch (the A/B baseline); >0 = that segment size.
+    # update_many's rounds_per_dispatch= keyword overrides per call.
     rounds_per_dispatch: int = -1
     # multi-root trees (reference TreeParam::num_roots, tree/param.h):
     # rows enter the tree at per-row roots given by the root_index meta

@@ -352,7 +352,7 @@ class ExtMemDMatrix:
         falling back to 2048MB when the backend reports no stats (CPU)."""
         assert self._binned_mm is not None, "call build_binned first"
         # canonical XGBTPU_ prefix; the pre-round-8 XGTPU_ spelling is
-        # still honored (bench.py and older A/B scripts set it)
+        # still honored (older A/B scripts set it)
         env = os.environ.get("XGBTPU_EXT_DEVICE_CACHE_MB",
                              os.environ.get("XGTPU_EXT_DEVICE_CACHE_MB"))
         if env is not None:

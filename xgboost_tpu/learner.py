@@ -15,6 +15,7 @@ the reference's pred_buffer/pred_counter design
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -39,8 +40,7 @@ def _predict_upload_depth() -> int:
     many f32 row blocks stage ahead of the quantize+traverse consuming
     them (external._prefetch_to_device).  2 = double-buffered (block
     k+1 uploads while block k computes); 1 = single lookahead; 0 =
-    synchronous.  ``XGBTPU_PREDICT_UPLOAD_DEPTH`` is the A/B seam
-    (tools/predict_microbench.py e2e cells)."""
+    synchronous.  ``XGBTPU_PREDICT_UPLOAD_DEPTH`` is the A/B seam."""
     try:
         return max(0, int(os.environ.get("XGBTPU_PREDICT_UPLOAD_DEPTH",
                                          "2")))
@@ -832,13 +832,19 @@ class Booster:
             # base instead of serving a mixed window
             entry.margin = None
             entry.applied = 0
-        if entry.margin is None:
-            entry.margin = jnp.broadcast_to(
-                entry.base, (entry.binned.shape[0], self._K)).astype(jnp.float32)
         if self.param.booster == "gblinear":
             entry.margin = self.gbtree.predict_margin(entry.binned, entry.base)
             entry.applied = self.gbtree.version
             return
+        if entry.margin is None:
+            # a buffer of its own (copy=True): where base already has
+            # the margin's shape and dtype, broadcast_to and astype hand
+            # back base ITSELF, and the fused scan donates its margin —
+            # base must outlive that (ntree_limit predictions and every
+            # later rebuild read it)
+            entry.margin = jnp.array(jnp.broadcast_to(
+                entry.base, (entry.binned.shape[0], self._K)),
+                jnp.float32, copy=True)
         per_round = self._K * max(1, self.param.num_parallel_tree)
         while entry.applied < self.gbtree.num_trees:
             chunk = self.gbtree.trees[entry.applied:entry.applied + per_round]
@@ -963,39 +969,86 @@ class Booster:
             if prof and entry.margin is not None:
                 p.block(entry.margin)
 
+    # Rows at which auto-K reaches 1: 9 x the fixed cost of a dispatch
+    # over the cost of a row, 9 * 4.46514 ms / 9.97417 ns.  Both come
+    # from a pre-round fit at 64 bins (125k...1M rows x 28) on a machine
+    # that is gone; ROADMAP S8(c) replaces the number with one measured
+    # on the benchmark's cells.
+    AUTO_DISPATCH_ROWS = 4_029_033.36
+
     def _resolve_rounds_per_dispatch(self, n_rows: int,
                                      override=None) -> int:
-        """Segment size K for fused training dispatches.  Priority:
-        env ``XGBTPU_ROUNDS_PER_DISPATCH`` > explicit ``override`` >
-        the ``rounds_per_dispatch`` train param.  ``-1`` (auto) sizes
-        the segment from the fitted round model (ROUND_MODEL.json) so
-        the fixed per-dispatch cost amortizes to <=10% of the dispatch
-        — ``K >= 9 * fixed / (per_row * rows)`` — clamped to [1, 64]
-        (past 64 the fixed term is noise and longer segments only delay
-        eval lines / checkpoints).  ``0`` = per-round dispatch, the A/B
-        baseline.  The fit is a pre-round record from another machine:
-        the per-dispatch cost is not measured on this one (ROADMAP
-        S8)."""
-        import math
-        env = os.environ.get("XGBTPU_ROUNDS_PER_DISPATCH")
-        if env not in (None, ""):
-            k = int(env)
-        elif override is not None:
-            k = int(override)
-        else:
-            k = int(self.param.rounds_per_dispatch)
+        """Segment size K for fused training dispatches: the explicit
+        ``override`` (``update_many``'s keyword) if given, else the
+        ``rounds_per_dispatch`` train param.  ``-1`` (auto) sizes the
+        segment so the fixed per-dispatch cost amortizes to <=10% of
+        the dispatch — ``K = ceil(AUTO_DISPATCH_ROWS / rows)`` —
+        clamped to [1, 64] (past 64 the fixed term is noise and longer
+        segments only delay eval lines / checkpoints).  ``0`` =
+        per-round dispatch, the A/B baseline."""
+        k = int(self.param.rounds_per_dispatch if override is None
+                else override)
         if k >= 0:
             return k
-        from xgboost_tpu.parallel.commcost import fitted_round_model
-        m = fitted_round_model() or {}
-        # baked defaults = the committed ROUND_MODEL.json fit, so auto
-        # still sizes sanely when the file is missing
-        fixed = float(m.get("fixed_round_s", 4.465e-3))
-        per_row = float(m.get("per_row_s", 9.974e-9))
-        per_round = per_row * max(1, int(n_rows))
-        if per_round <= 0.0 or fixed <= 0.0:
-            return 16
-        return max(1, min(64, math.ceil(9.0 * fixed / per_round)))
+        return max(1, min(64, math.ceil(
+            self.AUTO_DISPATCH_ROWS / max(1, int(n_rows)))))
+
+    def _fused_grad(self, entry):
+        """The objective's jittable gradient for ``entry`` (None when
+        it has none): the rank-padded relayout's, where the entry
+        carries one."""
+        if entry.rank_pad_prep is not None:
+            return self.obj.fused_grad(entry.info,
+                                       pad_prep=entry.rank_pad_prep)
+        return self.obj.fused_grad(entry.info)
+
+    def _fused_blockers(self, entry, ups, *, lanes: bool, fobj=None,
+                        n_rounds: int, evals=(), feval=None) -> list:
+        """Why this job may not run as fused segments: the reasons in
+        table order, empty when it may (the first is the one
+        ``xgbtpu_train_fused_fallback_total`` and a declined lane
+        report).  ONE table for :meth:`update_many` (``lanes=False``)
+        and :meth:`fused_lane_spec` (``lanes=True``); ``lanes`` only
+        selects the rows that exist for one caller alone.  ``evals`` is
+        ``update_many``'s ``(dmat, name, entry, is_train)`` watchlist.
+
+        Fault injection (mock) does not block fusion — do_boost_fused
+        replays the injector's round/seqno coordinates before each
+        dispatch.  Sharded watchlist sets ride the scan carry like any
+        mesh entry; their eval lines reduce metric partials via
+        ShardedDMatrix.allsum (_eval_parts_sharded) — only a custom
+        feval (needs the full vector on one host) excludes them.
+        External-memory sets still page batches per round."""
+        solo = not lanes
+        checks = (
+            ("custom_objective", solo and fobj is not None),
+            ("single_round", solo and n_rounds <= 1),
+            ("booster", solo and self.param.booster != "gbtree"),
+            ("no_rounds", lanes and n_rounds < 1),
+            ("external_train", bool(entry.external)),
+            ("mesh", lanes and self._mesh is not None),
+            ("col_split", self._col_mesh is not None),
+            # escape hatch: sequential per-round launches (the fused
+            # scan always grows the round's ensemble vmapped)
+            ("seq_boost_env", bool(os.environ.get("XGBTPU_SEQ_BOOST"))),
+            ("profiler", self.profiler is not None),
+            ("prune", self.param.gamma > 0.0 and "prune" in ups),
+            ("multi_root", max(1, self.param.num_roots) != 1),
+            ("exact", bool(getattr(self.gbtree, "exact_raw", False))),
+            ("refresh", "refresh" in ups),
+            ("no_grow_updater",
+             not any(u.startswith("grow") for u in ups)),
+            # stacking-only: lanes share one rowwise gradient program
+            ("rank_layout", lanes and entry.rank_pad_prep is not None),
+            ("no_fused_grad", self._fused_grad(entry) is None),
+            ("feature_screen", lanes and self.param.ema_fs > 0
+             and self._feature_screen is not None),
+            ("external_eval", solo and any(
+                e.external for _, _, e, _ in evals)),
+            ("sharded_eval_feval", solo and feval is not None and any(
+                getattr(d, "is_sharded", False) for d, _, _, _ in evals)),
+        )
+        return [name for name, blocked in checks if blocked]
 
     def update_many(self, dtrain: DMatrix, first_iteration: int,
                     n_rounds: int, fobj=None, *, evals=None, feval=None,
@@ -1053,46 +1106,24 @@ class Booster:
         entry = self._entry(dtrain)
         self._announce_rank_path(entry)
         ups = parse_updaters(self.param.updater)
+        # Watchlist entries are built HERE, before eligibility is
+        # consulted: a zero-round call is how a caller asks for the
+        # device entries of the training set and every watchlist member
+        # (benchmark/run.py ends its ingest stopwatch on one), so this
+        # is a stated step of every path below, fused or not.
+        # (entry, is_train) per slot: a slot that IS the training
+        # matrix reads the scan's grow-time margin (the
+        # prediction-buffer shortcut) instead of carrying a second copy.
         evals = list(evals) if evals else []
-
-        def fgrad():
-            if entry.rank_pad_prep is not None:
-                return self.obj.fused_grad(entry.info,
-                                           pad_prep=entry.rank_pad_prep)
-            return self.obj.fused_grad(entry.info)
-        # Eligibility as (reason, blocked) pairs so a fallback is LOUD:
-        # chaos/bench runs that mean to measure the fused path verify
-        # the fused_fallback counter stayed 0.  Fault injection (mock)
-        # no longer blocks fusion — do_boost_fused replays the
-        # injector's round/seqno coordinates before each dispatch.
-        # Sharded watchlist sets ride the scan carry like any mesh
-        # entry; their eval lines reduce metric partials via
-        # ShardedDMatrix.allsum (_eval_parts_sharded) — only a custom
-        # feval (needs the full vector on one host) excludes them.
-        # External-memory sets still page batches per round.
-        checks = (
-            ("custom_objective", fobj is not None),
-            ("single_round", n_rounds <= 1),
-            ("booster", self.param.booster != "gbtree"),
-            ("external_train", bool(entry.external)),
-            ("col_split", self._col_mesh is not None),
-            # escape hatch: sequential per-round launches (the fused
-            # scan always grows the round's ensemble vmapped)
-            ("seq_boost_env", bool(os.environ.get("XGBTPU_SEQ_BOOST"))),
-            ("profiler", self.profiler is not None),
-            ("prune", self.param.gamma > 0.0 and "prune" in ups),
-            ("multi_root", max(1, self.param.num_roots) != 1),
-            ("exact", bool(getattr(self.gbtree, "exact_raw", False))),
-            ("refresh", "refresh" in ups),
-            ("no_grow_updater",
-             not any(u.startswith("grow") for u in ups)),
-            ("no_fused_grad", fgrad() is None),
-            ("external_eval",
-             any(self._entry(d).external for d, _ in evals)),
-            ("sharded_eval_feval", feval is not None and any(
-                getattr(d, "is_sharded", False) for d, _ in evals)),
-        )
-        blockers = [name for name, blocked in checks if blocked]
+        espec = []
+        for dmat, name in evals:
+            e = self._entry(dmat)
+            espec.append((dmat, name, e, e is entry))
+        # a fallback is LOUD: chaos/bench runs that mean to measure the
+        # fused path verify the fused_fallback counter stayed 0
+        blockers = self._fused_blockers(
+            entry, ups, lanes=False, fobj=fobj, n_rounds=n_rounds,
+            evals=espec, feval=feval)
         fused_ok = not blockers
         k = (self._resolve_rounds_per_dispatch(
             dtrain.num_row, rounds_per_dispatch) if fused_ok else 0)
@@ -1122,15 +1153,9 @@ class Booster:
             return
         self.obj.validate_labels(entry.info)  # host check, once per info
         self._sync_margin(entry)
-        # (entry, is_train) per watchlist slot: a slot that IS the
-        # training matrix reads the scan's grow-time margin (the
-        # prediction-buffer shortcut) instead of carrying a second copy
-        espec = []
-        for dmat, name in evals:
-            e = self._entry(dmat)
-            if e is not entry:
+        for _, _, e, is_train in espec:
+            if not is_train:
                 self._sync_margin(e)
-            espec.append((dmat, name, e, e is entry))
         etransform = self.obj.fused_eval_transform() if espec else None
         # EMA-FS (ema_fs > 0 + set_feature_screen): fused segments grow
         # over the screened (C, N, F_kept) working set.  Confined to the
@@ -1176,7 +1201,7 @@ class Booster:
                 margin_f, emargins_f, eouts = self.gbtree.do_boost_fused(
                     _screened(entry) if screen is not None
                     else entry.binned,
-                    entry.margin, entry.info, fgrad(),
+                    entry.margin, entry.info, self._fused_grad(entry),
                     first, seg, row_valid=entry.row_valid,
                     mesh=self._mesh,
                     binned_t=(None if screen is not None
@@ -1243,9 +1268,9 @@ class Booster:
         next ``n_rounds`` fused rounds (PIPELINE.md "Gang-batched
         lanes").  Returns ``(LaneSpec, None)`` when the lane-stacking
         driver may vmap this booster with same-bucket peers, else
-        ``(None, reason)`` — the reasons mirror :meth:`update_many`'s
-        fused checks plus the stacking-only restrictions (any mesh,
-        rank relayouts, an active feature screen): a declined lane runs
+        ``(None, reason)`` — the reasons are :meth:`_fused_blockers`'
+        (``update_many``'s own table) with the stacking-only rows (any
+        mesh, rank relayouts, an active feature screen): a declined lane runs
         solo through the normal :meth:`update_many` path, which decides
         its own fused-vs-per-round route.
 
@@ -1260,27 +1285,8 @@ class Booster:
         self._lazy_init(dtrain)
         entry = self._entry(dtrain)
         ups = parse_updaters(self.param.updater)
-        grad_fn = (None if entry.rank_pad_prep is not None
-                   else self.obj.fused_grad(entry.info))
-        checks = (
-            ("no_rounds", n_rounds < 1),
-            ("external_train", bool(entry.external)),
-            ("mesh", self._mesh is not None),
-            ("col_split", self._col_mesh is not None),
-            ("seq_boost_env", bool(os.environ.get("XGBTPU_SEQ_BOOST"))),
-            ("profiler", self.profiler is not None),
-            ("prune", self.param.gamma > 0.0 and "prune" in ups),
-            ("multi_root", max(1, self.param.num_roots) != 1),
-            ("exact", bool(getattr(self.gbtree, "exact_raw", False))),
-            ("refresh", "refresh" in ups),
-            ("no_grow_updater",
-             not any(u.startswith("grow") for u in ups)),
-            ("rank_layout", entry.rank_pad_prep is not None),
-            ("no_fused_grad", grad_fn is None),
-            ("feature_screen", self.param.ema_fs > 0
-             and self._feature_screen is not None),
-        )
-        blockers = [name for name, blocked in checks if blocked]
+        blockers = self._fused_blockers(entry, ups, lanes=True,
+                                        n_rounds=n_rounds)
         if blockers:
             return None, blockers[0]
         k = self._resolve_rounds_per_dispatch(dtrain.num_row,
@@ -1298,7 +1304,8 @@ class Booster:
             K=self._K, npar=max(1, self.param.num_parallel_tree),
             cfg=self.gbtree.cfg,
             split_finder=self.gbtree._split_finder(),
-            grad_fn=grad_fn, pred_chunk=self.gbtree.pred_chunk,
+            grad_fn=self._fused_grad(entry),
+            pred_chunk=self.gbtree.pred_chunk,
             subsample=float(self.param.subsample),
             binned=entry.binned, margin=entry.margin,
             label=entry.info.label_dev(),

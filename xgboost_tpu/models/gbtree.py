@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import functools
 import os
-import sys
 import time
 from typing import Callable, List, Optional, Tuple
 
@@ -22,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from xgboost_tpu.binning import CutMatrix, _rank0
+from xgboost_tpu.binning import CutMatrix
 from xgboost_tpu.config import TrainParam
 from xgboost_tpu.models.tree import (GrowConfig, TreeArrays, grow_tree,
                                      predict_leaf_binned,
@@ -33,43 +32,16 @@ from xgboost_tpu.ops.histogram import kernel_mode
 from xgboost_tpu.ops.split import SplitConfig
 
 
-_WARNED: set = set()
-
-
-def _warn_once(key: str) -> bool:
-    if key in _WARNED:
-        return False
-    _WARNED.add(key)
-    return True
-
-
 def make_grow_config(p: TrainParam, n_bin: int) -> GrowConfig:
     split = SplitConfig(
         reg_lambda=p.reg_lambda, reg_alpha=p.reg_alpha,
         max_delta_step=p.max_delta_step, min_child_weight=p.min_child_weight,
         gamma=p.gamma, eta=p.eta, default_direction=p.default_direction)
-    # Histogram subtraction: OFF, env-gated rather than a config param.
-    # The MXU one-hot kernel's cost is per-row-tile, so subtraction
-    # only pays with row compaction — and XLA scatter/gather compaction
-    # cost an order of magnitude more than it saved when it was tried
-    # (pre-round record; PERF.md "Carried over").
-    # XGBTPU_HIST_SUBTRACTION=1 keeps the A/B reachable
-    # (numerics tested equal; tests/test_updaters.py); a
-    # hist_subtraction=... train param lands in extras and warns.
-    hs = os.environ.get("XGBTPU_HIST_SUBTRACTION", "0") == "1"
-    if ("hist_subtraction" in getattr(p, "extras", {})
-            and int(getattr(p, "silent", 0)) == 0
-            and _warn_once("hist_subtraction") and _rank0()):
-        print("[config] hist_subtraction is no longer a parameter "
-              "(row compaction cost more than it saved on TPU) — "
-              "ignored.  Set env XGBTPU_HIST_SUBTRACTION=1 to force "
-              "the subtraction path for kernel A/Bs.", file=sys.stderr)
     return GrowConfig(split=split, max_depth=p.max_depth, n_bin=n_bin,
                       subsample=p.subsample,
                       colsample_bytree=p.colsample_bytree,
                       colsample_bylevel=p.colsample_bylevel,
                       hist_precision=p.hist_precision,
-                      hist_subtraction=bool(hs),
                       n_roots=max(1, p.num_roots))
 
 
@@ -87,7 +59,7 @@ def _unstack_lane_flats(stacked, t: int):
     tree output into per-lane FLAT (n_rounds*K*npar, ...) stacks, all
     in ONE device launch.  The flatten rides inside the same program:
     reshaping eagerly per lane costs a dispatch per lane per tree field
-    and dominated the stacked cycle (tools/bench_lanes.py)."""
+    and dominated the stacked cycle."""
     flat = jax.tree.map(
         lambda x: x.reshape((x.shape[0], -1) + x.shape[3:]), stacked)
     return tuple(jax.tree.map(lambda x: x[i], flat) for i in range(t))
@@ -258,27 +230,23 @@ def _scan_rounds_mesh_impl(binned, margin, label, weight, base_key,
               cut_values, n_cuts, row_valid, eval_binned, eval_margins)
 
 
-# Jit wrappings of the round-scan implementations: the donating
-# variants hand the margin (arg 1) and eval-margin (arg 11) carries'
-# buffers to XLA so segment k+1 updates segment k's output in place —
-# no per-segment device copy of the O(N*K) state.  CPU ignores donation
-# (with a UserWarning per call), so callers pick the wrapper by backend
-# (do_boost_fused; XGBTPU_FUSED_DONATE overrides for A/Bs).  The
-# ``_mesh`` pair compiles the whole-scan shard_map (mesh-fused
+# Jit wrappings of the round-scan implementations, one each: the
+# margin (arg 1) and eval-margin (arg 11) carries' buffers are donated
+# to XLA so segment k+1 updates segment k's output in place — no
+# per-segment device copy of the O(N*K) state.  Every backend the repo
+# runs on honours the donation (the CPU one too: a donated input is
+# deleted), so a caller never reads an array it passed in those
+# positions (lint rule XGT013 checks the call sites).
+# ``_scan_rounds_mesh`` compiles the whole-scan shard_map (mesh-fused
 # training); ``_scan_rounds`` keeps ``mesh`` for the legacy
 # nested-grow_tree_dp scan (rank objectives).
 _SCAN_STATIC = ("n_rounds", "K", "npar", "cfg", "split_finder",
                 "grad_fn", "mesh", "eval_is_train", "etransform",
                 "pred_chunk")
 _scan_rounds = functools.partial(
-    jax.jit,
-    static_argnames=_SCAN_STATIC + ("hist_reduce",))(_scan_rounds_impl)
-_scan_rounds_donated = functools.partial(
     jax.jit, static_argnames=_SCAN_STATIC + ("hist_reduce",),
     donate_argnums=(1, 11))(_scan_rounds_impl)
 _scan_rounds_mesh = functools.partial(
-    jax.jit, static_argnames=_SCAN_STATIC)(_scan_rounds_mesh_impl)
-_scan_rounds_mesh_donated = functools.partial(
     jax.jit, static_argnames=_SCAN_STATIC,
     donate_argnums=(1, 11))(_scan_rounds_mesh_impl)
 
@@ -323,8 +291,6 @@ def _scan_rounds_lanes_impl(binned, margin, label, weight, base_key,
 _LANE_STATIC = ("n_rounds", "K", "npar", "cfg", "split_finder",
                 "grad_fn", "pred_chunk")
 _scan_rounds_lanes = functools.partial(
-    jax.jit, static_argnames=_LANE_STATIC)(_scan_rounds_lanes_impl)
-_scan_rounds_lanes_donated = functools.partial(
     jax.jit, static_argnames=_LANE_STATIC,
     donate_argnums=(1,))(_scan_rounds_lanes_impl)
 
@@ -374,9 +340,8 @@ class GBTree:
         # chunked tree-parallel traversal width (models/tree.py); 0/1 =
         # the sequential scan baseline; -1 auto = 32 on TPU, scan on
         # CPU (the batched compare-select kernel loses to the scan's
-        # cache locality there — tools/predict_microbench.py; the TPU
-        # width is not measured on this machine).  The env override is
-        # the A/B seam.
+        # cache locality there; the TPU width is not measured on this
+        # machine).  The env override is the A/B seam.
         env_chunk = os.environ.get("XGBTPU_PREDICT_TREE_CHUNK")
         if env_chunk not in (None, ""):
             self.pred_chunk = max(0, int(env_chunk))
@@ -772,7 +737,7 @@ class GBTree:
                        first_iteration: int, n_rounds: int,
                        row_valid=None, mesh=None, binned_t=None,
                        eval_binned=(), eval_margins=(),
-                       eval_is_train=(), etransform=None, donate=None,
+                       eval_is_train=(), etransform=None,
                        rowwise_grad: bool = True, feature_screen=None):
         """Scan ``n_rounds`` whole boosting rounds in ONE device launch.
 
@@ -799,7 +764,9 @@ class GBTree:
         the whole segment bit-identically.
 
         Args:
-          margin: (N, K) current margins (device).
+          margin: (N, K) current margins (device).  DONATED, like
+            ``eval_margins``: the caller replaces its own references
+            with the returned arrays and reads the passed ones no more.
           info: MetaInfo supplying device-cached label/weight.
           grad_fn: pure ``(margin, label, weight, iteration) -> (N, K, 2)``
             gradient with stable identity (Objective.fused_grad).
@@ -815,9 +782,6 @@ class GBTree:
             device-resident watchlist evaluation (see
             :func:`_scan_rounds_impl`) — per-round transformed eval
             outputs come back stacked, one launch for the whole segment.
-          donate: donate the margin/eval-margin carries to XLA (None =
-            auto: on for non-CPU backends, where donation is honored;
-            env XGBTPU_FUSED_DONATE=0/1 overrides).
           feature_screen: optional ascending FULL-space feature ids the
             caller screened ``binned``/``eval_binned`` down to (EMA-FS,
             xgboost_tpu.stream): the scan grows trees over the screened
@@ -832,12 +796,6 @@ class GBTree:
         """
         K = max(1, self.param.num_output_group)
         npar = max(1, self.param.num_parallel_tree)
-        if donate is None:
-            env = os.environ.get("XGBTPU_FUSED_DONATE")
-            if env not in (None, ""):
-                donate = env == "1"
-            else:
-                donate = jax.default_backend() != "cpu"
         mesh_scan = mesh is not None and rowwise_grad
         # the fused scan still performs the per-round collectives; keep
         # the comm/seqno count space identical to the per-round path by
@@ -855,7 +813,7 @@ class GBTree:
         from xgboost_tpu.obs import span, training_metrics
         from xgboost_tpu.parallel import mock
         with span("train.dispatch", first_round=first_iteration,
-                  n_rounds=n_rounds, donated=bool(donate),
+                  n_rounds=n_rounds,
                   mesh_fused=bool(mesh_scan)) as dispatch:
             with span("train.launch",
                       hist_mode=kernel_mode(self.cfg.hist_precision)):
@@ -883,12 +841,7 @@ class GBTree:
                                             count=self.cfg.max_depth)
                         else:
                             mock.collective(nbytes=comm_nbytes)
-                if mesh_scan:
-                    scan = _scan_rounds_mesh_donated if donate \
-                        else _scan_rounds_mesh
-                else:
-                    scan = _scan_rounds_donated if donate \
-                        else _scan_rounds
+                scan = _scan_rounds_mesh if mesh_scan else _scan_rounds
                 margin_f, emargins_f, stacks, eouts = scan(
                     binned, margin, label, weight,
                     self.base_key(),
@@ -938,8 +891,7 @@ class GBTree:
         per leaf, however many segments accumulated).  The gang-batched
         lane driver absorbs N tenants per dispatch; eager per-lane
         concat + cache rebuild here used to cost ~25 tiny device ops
-        per lane and swamped the stacked scan it had just saved
-        (tools/bench_lanes.py)."""
+        per lane and swamped the stacked scan it had just saved."""
         K = max(1, self.param.num_output_group)
         npar = max(1, self.param.num_parallel_tree)
         group_new = [j // npar for _ in range(n_rounds)

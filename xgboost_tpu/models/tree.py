@@ -55,13 +55,6 @@ class GrowConfig(NamedTuple):
     hist_precision: str = "auto"  # auto | fp32 | bf16 | int8 | fixed
     # (named TrainParam; "fixed" = int32 fixed-point scatter — bitwise
     # deterministic across any data-mesh size, ops/histogram.FIXED_SCALE)
-    # histogram subtraction: per parent, build only the SMALLER child's
-    # histogram over row-compacted buffers and derive the sibling as
-    # parent - small (the reference builds every node's histogram,
-    # histmaker-inl.hpp:296-348; subtraction is the classic hist-method
-    # optimization).  Dense TPU tiles process masked rows at full cost,
-    # so the win requires the row compaction this flag also enables.
-    hist_subtraction: bool = False
     # multi-root trees (reference TreeParam num_roots, data.h root_index):
     # the top ceil(log2 n_roots) levels of the perfect layout are root
     # slots; row i enters at node (2**d0 - 1) + root_index[i], matching
@@ -180,72 +173,6 @@ def _default_feat_sampler(key, rate, binned):
     return _sample_features(key, binned.shape[1], rate)
 
 
-def _subtracted_level_hist(binned, gh_used, pos, n_node: int, cfg,
-                           red, hist_parent, parent_split):
-    """Level histogram via subtraction + row compaction.
-
-    Per parent, only the child with FEWER rows is built; the sibling is
-    ``parent - small``.  The built rows are compacted into a static
-    N/2-row buffer so the histogram kernel touches ~half the rows per
-    level (sum over parents of min(left, right) <= N/2).  Distributed:
-    the small-child choice comes from psum'd counts, so every shard
-    builds the same children; a shard whose LOCAL small-child rows
-    overflow the buffer flips ALL shards to the plain full build
-    (lax.cond on a psum'd flag — collective-safe).
-    """
-    from xgboost_tpu.ops.histogram import dequantize_hist, node_stats
-
-    N, F = binned.shape
-    B = cfg.n_bin
-    # per-child ACTIVE-row counts (global under `red`): hessians can
-    # mislead on weighted data and the N/2 capacity bound is on rows
-    ones2 = jnp.broadcast_to(
-        (pos >= 0)[:, None].astype(jnp.float32), (N, 2))
-    counts = red(node_stats(ones2, pos, n_node))[:, 0]       # (n_node,)
-    small_is_left = counts[0::2] <= counts[1::2]
-    is_small = jnp.stack(
-        [small_is_left, ~small_is_left], axis=1).reshape(-1)  # (n_node,)
-
-    msk = (pos >= 0) & table_lookup(is_small, jnp.clip(pos, 0, n_node - 1))
-    cap = max(256, -(-(N // 2) // 256) * 256)
-    dest = jnp.where(msk, jnp.cumsum(msk.astype(jnp.int32)) - 1, cap)
-
-    def subtract_build():
-        b_small = jnp.zeros((cap, F), binned.dtype).at[dest].set(
-            binned, mode="drop")
-        gh_small = jnp.zeros((cap, 2), gh_used.dtype).at[dest].set(
-            gh_used, mode="drop")
-        pos_small = jnp.full(cap, -1, jnp.int32).at[dest].set(
-            pos, mode="drop")
-        from xgboost_tpu.ops.histogram import build_level_histogram
-        hist_small = dequantize_hist(red(build_level_histogram(
-            b_small, gh_small, pos_small, n_node, B, cfg.hist_precision)))
-        # the small child's histogram per parent is the pair-sum (the
-        # non-built sibling's slots are zero)
-        small_of_parent = hist_small.reshape(
-            n_node // 2, 2, F, B, 2).sum(axis=1)
-        # children of NON-split (leaf) parents have no rows: without the
-        # mask, sibling = parent - 0 would hand the parent's full mass
-        # to a phantom node, diverging from the plain build
-        sibling = jnp.where(parent_split[:, None, None, None],
-                            hist_parent - small_of_parent, 0.0)
-        sib_child = jnp.repeat(sibling, 2, axis=0)
-        return jnp.where(is_small[:, None, None, None],
-                         hist_small, sib_child)
-
-    def full_build():
-        from xgboost_tpu.ops.histogram import build_level_histogram
-        return dequantize_hist(red(build_level_histogram(
-            binned, gh_used, pos, n_node, B, cfg.hist_precision)))
-
-    # the N/2 bound holds for GLOBAL counts; a skewed shard can still
-    # overflow its local buffer, so reduce the local overflow flag and
-    # (rarely) flip every shard to the plain build together
-    local_over = jnp.sum(msk.astype(jnp.int32)) > cap
-    any_over = red(local_over.astype(jnp.float32)[None])[0] > 0
-    return jax.lax.cond(any_over, full_build, subtract_build)
-
-
 def root_level(n_roots: int) -> int:
     """Depth of the level holding the root slots (0 for a single root)."""
     return max(n_roots - 1, 0).bit_length()
@@ -333,7 +260,6 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
         pos = jnp.where(row_valid, pos, -1)
     row_leaf = jnp.zeros(N, jnp.int32)
     row_val = jnp.zeros(N, jnp.float32)
-    hist_prev = None
     prev = None  # (best, nst, do_split) of the previous level
 
     # once-per-tree histogram precompute: the bins transpose and (int8
@@ -351,9 +277,8 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
     # finder consumes the kernel's own output order, skipping the
     # per-level relayout transpose (~0.47 ms/round at 1M x 28 —
     # round-5 trace).  Default finder only (the colsplit/skmaker seams
-    # speak the standard layout), single node tile, no subtraction.
-    use_native = (default_finder and hist_prep is not None
-                  and not cfg.hist_subtraction)
+    # speak the standard layout), single node tile.
+    use_native = default_finder and hist_prep is not None
 
     from xgboost_tpu.ops.histogram import stats_from_histogram_native
     for depth in range(d0, d0 + D + 1):
@@ -364,18 +289,12 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
 
         if not terminal:
             with jax.named_scope("grow.hist"):
-                if cfg.hist_subtraction and hist_prev is not None:
-                    hist = _subtracted_level_hist(binned, gh_used, pos,
-                                                  n_node, cfg, red,
-                                                  hist_prev, prev[2])
-                else:
-                    hist = dequantize_hist(
-                        red(build_level_histogram(binned, gh_used, pos,
-                                                  n_node, cfg.n_bin,
-                                                  cfg.hist_precision,
-                                                  prep=hist_prep,
-                                                  native=native)))
-            hist_prev = hist if cfg.hist_subtraction else None
+                hist = dequantize_hist(
+                    red(build_level_histogram(binned, gh_used, pos,
+                                              n_node, cfg.n_bin,
+                                              cfg.hist_precision,
+                                              prep=hist_prep,
+                                              native=native)))
 
         with jax.named_scope("grow.split"):
             if terminal:

@@ -24,9 +24,9 @@ Instrumented seams:
   psums per tree-growth step with the whole-tree payload estimate in
   ``xgbtpu_comm_psum_bytes_total``.  Its ``seconds`` counter stays 0
   by design — the psums execute inside ONE fused device program, so
-  per-collective wall time is not observable host-side (the measured
-  per-round psum cost lives in MULTICHIP_r06.json, fitted by
-  ``tools/fit_round_model.py``'s mesh cell); the dispatch wall goes to
+  per-collective wall time is not observable host-side (the per-round
+  psum cost on the chip is not measured, PERF.md §7 row 3); the
+  dispatch wall goes to
   ``xgbtpu_train_dispatch_seconds``, never to a collective family;
 - ``parallel/sharded.py`` eval collectives (``allsum``/``allgatherv``)
   and ``parallel/colsplit.py`` per-level split gathers record as

@@ -44,7 +44,6 @@ Contracts:
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Dict, List, Optional
@@ -220,7 +219,6 @@ class LaneGang:
 
     def _dispatch_bucket(self, key, arrs: List[_Arrival]) -> None:
         from xgboost_tpu.models.gbtree import (_scan_rounds_lanes,
-                                               _scan_rounds_lanes_donated,
                                                _unstack_lane_flats)
         n_pad, n_feat, w_pad = key[0], key[1], key[2]
         specs = [a.spec for a in arrs]
@@ -306,10 +304,6 @@ class LaneGang:
         first_s = jnp.asarray(np.asarray(
             [s.first_iteration for s in specs] + [0] * (L - L_real),
             np.int32))
-        env = os.environ.get("XGBTPU_FUSED_DONATE")
-        donate = (env == "1" if env not in (None, "")
-                  else jax.default_backend() != "cpu")
-        scan = _scan_rounds_lanes_donated if donate else _scan_rounds_lanes
         n_rounds, seg_k = s0.n_rounds, s0.seg_k
         done = 0
         views: List[Optional[np.ndarray]] = [None] * L_real
@@ -318,7 +312,7 @@ class LaneGang:
             with span("lanes.dispatch", lanes=L_real, width=L,
                       n_rounds=seg, bucket_rows=n_pad):
                 t0 = time.perf_counter()
-                margin_s, stacks = scan(
+                margin_s, stacks = _scan_rounds_lanes(
                     binned_s, margin_s, label_s, weight_s, key_s,
                     first_s + done, cut_s, ncut_s, rv_s,
                     n_rounds=seg, K=s0.K, npar=s0.npar, cfg=s0.cfg,
