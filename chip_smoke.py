@@ -553,7 +553,17 @@ def kernel_cases(n_rows: int = 70_000):
     # fine bins: f_tile=8 < F=28 with the u8 path off
     for precision in ("int8", "fp32", "bf16"):
         solo(n_rows, 28, 256, 64, precision, "int32", False)
-    solo(n_rows, 28, 256, 1, "int8", "int32", True)
+    # the folded levels (ops/pallas_hist._fold_of: the bin id's high bits
+    # in the lanes a shallow level leaves idle; at 256 bins a one-hot of
+    # 32, 64, 64, 128 rows at 1, 8, 16, 32 nodes in int8, of 16 at one
+    # node in bf16 and fp32; 64 bins fold by two up to 8 nodes (by 8 at
+    # one node in fp32) and stay unfolded from 16 on)
+    for M in (1, 8, 16, 32):
+        for precision in ("int8", "bf16", "fp32"):
+            solo(n_rows, 28, 256, M, precision, "int32",
+                 precision == "int8")
+    for precision in ("int8", "bf16", "fp32"):
+        solo(n_rows, 28, 64, 16, precision, "int32", False)
     # the Airline shape past one int32 accumulator's rows: F=13 in two
     # feature tiles of 8, three row chunks, both layouts
     solo(n_rows, 13, 256, 32, "int8", "int32", True, chunks=3)
@@ -625,8 +635,12 @@ def kernel_cases(n_rows: int = 70_000):
         for M in (1, 4):
             lanes(L, 64, 4, 32, M, "int8")
         lanes(L, 64, 4, 32, 4, "fp32")
-    # 256 bins: F=13 in two feature tiles of 8, 3 padded slots guarded
-    lanes(2, 3000, 13, 256, 8, "int8")
+    # 256 bins: F=13 in two feature tiles of 8, 3 padded slots guarded;
+    # the lanes kernel folds as the rows kernel does
+    for B in (256, 64):
+        for M in (1, 8, 16):
+            for precision in ("int8", "bf16", "fp32"):
+                lanes(2, 3000, 13, B, M, precision)
 
     def nstats(N, M):
         def build(interpret):

@@ -80,6 +80,39 @@ def test_chunked_int8_equals_one_block_bit_for_bit(call, M, native):
     assert np.array_equal(one, three)
 
 
+@pytest.mark.parametrize("kernel", ["solo", "lanes"])
+@pytest.mark.parametrize("n_bin,M", [(256, 1), (256, 8), (256, 16),
+                                     (64, 2), (67, 4)])
+def test_folded_chunked_int8_equals_one_block_and_unfolded(kernel, n_bin, M,
+                                                           monkeypatch):
+    """A folded level (ISSUE 34: the bin id's high bits in idle lanes,
+    ``_fold_of``) takes the row chunks as the unfolded one does: three
+    forced chunks equal one block, and both equal the unfolded program's
+    three chunks, bit for bit."""
+    assert ph._fold_of(n_bin, M, "int8")[1] > 1
+    rng = np.random.RandomState(34)
+    lead = (2,) if kernel == "lanes" else ()
+    binned = jnp.asarray(rng.randint(0, n_bin, lead + (N, F)).astype(np.uint8))
+    gh = jnp.asarray(rng.randn(*lead, N, 2).astype(np.float32))
+    pos = jnp.asarray(rng.randint(-1, M, lead + (N,)).astype(np.int32))
+    q, scale = ph.quantize_gh(gh)
+    tb = lambda b: ph.transpose_bins(b, n_bin)                  # noqa: E731
+    bt = jax.vmap(tb)(binned) if lead else tb(binned)
+    pre = ph._hist_pallas_lanes_pre if lead else ph._hist_pallas_pre
+
+    def run(rows_per_acc):
+        return np.asarray(pre(bt, q, scale, pos, (N, F), M, n_bin, "int8",
+                              True, native=True, rows_per_acc=rows_per_acc))
+    chunks = obs.training_metrics().hist_row_chunks
+    one = run(None)
+    assert chunks.value == 1 and np.abs(one).max() > 0
+    three = run(FORCED)
+    assert chunks.value == 3
+    assert np.array_equal(one, three)
+    monkeypatch.setattr(ph, "_fold_of", lambda n_bin, m_pad, p: (n_bin, 1))
+    assert np.array_equal(run(FORCED), three)
+
+
 def test_lane_chunks_equal_the_solo_call_of_each_lane():
     binned, gh, pos = _case(4, lanes=2)
     q, scale = ph.quantize_gh(gh)

@@ -15,6 +15,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from xgboost_tpu.ops.histogram import (build_level_histogram,  # noqa: E402
                                        node_stats, stats_from_histogram)
+from xgboost_tpu.ops import pallas_hist as ph  # noqa: E402
 from xgboost_tpu.ops.pallas_hist import (  # noqa: E402
     build_level_histogram_pallas, node_stats_pallas)
 
@@ -183,11 +184,14 @@ def test_padded_feature_slots_run_no_dot(kernel, F, B, precision,
     assert feature_dots.value == F                              # (c)
     got_junk = np.asarray(run(bt_junk))
     assert len(raw) == 2
+    # a feature's rows in the block: B, or the fold's (M = 4 folds at
+    # 256 bins in the rows and lanes kernels; the trees kernel never)
+    rows = B if kernel == "trees" else ph._fold_of(B, M, precision)[0]
     for block in raw:                                           # (b)
         block = np.asarray(block)
-        assert block.shape[-2] == f_pad * B
-        assert not block[..., F * B:, :].any()
-        assert block[..., :F * B, :].any()
+        assert block.shape[-2] == f_pad * rows
+        assert not block[..., F * rows:, :].any()
+        assert block[..., :F * rows, :].any()
     np.testing.assert_array_equal(got_junk, got)
 
     assert got.shape == (gh.shape[0], M, F, B, 2)               # (a)
@@ -351,3 +355,163 @@ def test_native_vmapped_multiclass_matches_scatter(monkeypatch):
         preds[impl] = np.asarray(bst.predict(d, output_margin=True))
     np.testing.assert_allclose(preds["pallas"], preds["scatter"],
                                rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------------ the fold (ISSUE 34)
+def _unfolded(n_bin, m_pad, precision):
+    return n_bin, 1
+
+
+def _fold_case(N, F, B, M, precision, lanes=None, seed=34):
+    """Prepared operands with 20 % inactive rows and one column that is
+    0 in 99.8 % of rows; ``gh_ref`` is what the scatter has to sum to
+    give the kernel's cells exactly (the quantized integers in int8)."""
+    rng = np.random.RandomState(seed)
+    lead = () if lanes is None else (lanes,)
+    binned = rng.randint(0, B, lead + (N, F)).astype(np.uint8)
+    binned[..., F // 2] *= rng.rand(*lead, N) >= 0.998
+    gh = (rng.randint(-512, 512, lead + (N, 2)) / 256.0).astype(np.float32)
+    pos = rng.randint(0, M, lead + (N,)).astype(np.int32)
+    pos[rng.rand(*lead, N) < 0.2] = -1
+    gh_j = jnp.asarray(gh)
+    if precision == "int8":
+        gh_in, scale = ph.quantize_gh(gh_j)
+        gh_ref = np.asarray(gh_in, np.float32)
+    else:
+        gh_in, scale, gh_ref = gh_j, None, gh
+    tb = lambda b: ph.transpose_bins(b, B)                      # noqa: E731
+    bt = tb(jnp.asarray(binned)) if lanes is None else jax.vmap(tb)(
+        jnp.asarray(binned))
+    return binned, bt, gh_in, scale, gh_ref, jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("precision", ["int8", "fp32"])
+@pytest.mark.parametrize("F", [28, 13, 8])
+@pytest.mark.parametrize("M", [1, 2, 8, 16, 32, 64])
+@pytest.mark.parametrize("B", [256, 64, 67])
+def test_folded_level_equals_scatter_and_unfolded(B, M, F, precision,
+                                                  monkeypatch):
+    """The level kernel with the bin id's high bits folded into the
+    lanes a shallow level leaves idle (``_fold_of``) gives the XLA
+    scatter's histogram and the unfolded program's, bit for bit, in the
+    standard and the kernel-native layout; where ``_fold_of`` leaves the
+    level unfolded the two programs are one.  F = 28 and 13 leave padded
+    slots in the last feature tile at 256 bins (PR 32's guard)."""
+    N = 700
+    binned, bt, gh_in, scale, gh_ref, pos = _fold_case(N, F, B, M, precision)
+
+    def run(native):
+        return np.asarray(ph._hist_pallas_pre(
+            bt, gh_in, scale, pos, (N, F), M, B, precision, True,
+            native=native))
+    raw = _spy_on_pallas_call(monkeypatch)
+    std, nat = run(False), run(True)
+    rows, n_hi = ph._fold_of(B, M, precision)
+    f_pad = bt.shape[0]
+    assert raw[0].shape == (1, f_pad * rows, n_hi * 2 * M)
+    monkeypatch.setattr(ph, "_fold_of", _unfolded)
+    std_1 = run(False)
+    assert raw[2].shape == (1, f_pad * B, 2 * M)
+    want = np.asarray(build_level_histogram(
+        jnp.asarray(binned), jnp.asarray(gh_ref), pos, M, B))
+    if precision == "int8":
+        want = want * np.asarray(scale / 127.0)
+    assert std.shape == (M, F, B, 2) and nat.shape == (F, B, 2, M)
+    assert np.abs(want).max() > 0
+    np.testing.assert_array_equal(std, want)
+    np.testing.assert_array_equal(std, std_1)
+    np.testing.assert_array_equal(nat, std.transpose(1, 2, 3, 0))
+
+
+@pytest.mark.parametrize("precision", ["int8", "fp32"])
+@pytest.mark.parametrize("B,M", [(256, 1), (256, 8), (256, 16), (256, 32),
+                                 (64, 2), (67, 4)])
+def test_folded_lanes_equal_solo_bit_for_bit(B, M, precision, monkeypatch):
+    """The lanes kernel shares ``_hist_kernel`` and folds with it: each
+    lane's histogram is the solo call's on that lane, bit for bit, in
+    both layouts, and the unfolded lanes program's."""
+    N, F, L = 700, 13, 2
+    binned, bt, gh_in, scale, _, pos = _fold_case(N, F, B, M, precision,
+                                                  lanes=L)
+
+    def run(native):
+        return np.asarray(ph._hist_pallas_lanes_pre(
+            bt, gh_in, scale, pos, (N, F), M, B, precision, True,
+            native=native))
+    raw = _spy_on_pallas_call(monkeypatch)
+    std, nat = run(False), run(True)
+    rows, n_hi = ph._fold_of(B, M, precision)
+    assert raw[0].shape == (L, bt.shape[1] * rows, n_hi * 2 * M)
+    for lane in range(L):
+        for native, got in ((False, std), (True, nat)):
+            solo = ph._hist_pallas_pre(
+                bt[lane], gh_in[lane], None if scale is None else scale[lane],
+                pos[lane], (N, F), M, B, precision, True, native=native)
+            np.testing.assert_array_equal(got[lane], np.asarray(solo))
+    monkeypatch.setattr(ph, "_fold_of", _unfolded)
+    np.testing.assert_array_equal(std, run(False))
+
+
+@pytest.mark.parametrize("n_bin,m_pad,precision,want", [
+    # 256 bins, int8: the one-hot dtype's sublane tile is 32 rows
+    (256, 1, "int8", (32, 8)), (256, 2, "int8", (32, 8)),
+    (256, 4, "int8", (32, 8)),
+    (256, 8, "int8", (64, 4)),          # 64 + 64 lanes beat 32 + 128
+    (256, 16, "int8", (64, 4)),
+    (256, 32, "int8", (128, 2)),        # measured -14 % on the chip
+    (256, 64, "int8", (256, 1)),        # a node tile: no idle lane
+    # bf16 and fp32 tiles are 16 and 8 rows
+    (256, 1, "bf16", (16, 16)), (256, 1, "fp32", (16, 16)),
+    (256, 2, "bf16", (32, 8)), (256, 8, "fp32", (64, 4)),
+    (256, 16, "bf16", (64, 4)), (256, 32, "bf16", (128, 2)),
+    (256, 32, "fp32", (128, 2)), (256, 64, "fp32", (256, 1)),
+    # 64 bins (the smoke): a fold of two while it pays
+    (64, 1, "int8", (32, 2)), (64, 2, "int8", (32, 2)),
+    (64, 4, "int8", (32, 2)), (64, 8, "int8", (32, 2)),
+    (64, 16, "int8", (64, 1)),          # measured +17 % folded: stays
+    (64, 32, "int8", (64, 1)), (64, 64, "int8", (64, 1)),
+    (64, 1, "fp32", (8, 8)), (64, 8, "bf16", (32, 2)),
+    # no power of two: the last group partly empty
+    (67, 1, "int8", (32, 3)), (68, 2, "int8", (32, 3)),
+    (67, 4, "int8", (32, 3)), (67, 2, "fp32", (16, 5)),
+    (67, 8, "int8", (67, 1)), (67, 16, "int8", (67, 1)),
+    (67, 32, "fp32", (67, 1)), (67, 64, "int8", (67, 1)),
+    # too few bins to halve
+    (32, 1, "int8", (32, 1)), (16, 1, "bf16", (16, 1)),
+    (8, 4, "fp32", (8, 1)), (2, 1, "fp32", (2, 1)),
+])
+def test_fold_of_table(n_bin, m_pad, precision, want):
+    rows, n_hi = ph._fold_of(n_bin, m_pad, precision)
+    assert (rows, n_hi) == want
+    assert n_hi * rows >= n_bin and n_hi * 2 * m_pad <= max(128, 2 * m_pad)
+    if n_hi > 1:
+        assert rows & (rows - 1) == 0 and (n_hi - 1) * rows < n_bin
+
+
+def test_onehot_rows_gauge_after_a_depth_6_trace(monkeypatch):
+    """Six levels of one tree at 256 bins, traced (never run): the gauge
+    holds the one-hot rows a feature pushes per row tile summed over the
+    levels, 32 + 32 + 32 + 64 + 64 + 128; the unfolded program's 6 x
+    256; a second tree starts again at its root."""
+    from xgboost_tpu import obs
+    gauge = obs.training_metrics().hist_onehot_rows
+
+    def tree(binned, gh, pos):
+        prep_bt = ph.transpose_bins(binned, 256)
+        q, scale = ph.quantize_gh(gh)
+        return [ph._hist_pallas_pre(prep_bt, q, scale, pos, binned.shape,
+                                    1 << d, 256, "int8", False, native=True)
+                for d in range(6)]
+    args = (jax.ShapeDtypeStruct((4096, 28), jnp.uint8),
+            jax.ShapeDtypeStruct((4096, 2), jnp.float32),
+            jax.ShapeDtypeStruct((4096,), jnp.int32))
+    def trace():            # a fresh function: eval_shape caches traces
+        jax.eval_shape(lambda *a: tree(*a), *args)
+    trace()
+    assert gauge.value == 352
+    assert "xgbtpu_hist_onehot_rows 352" in obs.registry().render()
+    trace()
+    assert gauge.value == 352
+    monkeypatch.setattr(ph, "_fold_of", _unfolded)
+    trace()
+    assert gauge.value == 1536

@@ -6,15 +6,18 @@ row tile of 2,048 rows and per feature, a ``(256, R)`` one-hot and one
 This times the same body at 8.4M rows x 8 features (one feature tile),
 M = 1 and M = 32, int8 and bf16, with
 
-* the one-hot cut to 256, 128, 64 and 32 rows (WRONG sums: timing only):
-  the slope over the rows is what a pushed one-hot row costs (its VPU
-  compare and select, and its pass through the MXU), the intercept what
-  a feature costs besides (loading the right-hand operand into the MXU,
-  the accumulate, the step);
-* the right-hand operand either the shared ``gh_exp`` of the production
-  kernel, built once per row tile, or a ``(128, R)`` operand built PER
-  FEATURE from the feature's bin ids (what folding the bin id's high
-  bits into the lanes that 2M <= 64 leaves idle would need);
+* the one-hot cut to 256, 128, 64 and 32 rows against the shared
+  ``gh_exp`` (WRONG sums: timing only): the slope over the rows is what
+  a pushed one-hot row costs (its VPU compare and select, and its pass
+  through the MXU), the intercept what a feature costs besides (loading
+  the right-hand operand into the MXU, the accumulate, the step);
+* the SHIPPED kernel (``ops/pallas_hist._hist_pallas_pre``, right sums)
+  with its fold (``_fold_of``: the bin id's high bits in the lanes that
+  2M < 128 leaves idle, the right-hand operand built per feature) forced
+  to each ``(rows, n_hi)`` that fits 128 lanes, M = 1 ... 32, beside the
+  one ``_fold_of`` ships (ISSUE 34); kernel time from a device trace,
+  so the call's XLA prologue and unfold are not in it.  ``--rehearse``
+  checks every forced fold against the unfolded sums, bit for bit;
 
 and, beside them, the forms of the padded-slot guard at 28 and 13
 features (none = the parent's program; tile = one ``pl.when`` on the
@@ -23,6 +26,7 @@ split = two whole loops, eight slots under ``fi < last`` and the real
 ones under ``fi == last``).
 
     chiprun -- python tools/hist_dots_probe.py            # on the chip
+    chiprun -- python tools/hist_dots_probe.py --only folds
     JAX_PLATFORMS=cpu python tools/hist_dots_probe.py --rehearse
 
 The table goes to stdout and to ``chiprun_out/hist_dots_probe.json``.
@@ -30,9 +34,11 @@ Nothing imports this file.
 """
 import argparse
 import functools
+import glob
 import json
 import os
 import sys
+import tempfile
 import time
 
 import jax
@@ -42,18 +48,17 @@ from jax.experimental import pallas as pl
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+from xgboost_tpu.ops import pallas_hist as ph  # noqa: E402
 from xgboost_tpu.ops.pallas_hist import _round_up  # noqa: E402
 
 B, R_TILE, F_TILE = 256, 2048, 8
 
 
-def make_kernel(mode, m_pad, hot_rows, rhs, n_feat, guard):
-    """The ``hist_level_rows`` body with the probe's three knobs."""
+def make_kernel(mode, m_pad, hot_rows, n_feat, guard):
+    """The unfolded ``hist_level_rows`` body with the probe's knobs."""
     hot_dtype, acc_dtype = ((jnp.int8, jnp.int32) if mode == "int8"
                             else (jnp.bfloat16, jnp.float32))
-    lanes = 2 * m_pad if rhs == "shared" else 128
-    fold = lanes // (2 * m_pad)          # bin-id values folded into lanes
-    shift = (B // fold).bit_length() - 1
+    lanes = 2 * m_pad
 
     def kernel(binned_ref, pos_ref, gh_ref, out_ref):
         r_tile = binned_ref.shape[1]
@@ -63,28 +68,19 @@ def make_kernel(mode, m_pad, hot_rows, rhs, n_feat, guard):
             out_ref[:] = jnp.zeros_like(out_ref)
 
         sub = jax.lax.broadcasted_iota(jnp.int32, (lanes, r_tile), 0)
-        within = sub % (2 * m_pad)
-        node_of_sub = jnp.where(within < m_pad, within, within - m_pad)
-        ghsel = jnp.where(within < m_pad, gh_ref[0:1, :], gh_ref[1:2, :])
+        node_of_sub = jnp.where(sub < m_pad, sub, sub - m_pad)
+        ghsel = jnp.where(sub < m_pad, gh_ref[0:1, :], gh_ref[1:2, :])
         active = pos_ref[0:1, :] == node_of_sub
         zero = jnp.zeros((), ghsel.dtype)
         gh_exp = jnp.where(active, ghsel, zero).astype(hot_dtype)
         bins = binned_ref[:].astype(jnp.int32)
         bin_ids = jax.lax.broadcasted_iota(jnp.int32, (hot_rows, r_tile), 0)
-        hi_of_sub = sub // (2 * m_pad)
 
         def slot(f):
-            b = bins[f:f + 1, :]
-            if rhs == "shared":
-                rhs_f = gh_exp
-            else:       # lane l takes the rows whose bin id's high bits
-                rhs_f = jnp.where(          # are l // 2M: built per feature
-                    active & (b >> shift == hi_of_sub),
-                    ghsel, zero).astype(hot_dtype)
             # bin ids past hot_rows match no row: fewer ones, same work
-            onehot = (b == bin_ids).astype(hot_dtype)
+            onehot = (bins[f:f + 1, :] == bin_ids).astype(hot_dtype)
             acc = jax.lax.dot_general(
-                onehot, rhs_f, (((1,), (1,)), ((), ())),
+                onehot, gh_exp, (((1,), (1,)), ((), ())),
                 preferred_element_type=acc_dtype)
             out_ref[f * hot_rows:(f + 1) * hot_rows, :] += acc
 
@@ -109,9 +105,9 @@ def make_kernel(mode, m_pad, hot_rows, rhs, n_feat, guard):
     return kernel, lanes, acc_dtype
 
 
-def build(mode, m_pad, hot_rows, rhs, n_feat, guard, interpret):
-    kernel, lanes, acc_dtype = make_kernel(mode, m_pad, hot_rows, rhs,
-                                           n_feat, guard)
+def build(mode, m_pad, hot_rows, n_feat, guard, interpret):
+    kernel, lanes, acc_dtype = make_kernel(mode, m_pad, hot_rows, n_feat,
+                                           guard)
     f_pad = _round_up(n_feat, F_TILE)
 
     @jax.jit
@@ -135,14 +131,24 @@ def build(mode, m_pad, hot_rows, rhs, n_feat, guard, interpret):
     return fn
 
 
-def operands(n_rows, f_pad, m_pad, mode, seed=32):
+def bin_ids(n_rows, f_pad, n_bin=B, seed=32):
+    """Made on the device: 8.4M x 32 bin ids are 1.1 GB."""
+    return jax.random.randint(jax.random.PRNGKey(seed),
+                              (f_pad, _round_up(n_rows, R_TILE)), 0, n_bin,
+                              jnp.int32)
+
+
+def row_operands(n_rows, m_pad, mode, seed=33):
+    """``(pos, gh)``: every row in one of the level's ``m_pad`` nodes."""
     n_pad = _round_up(n_rows, R_TILE)
-    rng = np.random.RandomState(seed)
-    binned_t = rng.randint(0, B, (f_pad, n_pad)).astype(np.int32)
-    pos = rng.randint(0, m_pad, (1, n_pad)).astype(np.int32)
-    gh = rng.randint(-127, 128, (2, n_pad))
-    gh = gh.astype(np.int32 if mode == "int8" else np.float32)
-    return jnp.asarray(binned_t), jnp.asarray(pos), jnp.asarray(gh)
+    k = jax.random.split(jax.random.PRNGKey(seed))
+    pos = jax.random.randint(k[0], (1, n_pad), 0, m_pad, jnp.int32)
+    gh = jax.random.randint(k[1], (2, n_pad), -127, 128, jnp.int32)
+    return pos, gh.astype(jnp.int32 if mode == "int8" else jnp.float32)
+
+
+def operands(n_rows, f_pad, m_pad, mode):
+    return (bin_ids(n_rows, f_pad),) + row_operands(n_rows, m_pad, mode)
 
 
 def timed(fn, args, reps):
@@ -155,14 +161,89 @@ def timed(fn, args, reps):
     return float(np.median(times)), float(min(times))
 
 
+def kernel_timed(calls, reps):
+    """Per ``(fn, args)`` of ``calls``: median and least device time (s)
+    of its ``hist_level_rows`` kernel over ``reps`` calls, from ONE
+    profiler trace of all of them in turn (starting a trace costs far
+    more than the calls); the call's wall time where there is no device
+    plane (``--rehearse``)."""
+    from jax.profiler import ProfileData
+    wall = [timed(fn, args, 1) for fn, args in calls]   # compiles, warms
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            for fn, args in calls:
+                for _ in range(reps):
+                    out = fn(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        data = ProfileData.from_file(files[-1])
+        # an event is named by its HLO text, and the kernel's consumer
+        # names the kernel too: match the op's own name, which comes first
+        times = sorted((e.start_ns, e.duration_ns * 1e-9)
+                       for plane in data.planes
+                       if plane.name == "/device:TPU:0"
+                       for line in plane.lines if line.name == "XLA Ops"
+                       for e in line.events
+                       if e.name.startswith("%hist_level_rows"))
+    if len(times) != reps * len(calls):
+        if times:                   # else: no device plane (--rehearse)
+            print("# trace:", len(times), "kernel events for", reps, "x",
+                  len(calls), "calls; wall times instead",
+                  file=sys.stderr, flush=True)
+        return wall
+    times = [t for _, t in times]
+    return [(float(np.median(times[i:i + reps])),
+             float(min(times[i:i + reps])))
+            for i in range(0, len(times), reps)]
+
+
+_SHIPPED_FOLD_OF = ph._fold_of
+
+
+def shipped_level(mode, m_pad, n_feat, n_bin, fold, interpret):
+    """One level of the shipped kernel on prepared operands, its fold
+    forced (``ph._fold_of`` is read at trace time): the kernel's native
+    ``(F, B, 2, M)`` histogram."""
+    def fn(binned_t, pos, gh):
+        ph._fold_of = lambda *a: fold
+        try:
+            scale = jnp.ones((2,), jnp.float32) if mode == "int8" else None
+            return ph._hist_pallas_pre(
+                binned_t, gh.T, scale, pos[0], (binned_t.shape[1], n_feat),
+                m_pad, n_bin, mode, interpret, native=True)
+        finally:
+            ph._fold_of = _SHIPPED_FOLD_OF
+    return jax.jit(fn)
+
+
+def folds_of(n_bin, m_pad, mode):
+    """Unfolded, then every power-of-two fold that fits 128 lanes."""
+    floor = {"int8": 32, "bf16": 16}[mode]
+    out, rows = [(n_bin, 1)], n_bin // 2
+    while rows >= floor and (n_bin // rows) * 2 * m_pad <= 128:
+        out.append((rows, n_bin // rows))
+        rows //= 2
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true",
-                    help="interpret mode, 4,096 rows: control flow only")
+                    help="interpret mode, 4,096 rows: control flow, and "
+                    "every forced fold against the unfolded sums")
     ap.add_argument("--rows", type=int, default=8_400_000)
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--only-guards", action="store_true")
+    ap.add_argument("--only", choices=("rows", "folds", "guards"),
+                    action="append", help="default: all three")
     args = ap.parse_args()
+    parts = args.only or ["rows", "folds", "guards"]
     interp = args.rehearse
     n_rows = 4096 if interp else args.rows
     reps = 1 if interp else args.reps
@@ -171,40 +252,85 @@ def main():
     n_tiles = _round_up(n_rows, R_TILE) // R_TILE
     rows = []
 
-    def run(mode, m_pad, hot_rows, rhs, n_feat, guard, ops):
-        fn = build(mode, m_pad, hot_rows, rhs, n_feat, guard, interp)
-        med, low = timed(fn, ops, reps)
-        real_dots = n_feat if guard != "none" else _round_up(n_feat, F_TILE)
-        row = {"mode": mode, "M": m_pad, "hot_rows": hot_rows, "rhs": rhs,
-               "F": n_feat, "guard": guard, "ms": med * 1e3,
-               "ms_min": low * 1e3,
-               "us_per_dot": med * 1e6 / (n_tiles * real_dots),
-               "us_per_step": med * 1e6
-               / (n_tiles * _round_up(n_feat, F_TILE) // F_TILE)}
+    def report(row, med, low, n_feat, real_dots, tiles=n_tiles,
+               f_tile=F_TILE):
+        row.update(ms=med * 1e3, ms_min=low * 1e3,
+                   us_per_dot=med * 1e6 / (tiles * real_dots),
+                   us_per_step=med * 1e6
+                   / (tiles * _round_up(n_feat, f_tile) // f_tile))
         rows.append(row)
         print(json.dumps(row), flush=True)
 
-    # one feature tile of eight real features: rows of the one-hot and
-    # the right-hand operand
-    for mode in () if args.only_guards else ("int8", "bf16"):
-        for m_pad in (1, 32):
-            ops = operands(n_rows, F_TILE, m_pad, mode)
-            for rhs in ("shared", "per_feature"):
+    def run(mode, m_pad, hot_rows, n_feat, guard, ops):
+        fn = build(mode, m_pad, hot_rows, n_feat, guard, interp)
+        med, low = timed(fn, ops, reps)
+        report({"part": "guards" if n_feat != F_TILE else "rows",
+                "mode": mode, "M": m_pad, "hot_rows": hot_rows,
+                "F": n_feat, "guard": guard}, med, low, n_feat,
+               n_feat if guard != "none" else _round_up(n_feat, F_TILE))
+
+    def run_folds(n_feat, n_bin, n, levels):
+        """``levels``: (mode, m_pad, folds) triples on one set of bin
+        ids; one trace for the lot."""
+        t0 = time.perf_counter()
+        f_tile = ph._tiling(n, n_feat, n_bin)[1]
+        f_pad = _round_up(n_feat, f_tile)
+        binned_t = bin_ids(n, f_pad, n_bin)
+        calls, heads = [], []
+        for mode, m_pad, folds in levels:
+            ops = (binned_t,) + row_operands(n, m_pad, mode)
+            fns = [shipped_level(mode, m_pad, n_feat, n_bin, fold, interp)
+                   for fold in folds]
+            if interp:
+                got = [np.asarray(fn(*ops)) for fn in fns]
+                assert got[0].any() and all(
+                    np.array_equal(g, got[0]) for g in got), (mode, m_pad,
+                                                              n_bin)
+            calls += [(fn, ops) for fn in fns]
+            ships = ph._fold_of(n_bin, m_pad, mode)
+            heads += [{"part": "folds", "mode": mode, "M": m_pad,
+                       "B": n_bin, "F": n_feat, "N": n, "rows": fold[0],
+                       "n_hi": fold[1], "ships": fold == ships}
+                      for fold in folds]
+        for head, (med, low) in zip(heads, kernel_timed(calls, reps)):
+            report(head, med, low, n_feat, n_feat,
+                   tiles=_round_up(n, R_TILE) // R_TILE, f_tile=f_tile)
+        print(f"# {len(calls)} kernels in {time.perf_counter() - t0:.1f} s",
+              file=sys.stderr, flush=True)
+
+    # one feature tile of eight real features: rows of the one-hot
+    if "rows" in parts:
+        for mode in ("int8", "bf16"):
+            for m_pad in (1, 32):
+                ops = operands(n_rows, F_TILE, m_pad, mode)
                 for hot_rows in (256, 128, 64, 32):
-                    run(mode, m_pad, hot_rows, rhs, F_TILE, "none", ops)
+                    run(mode, m_pad, hot_rows, F_TILE, "none", ops)
+    # the shipped kernel, its fold forced; then the cells' shapes and
+    # the 64-bin smoke's, unfolded against what ships
+    if "folds" in parts:
+        levels = (1, 2, 4, 8, 16, 32)
+        run_folds(F_TILE, B, n_rows,
+                  [(mode, m, folds_of(B, m, mode))
+                   for mode in ("int8", "bf16") for m in levels])
+        run_folds(28, B, n_rows,
+                  [("int8", m, [(B, 1), ph._fold_of(B, m, "int8")])
+                   for m in levels])
+        run_folds(28, 64, n_rows, [("int8", m, folds_of(64, m, "int8"))
+                                   for m in levels[:5]])
     # the guard's forms where the last tile has padded slots
-    for n_feat in (28, 13):
-        for mode, m_pad in (("int8", 32), ("int8", 1), ("bf16", 32)):
-            ops = operands(n_rows, _round_up(n_feat, F_TILE), m_pad, mode)
-            for guard in ("none", "tile", "slot", "split"):
-                run(mode, m_pad, B, "shared", n_feat, guard, ops)
+    if "guards" in parts:
+        for n_feat in (28, 13):
+            for mode, m_pad in (("int8", 32), ("int8", 1), ("bf16", 32)):
+                ops = operands(n_rows, _round_up(n_feat, F_TILE), m_pad,
+                               mode)
+                for guard in ("none", "tile", "slot", "split"):
+                    run(mode, m_pad, B, n_feat, guard, ops)
 
     out = {"device": str(jax.devices()[0].device_kind), "rows": n_rows,
            "row_tiles": n_tiles, "reps": reps, "rehearsal": interp,
-           "table": rows}
+           "parts": parts, "table": rows}
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    name = ("hist_dots_probe_guards.json" if args.only_guards
-            else "hist_dots_probe.json")
+    name = "hist_dots_probe_" + "_".join(parts) + ".json"
     with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps({"ok": True, "cases": len(rows),

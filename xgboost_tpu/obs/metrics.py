@@ -550,6 +550,17 @@ class TrainingMetrics:
             "recently traced Pallas level histogram, summed over its "
             "feature tiles: the feature count F (0 until a Pallas "
             "histogram is traced)")
+        # trace-time gauge (ops/pallas_hist._note_onehot_rows): set at a
+        # level of one node, added to at the others, so after a tree's
+        # trace it holds the sum over the tree's levels
+        self.hist_onehot_rows = Gauge(
+            "xgbtpu_hist_onehot_rows",
+            "one-hot rows one feature pushes through the MXU per row "
+            "tile, summed over the Pallas level histograms of the most "
+            "recently traced tree: 352 at depth 6 and 256 bins in int8 "
+            "where the levels of 1-32 nodes fold the bin id's high bits "
+            "into idle lanes, 1536 unfolded (0 until a Pallas histogram "
+            "is traced)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -566,7 +577,8 @@ class TrainingMetrics:
                      self.checkpoints, self.checkpoint_seconds,
                      self.device_memory, self.dispatch_seconds,
                      self.rounds_per_dispatch, self.hist_row_chunks,
-                     self.hist_feature_dots, self.fused_fallback)
+                     self.hist_feature_dots, self.hist_onehot_rows,
+                     self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

@@ -18,6 +18,27 @@ kernel reformulates the histogram as MXU matmuls:
   subsampled-out shards) contribute nothing because the node mask never
   matches.
 
+  A level of few nodes leaves most of the 128 lanes idle, and what a
+  feature costs is its one-hot's ROWS (built on the VPU and pushed
+  through the MXU one by one), whatever the lanes hold.  Such a level
+  FOLDS the bin id's high bits into the idle lanes (:func:`_fold_of`;
+  the rows and lanes kernels): with b = hi * rows + lo,
+      onehot[lo', r]  = 1 iff lo(binned[f, r]) == lo'         (rows, R)
+      rhs_f[r, l]     = gh[r, (l % 2M) // M]
+                        * (hi(binned[f, r]) * M + pos[r]
+                           == (l // 2M) * M + l % M)          (R, n_hi*2M)
+      hist_f         += onehot @ rhs_f                        (rows, n_hi*2M)
+  and cell (lo, hi * 2M + c * M + n) is bin hi * rows + lo of channel c
+  and node n: the caller unfolds it (:func:`_unfold`).  At 256 bins in
+  int8 a level of 1-4 nodes pushes 32 one-hot rows per feature, one of
+  8 or 16 nodes 64 and one of 32 nodes 128, where the unfolded kernel
+  pushes 256; the right-hand operand is then built per feature (one
+  compare, one select, one convert per element).  Every cell still
+  sums the same rows in the same row-tile order, so the sums are the
+  unfolded kernel's bit for bit.  From 64 nodes on (and in the
+  tree-batched kernel, whose lanes the trees fill) nothing folds and
+  the program is the one above.
+
 Bins are consumed feature-major ((F, N), int32) so every block satisfies
 the TPU (8, 128) tile rule; the (N, F) -> (F, N) transpose happens once
 per jit trace (CSE collapses the per-level copies inside one tree).
@@ -72,11 +93,78 @@ def _mxu_mode(precision_mode: str) -> tuple:
     return jnp.bfloat16, jnp.float32, jax.lax.Precision.DEFAULT
 
 
-def _feature_dots(bins, gh_exp, out_ref, lead: tuple, fi, *, n_feat: int,
-                  n_bin: int, f_tile: int, precision_mode: str):
-    """The per-feature loop of every level kernel: one (B, R) one-hot
-    and one ``(B, R) @ (R, lanes)`` dot per REAL feature of this feature
-    tile, added into rows ``f * n_bin`` of ``out_ref[lead]``.
+def _fold_of(n_bin: int, m_pad: int, precision_mode: str) -> tuple:
+    """``(rows, n_hi)``: the one-hot rows a level kernel pushes per
+    feature and the bin-id groups it folds into the lane dim, from the
+    level's shapes alone.  ``(n_bin, 1)`` is the unfolded program.
+
+    A bin id is ``hi * rows + lo``.  The one-hot is over ``lo`` only and
+    the right-hand operand, built per feature, puts a row's (g, h) in
+    lane ``hi * 2M + channel * M + node``, so the high bits ride the
+    lanes that 2M < 128 leaves idle.  ``rows`` is a power of two (so
+    ``hi`` and ``lo`` are a shift and a mask), at least the one-hot
+    dtype's sublane tile, and ``n_hi * 2 * m_pad <= 128``.  An ``n_bin``
+    that is no power of two (67, 68) folds too: the last group is partly
+    empty and the caller cuts it away (``[:n_bin]`` after the unfold).
+
+    What a feature costs per row tile is linear in both (the shipped
+    kernel at every fold, ``tools/hist_dots_probe.py``, PERF.md §7): a
+    pushed one-hot row 1.3-1.4 ns in int8 (2.7 in bf16), a lane of the
+    per-feature operand 0.9-1.0 ns (1.3): ``_LANE_COST`` of a row.  So
+    ``(rows, n_hi)`` costs ``rows + _LANE_COST * n_hi * 2 * m_pad``
+    against ``n_bin`` unfolded; the cheapest power of two is taken, and
+    the level stays unfolded unless that saves ``_FOLD_PAYS`` of the
+    cost.  At 256 bins in int8: 32 rows up to 4 nodes, 64 at 8 and 16,
+    128 at 32 (measured 2.65 us a step of eight features against 3.11;
+    the count says 218 against 256); a node-tiled deep level (m_pad =
+    64, no idle lane) runs the unfolded program, as does 64 bins from
+    16 nodes on (folded by two it measured 3.27 us a step of 28
+    features against 2.77)."""
+    floor = {"int8": 32, "bf16": 16}.get(precision_mode, 8)
+    best, best_cost = (n_bin, 1), n_bin * (1.0 - _FOLD_PAYS)
+    rows = floor
+    while rows < n_bin:
+        n_hi = -(-n_bin // rows)
+        lanes = n_hi * 2 * m_pad
+        cost = rows + _LANE_COST * lanes
+        if lanes <= 128 and cost < best_cost:
+            best, best_cost = (rows, n_hi), cost
+        rows *= 2
+    return best
+
+
+# what a lane of the folded level's per-feature operand costs, in one-hot
+# rows, and the share of an unfolded level's cost a fold has to save
+# before the level is folded (both measured: see _fold_of)
+_LANE_COST = 0.7
+_FOLD_PAYS = 0.1
+
+
+def _note_onehot_rows(n_node: int, rows: int) -> None:
+    """Gauge ``xgbtpu_hist_onehot_rows``: the one-hot rows one feature
+    pushes through the MXU per row tile, summed over the levels of the
+    tree last traced: set at a level of one node, added to at the
+    others (trace time, like its neighbours).  Depth 6 at 256 bins in
+    int8: 32 + 32 + 32 + 64 + 64 + 128 = 352 where the unfolded kernel
+    pushes 1,536."""
+    from xgboost_tpu.obs import training_metrics
+    gauge = training_metrics().hist_onehot_rows
+    if n_node == 1:
+        gauge.set(float(rows))
+    else:
+        gauge.inc(float(rows))
+
+
+def _feature_dots(bins, rhs_of, out_ref, lead: tuple, fi, *, n_feat: int,
+                  rows: int, lo_mask, f_tile: int, precision_mode: str):
+    """The per-feature loop of every level kernel: one ``(rows, R)``
+    one-hot and one ``(rows, R) @ (R, lanes)`` dot per REAL feature of
+    this feature tile, added into rows ``f * rows`` of ``out_ref[lead]``.
+    ``rhs_of(b)`` gives the right-hand operand for a feature's bin ids
+    ``b`` (1, R): the shared ``gh_exp`` on an unfolded level (``rows ==
+    n_bin``, ``lo_mask`` None: the one-hot is over the bin id), the
+    per-feature folded operand otherwise (the one-hot is over ``b &
+    lo_mask``, :func:`_fold_of`).
 
     A slot that only pads the feature tile (``fi * f_tile + f >=
     n_feat``: 4 of 32 at 28 features and 256 bins, 3 of 16 at 13; its
@@ -88,26 +176,28 @@ def _feature_dots(bins, gh_exp, out_ref, lead: tuple, fi, *, n_feat: int,
     straight-line code it always was: where F fills its tiles (or
     ``f_tile == F``, the 64-bin jobs) no guard is in the program.
 
-    bins: (f_tile, R) int32; gh_exp: (lanes, R); fi: this step's index
-    along the feature-tile grid axis.  Traced once per level, so the
-    gauge ``xgbtpu_hist_feature_dots`` holds the dots one row tile of
-    the last level traced runs over all its feature tiles: F."""
+    bins: (f_tile, R) int32; fi: this step's index along the
+    feature-tile grid axis.  Traced once per level, so the gauge
+    ``xgbtpu_hist_feature_dots`` holds the dots one row tile of the
+    last level traced runs over all its feature tiles: F."""
     from xgboost_tpu.obs import training_metrics
     training_metrics().hist_feature_dots.set(float(n_feat))
     hot_dtype, acc_dtype, prec = _mxu_mode(precision_mode)
     r_tile = bins.shape[1]
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_bin, r_tile), 0)
+    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, r_tile), 0)
     last = (n_feat - 1) // f_tile           # index of the last tile
     n_real = n_feat - last * f_tile         # its slots that hold a feature
 
     def slots(lo, hi):
         for f in range(lo, hi):
-            onehot = (bins[f:f + 1, :] == bin_ids).astype(hot_dtype)  # (B, R)
+            b = bins[f:f + 1, :]
+            lo_id = b if lo_mask is None else b & lo_mask
+            onehot = (lo_id == bin_ids).astype(hot_dtype)    # (rows, R)
             acc = jax.lax.dot_general(
-                onehot, gh_exp, (((1,), (1,)), ((), ())),
+                onehot, rhs_of(b), (((1,), (1,)), ((), ())),
                 precision=prec,
-                preferred_element_type=acc_dtype)            # (B, lanes)
-            out_ref[lead + (slice(f * n_bin, (f + 1) * n_bin),
+                preferred_element_type=acc_dtype)            # (rows, lanes)
+            out_ref[lead + (slice(f * rows, (f + 1) * rows),
                             slice(None))] += acc
 
     slots(0, n_real)
@@ -116,15 +206,16 @@ def _feature_dots(bins, gh_exp, out_ref, lead: tuple, fi, *, n_feat: int,
 
 
 def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
-                 n_bin: int, m_pad: int, f_tile: int, n_feat: int,
-                 precision_mode: str, rpl: int, rpa: int):
+                 m_pad: int, f_tile: int, n_feat: int,
+                 precision_mode: str, rpl: int, rpa: int,
+                 rows: int, n_hi: int):
     """One (node_tile, feature_tile, row_tile) grid step.
 
     binned_ref: (f_tile, R) u8|int32 bin ids, feature-major
     pos_ref:    (1, R) int32 node position (-1 = inactive)
     gh_ref:     (2, R) f32|int32 grad/hess
-    out_ref:    (f_tile * n_bin, 2 * m_pad) accumulator for the m_pad
-                nodes of THIS node tile (grid dim 0) — deep levels
+    out_ref:    (f_tile * rows, n_hi * 2 * m_pad) accumulator for the
+                m_pad nodes of THIS node tile (grid dim 0) — deep levels
                 (n_node > m_pad) tile the node dim so the block never
                 outgrows VMEM.
     n_feat:     the real feature count F: slots past it in the last
@@ -141,6 +232,15 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                 an int8 job past 16.7M rows sums each CHUNK of ``rpa``
                 tiles exactly into a block of its own, and the caller
                 widens and adds the chunks (:func:`_sum_chunks`).
+    rows, n_hi: the fold (:func:`_fold_of`).  ``n_hi == 1``: ``rows ==
+                n_bin``, the one-hot is over the bin id and every
+                feature's dot shares one ``gh_exp``.  ``n_hi > 1`` (a
+                shallow level, single node tile): the one-hot is over
+                the bin id's low bits and each feature's right-hand
+                operand holds the row's (g, h) in the lane of its bin
+                id's high bits, channel and node; every output cell
+                still sums the same rows in the same row-tile order as
+                unfolded, so the sums are the same bit for bit.
 
     EVERY per-row operand keeps rows in the LANE dim: TPU arrays tile
     to (8, 128), so (N, 1)/(N, 2) operands are physically inflated
@@ -161,21 +261,64 @@ def _hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
+    hot_dtype = _mxu_mode(precision_mode)[0]
     pos = pos_ref[0:1, :]                                    # (1, R)
-    # gh_exp[l, r] = gh[l // m_pad, r] masked by (pos[r] == l % m_pad)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (m2, r_tile), 0)
-    node_of_sub = m_base + jnp.where(sub < m_pad, sub, sub - m_pad)
-    ghsel = jnp.where(sub < m_pad, gh_ref[0:1, :], gh_ref[1:2, :])
-    active = (pos == node_of_sub)                            # (2M, R)
-    gh_exp = jnp.where(active, ghsel,
-                       0).astype(_mxu_mode(precision_mode)[0])
+    # lane l of the right-hand operand: bin-id group l // 2M (0 when
+    # unfolded), channel (l % 2M) // M, node l % M of this node tile
+    sub = jax.lax.broadcasted_iota(jnp.int32, (n_hi * m2, r_tile), 0)
+    if n_hi == 1:
+        # gh_exp[l, r] = gh[l // m_pad, r] masked by (pos[r] == l % m_pad)
+        node_of_sub = m_base + jnp.where(sub < m_pad, sub, sub - m_pad)
+        ghsel = jnp.where(sub < m_pad, gh_ref[0:1, :], gh_ref[1:2, :])
+        active = (pos == node_of_sub)                        # (2M, R)
+        gh_exp = jnp.where(active, ghsel, 0).astype(hot_dtype)
+
+        def rhs_of(b):
+            return gh_exp
+    else:
+        # one key per lane, hi * M + node, and per row and feature,
+        # (b >> shift) * M + pos: one compare selects the lane.  A row
+        # that is in no node of the level (pos < 0, or past the level's
+        # nodes) takes a key below every lane's
+        hi_of_sub = sub // m2
+        within = sub - hi_of_sub * m2
+        is_h = within >= m_pad
+        lane_key = hi_of_sub * m_pad + jnp.where(is_h, within - m_pad,
+                                                 within)
+        ghsel = jnp.where(is_h, gh_ref[1:2, :], gh_ref[0:1, :])
+        pos_key = jnp.where((pos < 0) | (pos >= m_pad), -n_hi * m_pad, pos)
+        shift = rows.bit_length() - 1
+
+        def rhs_of(b):
+            key = (b >> shift) * m_pad + pos_key             # (1, R)
+            return jnp.where(key == lane_key, ghsel,
+                             0).astype(hot_dtype)            # (lanes, R)
     # bins may arrive u8 (the entry's resident pre-transposed operand —
     # zero per-round transpose/layout-copy cost) or int32 (the
     # in-graph transpose fallback); widen in-register either way
     bins = binned_ref[:].astype(jnp.int32)                   # (f_tile, R)
-    _feature_dots(bins, gh_exp, out_ref, (0,), pl.program_id(1),
-                  n_feat=n_feat, n_bin=n_bin, f_tile=f_tile,
+    _feature_dots(bins, rhs_of, out_ref, (0,), pl.program_id(1),
+                  n_feat=n_feat, rows=rows,
+                  lo_mask=None if n_hi == 1 else rows - 1, f_tile=f_tile,
                   precision_mode=precision_mode)
+
+
+def _unfold(out: jax.Array, f_pad: int, n_bin: int, rows: int, n_hi: int,
+            m_pad: int) -> jax.Array:
+    """A folded level's ``(..., f_pad * rows, n_hi * 2 * m_pad)`` block
+    back to the unfolded kernel's ``(..., f_pad * n_bin, 2 * m_pad)``:
+    bin ``hi * rows + lo`` of a feature sits at row ``lo``, lanes
+    ``hi * 2M ...``; the bins past ``n_bin`` of a partly empty last
+    group are cut away.  ``n_hi == 1`` passes through: no op is added
+    to the program."""
+    if n_hi == 1:
+        return out
+    lead = out.shape[:-2]
+    k = len(lead)
+    out = out.reshape(lead + (f_pad, rows, n_hi, 2 * m_pad))
+    out = out.transpose(tuple(range(k)) + (k, k + 2, k + 1, k + 3))
+    out = out.reshape(lead + (f_pad, n_hi * rows, 2 * m_pad))[..., :n_bin, :]
+    return out.reshape(lead + (f_pad * n_bin, 2 * m_pad))
 
 
 def _rows_per_acc(r_tile: int) -> int:
@@ -338,11 +481,14 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
                     constant_values=-1)[None, :]             # (1, n_pad)
     gh_t = jnp.pad(gh_in.T, ((0, 0), (0, n_pad - N)))        # (2, n_pad)
 
+    rows, n_hi = _fold_of(n_bin, m_pad, precision)
+    _note_onehot_rows(n_node, rows * n_m_tiles)
+
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
-    kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
+    kernel = functools.partial(_hist_kernel, m_pad=m_pad,
                                f_tile=f_tile, n_feat=F,
                                precision_mode=precision,
-                               rpl=n_tiles, rpa=rpa)
+                               rpl=n_tiles, rpa=rpa, rows=rows, n_hi=n_hi)
     # a chunk's blocks follow the previous chunk's along the node-tile
     # axis; with one chunk the index map is the plain one
     if n_chunks == 1:
@@ -359,13 +505,16 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
             pl.BlockSpec((1, r_tile), lambda mi, fi, ri: (0, ri)),
             pl.BlockSpec((2, r_tile), lambda mi, fi, ri: (0, ri)),
         ],
-        out_specs=pl.BlockSpec((1, f_tile * n_bin, 2 * m_pad), out_index),
+        out_specs=pl.BlockSpec((1, f_tile * rows, n_hi * 2 * m_pad),
+                               out_index),
         out_shape=jax.ShapeDtypeStruct(
-            (n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
+            (n_chunks * n_m_tiles, f_pad * rows, n_hi * 2 * m_pad),
+            out_dtype),
         interpret=interpret,
         name="hist_level_rows",
     )(binned_t, pos_t, gh_t)
-    out = _sum_chunks(out, n_chunks, 0)
+    out = _unfold(_sum_chunks(out, n_chunks, 0), f_pad, n_bin, rows, n_hi,
+                  m_pad)
 
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
@@ -419,11 +568,14 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     gh_t = gh_t.transpose(2, 0, 1).reshape(2, L * n_pad)
     bt = binned_t.transpose(1, 0, 2).reshape(f_pad, L * n_pad)
 
+    rows, n_hi = _fold_of(n_bin, m_pad, precision)
+    _note_onehot_rows(n_node, rows * n_m_tiles)
+
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
-    kernel = functools.partial(_hist_kernel, n_bin=n_bin, m_pad=m_pad,
+    kernel = functools.partial(_hist_kernel, m_pad=m_pad,
                                f_tile=f_tile, n_feat=F,
                                precision_mode=precision,
-                               rpl=rpl, rpa=rpa)
+                               rpl=rpl, rpa=rpa, rows=rows, n_hi=n_hi)
 
     def acc_of(ri):                 # lane-major, a lane's chunks within
         if n_chunks == 1:
@@ -438,16 +590,19 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
             pl.BlockSpec((2, r_tile), lambda mi, fi, ri: (0, ri)),
         ],
         out_specs=pl.BlockSpec(
-            (1, f_tile * n_bin, 2 * m_pad),
+            (1, f_tile * rows, n_hi * 2 * m_pad),
             lambda mi, fi, ri: (acc_of(ri) * n_m_tiles + mi, fi, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (L * n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad), out_dtype),
+            (L * n_chunks * n_m_tiles, f_pad * rows, n_hi * 2 * m_pad),
+            out_dtype),
         interpret=interpret,
         name="hist_level_lanes",
     )(bt, pos_t, gh_t)
 
-    out = _sum_chunks(out.reshape(L, n_chunks * n_m_tiles, -1, 2 * m_pad),
-                      n_chunks, 1)
+    out = _sum_chunks(
+        out.reshape(L, n_chunks * n_m_tiles, -1, n_hi * 2 * m_pad),
+        n_chunks, 1)
+    out = _unfold(out, f_pad, n_bin, rows, n_hi, m_pad)
     out = out.reshape(L, n_m_tiles, f_pad, n_bin, 2, m_pad)
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
@@ -542,8 +697,8 @@ def _batched_hist_kernel(binned_ref, pos_ref, gh_ref, out_ref, *,
                        0).astype(_mxu_mode(precision_mode)[0])
 
     bins = binned_ref[:].astype(jnp.int32)
-    _feature_dots(bins, gh_exp, out_ref, (0, 0), pl.program_id(2),
-                  n_feat=n_feat, n_bin=n_bin, f_tile=f_tile,
+    _feature_dots(bins, lambda b: gh_exp, out_ref, (0, 0), pl.program_id(2),
+                  n_feat=n_feat, rows=n_bin, lo_mask=None, f_tile=f_tile,
                   precision_mode=precision_mode)
 
 
@@ -655,6 +810,7 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
     T_pad = t_tiles * t_tile
     n_tiles = n_pad // r_tile
     rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
+    _note_onehot_rows(n_node, n_bin * n_m_tiles)       # never folded
     if n_pad != N or T_pad != T:
         gh = jnp.pad(gh, ((0, T_pad - T), (0, n_pad - N), (0, 0)))
         pos = jnp.pad(pos, ((0, T_pad - T), (0, n_pad - N)),
