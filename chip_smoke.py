@@ -568,6 +568,15 @@ def kernel_cases(n_rows: int = 70_000):
     # feature tiles of 8, three row chunks, both layouts
     solo(n_rows, 13, 256, 32, "int8", "int32", True, chunks=3)
     solo(n_rows, 13, 256, 128, "int8", "int32", False, chunks=3)
+    # the Epsilon shape: F=2000 in 250 feature tiles of 8 with no padded
+    # slot; a folded shallow level, the 32-node level folded by two, the
+    # unfolded 64-node tile (native layout) and the 128-node level's two
+    # node tiles (standard layout, the relayout); fewer rows, the grid's
+    # feature axis is what is new
+    wide_rows = max(n_rows // 8, 2500)
+    for M in (1, 32, 64):
+        solo(wide_rows, 2000, 256, M, "int8", "int32", True)
+    solo(wide_rows, 2000, 256, 128, "int8", "int32", False)
 
     def batched(T, N, F, B, M, precision):
         def build(interpret):

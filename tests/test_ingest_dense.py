@@ -153,6 +153,47 @@ def test_thread_pool_gives_the_serial_loop_s_bytes(monkeypatch, threads):
     assert pooled_bins.tobytes() == bins.tobytes()
 
 
+@pytest.mark.parametrize("F,rows,group", [(28, 1 << 18, 1), (13, 1 << 18, 1),
+                                          (2000, 16777, 16)])
+def test_tasks_follow_the_matrix_s_width(monkeypatch, F, rows, group):
+    """A binning block is sized in cells and a cut-proposal task in
+    columns: what they were at 28 and 13 columns (2^18 rows, one
+    column), 24 + 6 blocks and 125 groups of 16 at 400,000 + 100,000 x
+    2,000, where 2^18 rows were two tasks and one."""
+    monkeypatch.setattr(binning, "_THREADS", 8)
+    assert binning._block_rows(F) == rows
+    assert binning._column_group(F) == group
+
+
+@pytest.mark.parametrize("F", [28, 2000])
+def test_blocks_and_column_groups_give_the_serial_loop_s_bytes(monkeypatch, F):
+    n = 20000
+    X = _normal(n, F, 36)
+    X[np.random.default_rng(37).random(X.shape) < 0.01] = np.nan
+    X[:, F // 2] = np.round(X[:, F // 2])           # a column of ties
+    with monkeypatch.context() as m:
+        # one column a task, one block, one thread
+        m.setattr(binning, "_THREADS", 1)
+        m.setattr(binning, "_LINE", 1)
+        m.setattr(binning, "_BIN_CELLS", 1 << 40)
+        cuts, bins = _quantize(DMatrix(X), 256)
+    monkeypatch.setattr(binning, "_SKETCH_MIN", 1 << 12)    # pooled
+    monkeypatch.setattr(binning, "_THREADS", 8)
+    monkeypatch.setattr(binning, "_BIN_BLOCK", 1 << 13)
+    assert -(-n // binning._block_rows(F)) == 3
+    assert binning._column_group(F) == (16 if F == 2000 else 1)
+    got_cuts, got_bins = _quantize(DMatrix(X), 256)
+    assert got_cuts.cut_values.tobytes() == cuts.cut_values.tobytes()
+    assert got_cuts.n_cuts.tobytes() == cuts.n_cuts.tobytes()
+    assert got_bins.tobytes() == bins.tobytes()
+    # and a matrix narrower than num_col= still pads with empty columns
+    wide = compute_cuts(DMatrix(X[:, :F - 3], num_col=F), max_bin=256,
+                        sketch_eps=1.0 / 256)
+    assert wide.n_cuts[F - 3:].tolist() == [0, 0, 0]
+    np.testing.assert_array_equal(wide.cut_values[:F - 3, :cuts.cut_values.shape[1]],
+                                  cuts.cut_values[:F - 3])
+
+
 @pytest.mark.parametrize("n,chunk", [(5000, 1 << 22), (20000, 3000)])
 def test_unweighted_sketch_is_the_weighted_one_s_twin(n, chunk):
     rng = np.random.default_rng(n)

@@ -488,6 +488,23 @@ def test_fold_of_table(n_bin, m_pad, precision, want):
         assert rows & (rows - 1) == 0 and (n_hi - 1) * rows < n_bin
 
 
+def _trace_tree_levels(F, depth):
+    """Trace (never run) the level histograms of one tree at 256 bins in
+    int8, kernel-native up to 64 nodes as the grower asks for them.  A
+    fresh function each call: eval_shape caches traces."""
+    def tree(binned, gh, pos):
+        prep_bt = ph.transpose_bins(binned, 256)
+        q, scale = ph.quantize_gh(gh)
+        return [ph._hist_pallas_pre(prep_bt, q, scale, pos, binned.shape,
+                                    1 << d, 256, "int8", False,
+                                    native=(1 << d) <= 64)
+                for d in range(depth)]
+    jax.eval_shape(lambda *a: tree(*a),
+                   jax.ShapeDtypeStruct((4096, F), jnp.uint8),
+                   jax.ShapeDtypeStruct((4096, 2), jnp.float32),
+                   jax.ShapeDtypeStruct((4096,), jnp.int32))
+
+
 def test_onehot_rows_gauge_after_a_depth_6_trace(monkeypatch):
     """Six levels of one tree at 256 bins, traced (never run): the gauge
     holds the one-hot rows a feature pushes per row tile summed over the
@@ -496,17 +513,8 @@ def test_onehot_rows_gauge_after_a_depth_6_trace(monkeypatch):
     from xgboost_tpu import obs
     gauge = obs.training_metrics().hist_onehot_rows
 
-    def tree(binned, gh, pos):
-        prep_bt = ph.transpose_bins(binned, 256)
-        q, scale = ph.quantize_gh(gh)
-        return [ph._hist_pallas_pre(prep_bt, q, scale, pos, binned.shape,
-                                    1 << d, 256, "int8", False, native=True)
-                for d in range(6)]
-    args = (jax.ShapeDtypeStruct((4096, 28), jnp.uint8),
-            jax.ShapeDtypeStruct((4096, 2), jnp.float32),
-            jax.ShapeDtypeStruct((4096,), jnp.int32))
-    def trace():            # a fresh function: eval_shape caches traces
-        jax.eval_shape(lambda *a: tree(*a), *args)
+    def trace():
+        _trace_tree_levels(28, 6)
     trace()
     assert gauge.value == 352
     assert "xgbtpu_hist_onehot_rows 352" in obs.registry().render()
@@ -515,3 +523,45 @@ def test_onehot_rows_gauge_after_a_depth_6_trace(monkeypatch):
     monkeypatch.setattr(ph, "_fold_of", _unfolded)
     trace()
     assert gauge.value == 1536
+
+
+# ------------------------- wide F and node tiles (ISSUE 36, the Epsilon shape)
+@pytest.mark.parametrize("M", [32, 64, 128])
+@pytest.mark.parametrize("F,N", [(264, 4200), (2000, 2100)])
+def test_wide_level_equals_scatter_in_int8(F, N, M, monkeypatch):
+    """33 and 250 feature tiles of 8 at 256 bins, a few row tiles, 20 %
+    inactive rows: the level kernel's int32 sums are the XLA scatter's
+    of the same quantized gradients, bit for bit, at 32 nodes (folded by
+    two), 64 (one unfolded 64-node tile) and 128 (two node tiles, the
+    relayout of the standard layout)."""
+    binned, bt, gh_in, scale, gh_ref, pos = _fold_case(N, F, 256, M, "int8")
+    raw = _spy_on_pallas_call(monkeypatch)
+    got = np.asarray(ph._hist_pallas_pre(bt, gh_in, scale, pos, (N, F), M,
+                                         256, "int8", True))
+    rows, n_hi = ph._fold_of(256, min(M, 64), "int8")
+    assert (rows, n_hi) == ((128, 2) if M == 32 else (256, 1))
+    assert bt.shape[0] == F and F % 8 == 0          # no padded slot
+    assert raw[0].shape == (-(-M // 64), F * rows, n_hi * 2 * min(M, 64))
+    want = np.asarray(build_level_histogram(
+        jnp.asarray(binned), jnp.asarray(gh_ref), pos, M, 256))
+    assert got.shape == (M, F, 256, 2) and np.abs(want).max() > 0
+    np.testing.assert_array_equal(got, want * np.asarray(scale / 127.0))
+
+
+@pytest.mark.parametrize("depth,F,tiles,node_tiles,rows", [
+    (6, 28, 4, 6, 352), (6, 13, 2, 6, 352), (8, 2000, 250, 9, 1120),
+    (8, 264, 33, 9, 1120)])
+def test_grid_gauges_after_a_traced_tree(depth, F, tiles, node_tiles, rows):
+    """A tree's level histograms at 256 bins, traced (never run): the
+    gauges hold the feature tiles of the last level and the node tiles
+    and one-hot rows summed over the levels (the 128-node level counts
+    two tiles and 2 x 256 rows), and the registry renders them."""
+    from xgboost_tpu import obs
+    tm = obs.training_metrics()
+    _trace_tree_levels(F, depth)
+    assert tm.hist_feature_tiles.value == tiles
+    assert tm.hist_node_tiles.value == node_tiles
+    assert tm.hist_onehot_rows.value == rows
+    text = obs.registry().render()
+    assert f"xgbtpu_hist_feature_tiles {tiles}" in text
+    assert f"xgbtpu_hist_node_tiles {node_tiles}" in text

@@ -165,6 +165,34 @@ def test_auto_k_by_rows(rows, k):
     assert bst._resolve_rounds_per_dispatch(rows) == k
 
 
+@pytest.mark.parametrize("rows,call,plan,dispatches", [
+    (400_000, 10, 11, 1), (400_000, 25, 11, 3), (8_400_000, 10, 1, 10)])
+def test_auto_k_at_a_cell_s_rows_is_one_dispatch_and_the_same_trees(
+        monkeypatch, rows, call, plan, dispatches):
+    """A call of 10 rounds on 400,000 rows is ONE dispatch of a ten-round
+    scan (auto-K = ceil(4,029,033 / 400,000) = 11), where 8.4M rows take
+    ten; the trees, margins and eval lines are those of K = 1, byte for
+    byte, at a depth whose levels pass 32 nodes.  The data is 1,500
+    rows: the resolver is given the stated row count."""
+    from xgboost_tpu.obs import span_totals, training_metrics
+    params = {**PARAMS, "max_depth": 7, "eval_metric": "logloss"}
+    assert Booster(params)._resolve_rounds_per_dispatch(rows) == plan
+    resolve = Booster._resolve_rounds_per_dispatch
+    monkeypatch.setattr(
+        Booster, "_resolve_rounds_per_dispatch",
+        lambda self, n_rows, override=None: resolve(
+            self, rows if override is None else n_rows, override))
+    b1, l1, d = _run(params, call, 1)
+    before = span_totals().count.values().get("train.dispatch", 0)
+    bk, lk, _ = _run(params, call, None)
+    assert (span_totals().count.values()["train.dispatch"] - before
+            == dispatches)
+    assert training_metrics().rounds_per_dispatch.value == min(
+        plan, call - (dispatches - 1) * plan)
+    assert len(lk) == call
+    _assert_bitwise_equal(b1, l1, bk, lk, d)
+
+
 def test_auto_plan_reported_once():
     X, y = make_data(n=400)
     d = xgb.DMatrix(X, label=y)

@@ -158,26 +158,62 @@ def test_cuts_and_bin_spans_say_whether_the_dense_source_was_read(
     assert len(built) == (1 if dense else 3)
 
 
-@pytest.fixture(scope="module")
-def scan_op_names():
-    """op_name metadata of the compiled scan of a run whose histograms
-    go through the Pallas path (interpreted here), so that the operand
-    it builds in-graph is there to be named."""
+def _live_scans() -> dict:
+    """Text of every live compiled scan, by its hash."""
     import jax.extend
+    return {hash(t): t
+            for ex in jax.extend.backend.get_backend().live_executables()
+            for m in ex.hlo_modules() if "_scan_rounds_impl" in m.name
+            for t in [m.to_string()]}
+
+
+@pytest.fixture(scope="module", params=[3, 6, 7])
+def depth_and_op_names(request):
+    """(max_depth, op_name metadata of the compiled scan) of a run whose
+    histograms go through the Pallas path (interpreted here), so that
+    the operand it builds in-graph is there to be named.  A row count
+    of its own for each depth: the scan it compiles is a new one."""
+    depth = request.param
+    before = _live_scans()
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("XGBTPU_HIST", "pallas_int8")
-        _train(257, rounds=2, k=2)
-    names = []
-    for ex in jax.extend.backend.get_backend().live_executables():
-        for m in ex.hlo_modules():
-            if "_scan_rounds_impl" in m.name:
-                names += re.findall(r'op_name="([^"]*)"', m.to_string())
-    return names
+        _train(257 + 2 * depth, rounds=2, k=2,
+               params={**PARAMS, "max_depth": depth})
+    new = [t for h, t in _live_scans().items() if h not in before]
+    assert new, "the run compiled no scan of its own"
+    return depth, [n for t in new for n in re.findall(r'op_name="([^"]*)"', t)]
 
 
 @pytest.mark.parametrize("scope", SCOPES)
-def test_compiled_scan_carries_the_scope(scan_op_names, scope):
-    assert any(f"/{scope}" in n for n in scan_op_names), scope
+def test_compiled_scan_carries_the_scope(depth_and_op_names, scope):
+    _, names = depth_and_op_names
+    assert any(f"/{scope}" in n for n in names), scope
+
+
+DEEP = ("deep.hist", "deep.split", "deep.route")
+
+
+@pytest.mark.parametrize("scope", DEEP)
+def test_levels_past_32_nodes_carry_their_own_scopes(depth_and_op_names,
+                                                     scope):
+    """Depth 7: the level of 64 nodes is under deep.*, the levels of
+    1-32 nodes and the terminal one under grow.*; a tree whose levels
+    stop at 32 nodes (depth 3; depth 6: 32, then the terminal 64) never
+    enters them.  The benchmark's reader finds a scope as the innermost
+    ``[a-z_]+\\.[a-z_]+`` component of an op's name."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from readers import trace_scope_time
+    depth, names = depth_and_op_names
+    hits = [n for n in names if f"/{scope}" in n]
+    if depth < 7:
+        assert not hits
+        return
+    assert hits and all(trace_scope_time.innermost(n) == scope
+                        or "grow.widen" in n for n in hits)
+    twin = scope.replace("deep.", "grow.")
+    assert any(trace_scope_time.innermost(n) == twin for n in names)
 
 
 def test_spans_and_scopes_leave_the_model_bytes_alone(monkeypatch):

@@ -44,8 +44,18 @@ DEFAULT_TRIM_MARGIN = 4
 # ``np.searchsorted`` release the GIL, so they map over this many
 # threads; the result is the serial loop's, byte for byte.
 _THREADS = max(1, min(8, (os.cpu_count() or 2) - 1))
-_BIN_BLOCK = 1 << 18    # rows of one binning task (29 MB of float32
-#                         at 28 columns: read once, column by column)
+_BIN_BLOCK = 1 << 18    # rows of one binning task, at most, and
+_BIN_CELLS = 1 << 25    # its cells: 2^18 rows up to 128 columns,
+#                         16,777 at 2,000 (where 2^18 rows were two
+#                         tasks for 400,000 rows).  Not fewer: a task
+#                         bins column by column, and a column's
+#                         searchsorted has to dwarf the interpreter's
+#                         work between two of them or the threads queue
+#                         on the GIL (3,670 rows x 2,000 on 7 threads:
+#                         slower than one thread)
+_LINE = 16              # float32 cells of a cache line: the columns of
+#                         one cut-proposal task of a wide matrix
+_GATHER_ROWS = 1 << 10  # rows of one gather step of such a task
 _SKETCH_MIN = 1 << 16   # longer columns go through sketch_column
 
 
@@ -63,6 +73,17 @@ def holds_dense(dmat) -> int:
     return int(_dense_source(dmat) is not None)
 
 
+def _block_rows(width: int) -> int:
+    """Rows of one binning task of a matrix ``width`` columns wide."""
+    return max(1, min(_BIN_BLOCK, _BIN_CELLS // max(width, 1)))
+
+
+def _column_group(n_col: int) -> int:
+    """Columns of one cut-proposal task: one, until there are columns
+    enough to keep every thread in tasks of a cache line's worth."""
+    return max(1, min(_LINE, n_col // (4 * _THREADS)))
+
+
 def _map(fn, tasks) -> list:
     tasks = list(tasks)
     if _THREADS == 1 or len(tasks) < 2:
@@ -71,14 +92,29 @@ def _map(fn, tasks) -> list:
         return list(pool.map(fn, tasks))
 
 
-def _dense_column(arr: np.ndarray, missing: float, f: int) -> np.ndarray:
-    """Column ``f`` of a dense source less its missing cells, in row
-    order: what ``column_values(f)`` returns of the CSR built from it."""
-    if f >= arr.shape[1]:       # num_col= wider than the array
-        return np.zeros(0, np.float32)
-    col = np.ascontiguousarray(arr[:, f])
-    present = ~np.isnan(col) if np.isnan(missing) else col != missing
-    return col if present.all() else col[present]
+def _dense_columns(arr: np.ndarray, missing: float, f0: int, f1: int):
+    """Columns ``f0 .. f1-1`` of a dense source, each less its missing
+    cells and in row order: what ``column_values(f)`` returns of the CSR
+    built from it.  A group of columns is gathered in ONE pass over the
+    rows, a block of rows at a time so that the block's cache lines are
+    read once for all of them (a row's line holds 16 neighbouring
+    cells: gathered one by one, each of 2,000 columns walks all N lines
+    at a stride of 8 KB)."""
+    n, width = arr.shape[0], max(0, min(f1, arr.shape[1]) - f0)
+    if width == 1:
+        got = np.ascontiguousarray(arr[:, f0])[None]
+    else:
+        got = np.empty((width, n), arr.dtype)
+        for r in range(0, n, _GATHER_ROWS):
+            got[:, r:r + _GATHER_ROWS] = arr[r:r + _GATHER_ROWS,
+                                             f0:f0 + width].T
+    for f in range(f0, f1):
+        if f - f0 >= width:     # num_col= wider than the array
+            yield np.zeros(0, np.float32)
+            continue
+        col = got[f - f0]
+        present = ~np.isnan(col) if np.isnan(missing) else col != missing
+        yield col if present.all() else col[present]
 
 
 @dataclasses.dataclass
@@ -120,23 +156,30 @@ def compute_cuts(dmat: DMatrix, max_bin: int = 256, sketch_eps: float = 0.03,
     src = None if hess_weights is not None else _dense_source(dmat)
     small = max(2, int(sketch_ratio / max(sketch_eps, 1.0 / max_bin)))
 
-    def column_cuts(f: int) -> np.ndarray:
-        if src is not None:
-            vals, w = _dense_column(*src, f), None
-        else:
-            rows, vals = dmat.column_values(f)
-            w = None if hess_weights is None else hess_weights[rows]
+    def cuts_of(vals, w=None) -> np.ndarray:
         if len(vals) > _SKETCH_MIN:
             summary = sketch_column(vals, w, sketch_eps, sketch_ratio)
         else:
             summary = prune_summary(make_summary(vals, w), small)
         return propose_cuts(summary, max_bin - 1)  # room for missing bin
 
+    def column_cuts(f: int) -> np.ndarray:
+        rows, vals = dmat.column_values(f)
+        return cuts_of(vals,
+                       None if hess_weights is None else hess_weights[rows])
+
+    def group_cuts(f0: int) -> list:
+        return [cuts_of(v) for v in _dense_columns(*src, f0, min(f0 + group, F))]
+
     F = dmat.num_col
-    if src is not None and src[0].shape[0] > _SKETCH_MIN:
-        per_feature = _map(column_cuts, range(F))
-    else:
+    if src is None:
         per_feature = [column_cuts(f) for f in range(F)]
+    else:
+        group = _column_group(F)
+        tasks = range(0, F, group)
+        groups = (_map(group_cuts, tasks) if src[0].shape[0] > _SKETCH_MIN
+                  else [group_cuts(f0) for f0 in tasks])
+        per_feature = [c for g in groups for c in g]
     return pack_cuts(align_cut_lists(per_feature, bin_align,
                                      bin_align_margin))
 
@@ -310,9 +353,10 @@ def _bin_dense_into(out: np.ndarray, X: np.ndarray, cuts: CutMatrix,
     goes where ``searchsorted`` sends it.  Row blocks, so that a
     block's columns are read from cache and tasks write apart."""
     width = min(X.shape[1], out.shape[1], cuts.num_feature)
+    n_rows = _block_rows(width)
 
     def block(start: int) -> None:
-        rows = slice(start, start + _BIN_BLOCK)
+        rows = slice(start, start + n_rows)
         for f in range(width):
             col, dst = X[rows, f], out[rows, f]
             present = ~np.isnan(col)
@@ -325,7 +369,7 @@ def _bin_dense_into(out: np.ndarray, X: np.ndarray, cuts: CutMatrix,
                 dst[present] = 1 + np.searchsorted(cut, col[present],
                                                    side="right")
 
-    _map(block, range(0, X.shape[0], _BIN_BLOCK))
+    _map(block, range(0, X.shape[0], n_rows))
 
 
 def bin_dense(X: np.ndarray, cuts: CutMatrix, missing: float = np.nan) -> np.ndarray:

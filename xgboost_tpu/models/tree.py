@@ -286,9 +286,14 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
         base = n_node - 1  # global index of first node at this level
         terminal = depth == d0 + D
         native = use_native and n_node <= 64
+        # a level that builds a histogram past 32 nodes (the unfolded
+        # 64-node tile, then node tiles) is set apart in a device trace:
+        # its kernels, split finding and routing go under deep.*; every
+        # other level, the terminal one included, stays under grow.*
+        scope = "deep" if n_node > 32 and not terminal else "grow"
 
         if not terminal:
-            with jax.named_scope("grow.hist"):
+            with jax.named_scope(f"{scope}.hist"):
                 hist = dequantize_hist(
                     red(build_level_histogram(binned, gh_used, pos,
                                               n_node, cfg.n_bin,
@@ -296,7 +301,7 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
                                               prep=hist_prep,
                                               native=native)))
 
-        with jax.named_scope("grow.split"):
+        with jax.named_scope(f"{scope}.split"):
             if terminal:
                 # terminal level: everything still active becomes a
                 # leaf.  Node stats DERIVE from the parent's chosen
@@ -357,7 +362,7 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
                 * cfg.split.eta
 
         # park rows whose node became a leaf; route the rest to children
-        with jax.named_scope("grow.route"):
+        with jax.named_scope(f"{scope}.route"):
             active = pos >= 0
             node_of_row = jnp.clip(pos, 0, n_node - 1)
             if best is None:

@@ -550,7 +550,7 @@ class TrainingMetrics:
             "recently traced Pallas level histogram, summed over its "
             "feature tiles: the feature count F (0 until a Pallas "
             "histogram is traced)")
-        # trace-time gauge (ops/pallas_hist._note_onehot_rows): set at a
+        # trace-time gauge (ops/pallas_hist._note_level): set at a
         # level of one node, added to at the others, so after a tree's
         # trace it holds the sum over the tree's levels
         self.hist_onehot_rows = Gauge(
@@ -561,6 +561,19 @@ class TrainingMetrics:
             "where the levels of 1-32 nodes fold the bin id's high bits "
             "into idle lanes, 1536 unfolded (0 until a Pallas histogram "
             "is traced)")
+        # trace-time gauges of the level kernel's grid (_note_level too)
+        self.hist_feature_tiles = Gauge(
+            "xgbtpu_hist_feature_tiles",
+            "feature tiles (f_pad // f_tile) of the most recently "
+            "traced Pallas level histogram: 4 at 28 features and 256 "
+            "bins, 2 at 13, 250 at 2,000 (0 until a Pallas histogram "
+            "is traced)")
+        self.hist_node_tiles = Gauge(
+            "xgbtpu_hist_node_tiles",
+            "node tiles (64 nodes each) of the Pallas level histograms "
+            "of the most recently traced tree, summed over its levels: "
+            "6 at depth 6, 9 at depth 8, where the 128-node level "
+            "takes two (0 until a Pallas histogram is traced)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -578,6 +591,7 @@ class TrainingMetrics:
                      self.device_memory, self.dispatch_seconds,
                      self.rounds_per_dispatch, self.hist_row_chunks,
                      self.hist_feature_dots, self.hist_onehot_rows,
+                     self.hist_feature_tiles, self.hist_node_tiles,
                      self.fused_fallback)
         registry().register("training", self.render)
 

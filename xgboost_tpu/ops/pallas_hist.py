@@ -140,19 +140,30 @@ _LANE_COST = 0.7
 _FOLD_PAYS = 0.1
 
 
-def _note_onehot_rows(n_node: int, rows: int) -> None:
-    """Gauge ``xgbtpu_hist_onehot_rows``: the one-hot rows one feature
-    pushes through the MXU per row tile, summed over the levels of the
-    tree last traced: set at a level of one node, added to at the
-    others (trace time, like its neighbours).  Depth 6 at 256 bins in
-    int8: 32 + 32 + 32 + 64 + 64 + 128 = 352 where the unfolded kernel
-    pushes 1,536."""
+def _note_level(n_node: int, rows: int, n_m_tiles: int,
+                f_tiles: int) -> None:
+    """The trace-time gauges of a level histogram's grid.
+
+    ``xgbtpu_hist_onehot_rows``: the one-hot rows one feature pushes
+    through the MXU per row tile (``rows`` a node tile), summed over the
+    levels of the tree last traced: set at a level of one node, added
+    to at the others (trace time, like its neighbours).  Depth 6 at 256
+    bins in int8: 32 + 32 + 32 + 64 + 64 + 128 = 352 where the unfolded
+    kernel pushes 1,536.  ``xgbtpu_hist_node_tiles``: the node tiles of
+    64 nodes, summed the same way: 6 at depth 6, 9 at depth 8 (the
+    128-node level takes two).  ``xgbtpu_hist_feature_tiles``: the
+    feature tiles ``f_pad // f_tile`` of the level last traced (4 at 28
+    features and 256 bins, 250 at 2,000): with the row tiles, the grid
+    steps a node tile."""
     from xgboost_tpu.obs import training_metrics
-    gauge = training_metrics().hist_onehot_rows
-    if n_node == 1:
-        gauge.set(float(rows))
-    else:
-        gauge.inc(float(rows))
+    tm = training_metrics()
+    tm.hist_feature_tiles.set(float(f_tiles))
+    for gauge, v in ((tm.hist_onehot_rows, rows * n_m_tiles),
+                     (tm.hist_node_tiles, n_m_tiles)):
+        if n_node == 1:
+            gauge.set(float(v))
+        else:
+            gauge.inc(float(v))
 
 
 def _feature_dots(bins, rhs_of, out_ref, lead: tuple, fi, *, n_feat: int,
@@ -482,7 +493,7 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     gh_t = jnp.pad(gh_in.T, ((0, 0), (0, n_pad - N)))        # (2, n_pad)
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
-    _note_onehot_rows(n_node, rows * n_m_tiles)
+    _note_level(n_node, rows, n_m_tiles, f_pad // f_tile)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -569,7 +580,7 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     bt = binned_t.transpose(1, 0, 2).reshape(f_pad, L * n_pad)
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
-    _note_onehot_rows(n_node, rows * n_m_tiles)
+    _note_level(n_node, rows, n_m_tiles, f_pad // f_tile)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -810,7 +821,7 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
     T_pad = t_tiles * t_tile
     n_tiles = n_pad // r_tile
     rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
-    _note_onehot_rows(n_node, n_bin * n_m_tiles)       # never folded
+    _note_level(n_node, n_bin, n_m_tiles, f_pad // f_tile)  # never folded
     if n_pad != N or T_pad != T:
         gh = jnp.pad(gh, ((0, T_pad - T), (0, n_pad - N), (0, 0)))
         pos = jnp.pad(pos, ((0, T_pad - T), (0, n_pad - N)),

@@ -79,6 +79,56 @@ class BestSplit(NamedTuple):
     left_h: jax.Array = None  # (n_node,) f32
 
 
+def _first_max(loss_chg: jax.Array, GL: jax.Array, HL: jax.Array,
+               f_ax: int, c_ax: int, d_ax: int) -> BestSplit:
+    """Per node, the FIRST maximum of ``loss_chg`` over (feature, cut,
+    direction) in that order (the lowest feature wins a tie, then the
+    lowest cut, then default-right: what ``argmax`` over the flattened
+    ``(f * C + c) * 2 + d`` gives), and the winner's left sums.
+
+    Taken axis by axis, innermost first, and never flattened: C = B - 2
+    is no multiple of the sublane tile, so collapsing ``(F, C, 2)`` into
+    one axis is a physical relayout, which the TPU compiler emits
+    feature by feature (at F = 2,000: 29 MB of code and 3.5 minutes of
+    compile time for each level's finder).  The first maximum over d
+    for every (f, c), then the first c among those maxima for every f,
+    then the first f: the same cell as the flat argmax, NaN included."""
+    m_d = loss_chg.max(axis=d_ax, keepdims=True)
+    a_d = jnp.argmax(loss_chg, axis=d_ax, keepdims=True)
+    m_c = m_d.max(axis=c_ax, keepdims=True)
+    a_c = jnp.argmax(m_d, axis=c_ax, keepdims=True)
+    best_gain = m_c.max(axis=f_ax, keepdims=True)
+    a_f = jnp.argmax(m_c, axis=f_ax, keepdims=True)
+
+    def ids(ax):
+        return jax.lax.broadcasted_iota(
+            jnp.int32, tuple(n if i == ax else 1
+                             for i, n in enumerate(loss_chg.shape)), ax)
+    # the winner's cut and direction, gather-free: one-hot selects over
+    # the small per-feature tables (batched gathers serialize on TPU)
+    sel_f = ids(f_ax) == a_f
+    cut = jnp.where(sel_f, a_c, 0).sum(axis=f_ax, keepdims=True)
+    sel_fc = sel_f & (ids(c_ax) == cut)
+    d = jnp.where(sel_fc, a_d, 0).sum(axis=(f_ax, c_ax), keepdims=True)
+    sel = (sel_fc & (ids(d_ax) == d)).astype(jnp.float32)
+
+    def node(x):
+        return x.reshape(-1)            # every other axis is 1
+    axes = (f_ax, c_ax, d_ax)
+    best_gain = node(best_gain)
+    return BestSplit(
+        best_gain, node(a_f).astype(jnp.int32),
+        node(cut).astype(jnp.int32), node(d).astype(jnp.bool_),
+        # accept: positive reduction (reference loss_chg > rt_eps,
+        # histmaker-inl.hpp:253).  gamma is NOT applied here: the prune
+        # updater post-prunes loss_chg < min_split_loss bottom-up
+        # (updater_prune-inl.hpp:42-72), which keeps a weak split whose
+        # descendants are strong — pre-pruning would not.
+        best_gain > RT_EPS,
+        # winner's left-child sums (one-hot contraction, as above)
+        (GL * sel).sum(axis=axes), (HL * sel).sum(axis=axes))
+
+
 def find_best_splits(hist: jax.Array, nstats: jax.Array, n_cuts: jax.Array,
                      cfg: SplitConfig, feature_mask: jax.Array | None = None
                      ) -> BestSplit:
@@ -121,27 +171,7 @@ def find_best_splits(hist: jax.Array, nstats: jax.Array, n_cuts: jax.Array,
         ok &= jnp.array([True, False])[None, None, None, :]
     loss_chg = jnp.where(ok, loss_chg, NEG)
 
-    flat = loss_chg.reshape(n_node, F * C * 2)
-    best = jnp.argmax(flat, axis=1)     # first max -> lowest fid (tie-break)
-    # max() rather than flat[best]: the gather is slow as a vmap-batched
-    # op on TPU, and max/argmax scan the same array
-    best_gain = flat.max(axis=1)
-    feature = (best // (C * 2)).astype(jnp.int32)
-    cut_index = ((best // 2) % C).astype(jnp.int32)
-    default_left = (best % 2).astype(jnp.bool_)
-    # accept: positive reduction (reference loss_chg > rt_eps,
-    # histmaker-inl.hpp:253).  gamma is NOT applied here: the prune updater
-    # post-prunes loss_chg < min_split_loss bottom-up
-    # (updater_prune-inl.hpp:42-72), which keeps a weak split whose
-    # descendants are strong — pre-pruning would not.
-    valid = best_gain > RT_EPS
-    # winner's left-child sums, gather-free (one-hot contraction over the
-    # flat candidate axis — batched gathers serialize on TPU)
-    sel = jax.nn.one_hot(best, flat.shape[1], dtype=jnp.float32)
-    left_g = (GL.reshape(n_node, -1) * sel).sum(axis=1)
-    left_h = (HL.reshape(n_node, -1) * sel).sum(axis=1)
-    return BestSplit(best_gain, feature, cut_index, default_left, valid,
-                     left_g, left_h)
+    return _first_max(loss_chg, GL, HL, 1, 2, 3)
 
 
 def find_best_splits_native(hist: jax.Array, nstats: jax.Array,
@@ -186,16 +216,4 @@ def find_best_splits_native(hist: jax.Array, nstats: jax.Array,
         ok &= jnp.array([True, False])[None, None, :, None]
     loss_chg = jnp.where(ok, loss_chg, NEG)
 
-    flat = loss_chg.reshape(F * C * 2, n_node)
-    best = jnp.argmax(flat, axis=0).astype(jnp.int32)
-    best_gain = flat.max(axis=0)
-    feature = (best // (C * 2)).astype(jnp.int32)
-    cut_index = ((best // 2) % C).astype(jnp.int32)
-    default_left = (best % 2).astype(jnp.bool_)
-    valid = best_gain > RT_EPS
-    ids = jnp.arange(F * C * 2, dtype=jnp.int32)
-    sel = (ids[:, None] == best[None, :]).astype(jnp.float32)
-    left_g = (GL.reshape(F * C * 2, n_node) * sel).sum(axis=0)
-    left_h = (HL.reshape(F * C * 2, n_node) * sel).sum(axis=0)
-    return BestSplit(best_gain, feature, cut_index, default_left, valid,
-                     left_g, left_h)
+    return _first_max(loss_chg, GL, HL, 0, 1, 2)
