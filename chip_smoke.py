@@ -43,6 +43,7 @@ from __future__ import annotations
 import argparse
 import collections
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -489,6 +490,7 @@ def kernel_cases(n_rows: int = 70_000):
     kernel and ``verify(out)`` checks it.  tests/test_chip_contract.py
     cross-lowers the same list for TPU on the CPU; ``--kernels`` compiles
     and runs it on the chip."""
+    import jax
     import jax.numpy as jnp
     from xgboost_tpu.ops import pallas_hist as ph
 
@@ -577,6 +579,69 @@ def kernel_cases(n_rows: int = 70_000):
     for M in (1, 32, 64):
         solo(wide_rows, 2000, 256, M, "int8", "int32", True)
     solo(wide_rows, 2000, 256, 128, "int8", "int32", False)
+
+    def derived(N, F, B, M, chunks=1):
+        # a level of M nodes built whole against the same level built as
+        # its M/2 left children and derived from its parent level's raw
+        # int32 block (ph._hist_pallas_derived): equal to every bit
+        r_tile, _, n_pad, _ = ph._tiling(N, F, B)
+        rows_per_acc = (None if chunks == 1 else
+                        -(-(n_pad // r_tile) // chunks) * r_tile)
+
+        def build(interpret):
+            rng = np.random.RandomState(17)
+            binned, gh, ppos = _dyadic_case(N, F, B, M // 2, 17, 0.1)
+            did = rng.rand(M // 2) < 0.7      # parents that split
+            did[0] = True
+            pos = np.where((ppos >= 0) & did[np.maximum(ppos, 0)],
+                           2 * ppos + rng.randint(0, 2, N), -1
+                           ).astype(np.int32)
+
+            def fn(binned, gh, ppos, did, pos):
+                gh_in, scale = ph.quantize_gh(gh)
+                bt = ph.transpose_bins(binned, B)
+                raw = functools.partial(
+                    ph._hist_level_raw, bt, gh_in, nf=(N, F), n_bin=B,
+                    precision="int8", interpret=interpret,
+                    rows_per_acc=rows_per_acc)
+                native = M <= 64
+                return (raw(pos=pos, n_node=M),
+                        ph._hist_pallas_pre(
+                            bt, gh_in, scale, pos, (N, F), M, B, "int8",
+                            interpret, native=native,
+                            rows_per_acc=rows_per_acc),
+                        ph._hist_pallas_derived(
+                            bt, gh_in, scale, pos,
+                            raw(pos=ppos, n_node=M // 2), did, (N, F), M, B,
+                            interpret, native=native,
+                            rows_per_acc=rows_per_acc))
+
+            def verify(out):
+                raw_built, built, (got, raw) = jax.tree_util.tree_map(
+                    np.asarray, out)
+                check(raw.dtype == np.int32 and np.abs(raw).max() > 0
+                      and np.array_equal(raw, raw_built),
+                      f"{name}: derived int32 block != built")
+                check(np.array_equal(got.view(np.uint32),
+                                     built.view(np.uint32)),
+                      f"{name}: derived histogram != built, bitwise")
+            return fn, (jnp.asarray(binned), jnp.asarray(gh),
+                        jnp.asarray(ppos), jnp.asarray(did),
+                        jnp.asarray(pos)), verify
+        name = (f"solo N={N} F={F} B={B} M={M} int8 derived from "
+                f"{M // 2} left children"
+                + (f" {chunks} row chunks" if chunks > 1 else ""))
+        cases.append((name, build))
+
+    # a level past the first as the grower builds it in int8 (ISSUE 38):
+    # the 2-node level from the root, the 32- and 64-node levels (built
+    # as the folded 16- and 32-node programs), the 128-node level as ONE
+    # 64-node tile; one int32 block and three; and at the Epsilon width
+    for M in (2, 32, 64, 128):
+        for chunks in (1, 3):
+            derived(n_rows, 28, 256, M, chunks)
+    for M in (64, 128):
+        derived(wide_rows, 2000, 256, M)
 
     def batched(T, N, F, B, M, precision):
         def build(interpret):

@@ -28,6 +28,7 @@ CELL, CONFIG = "epsilon-shape-synth.train_logloss", "epsilon-shape-synth-d8-b256
 TINY, TINY_CONFIG = "tiny-epsilon.train_logloss", "tiny-epsilon-shape"
 NEW_METRICS = {"hist_feature_tiles": "hist_feature_tiles",
                "hist_node_tiles": "hist_node_tiles",
+               "hist_derived_levels": "hist_derived_levels",
                "deep_hist_ms_per_round": "deep.hist",
                "deep_route_ms_per_round": "deep.route",
                "deep_split_ms_per_round": "deep.split"}
@@ -81,7 +82,7 @@ def test_new_metrics_use_the_readers_the_benchmark_has(metric):
 def test_every_metric_listed_for_the_cell_has_its_files():
     bench = run.load_json(REPO, "BENCHMARK.json")
     mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert len(mine) == 32 and "widen_ms_per_round" not in mine
+    assert len(mine) == 33 and "widen_ms_per_round" not in mine
     for name in mine:
         spec = run.load_json(BENCH, "metrics", f"{name}.json")
         assert os.path.exists(os.path.join(
@@ -92,8 +93,10 @@ def test_new_gauges_are_read_or_nothing_is():
     from readers import program_gauge
     from xgboost_tpu.obs import training_metrics
     training_metrics().hist_feature_tiles.set(250.0)
-    training_metrics().hist_node_tiles.set(9.0)
-    for name, want in (("hist_feature_tiles", 250.0), ("hist_node_tiles", 9.0)):
+    training_metrics().hist_node_tiles.set(8.0)
+    training_metrics().hist_derived_levels.set(7.0)
+    for name, want in (("hist_feature_tiles", 250.0), ("hist_node_tiles", 8.0),
+                       ("hist_derived_levels", 7.0)):
         args = run.load_json(BENCH, "metrics", f"{name}.json")["args"]
         assert program_gauge.read({}, **args) == want
 

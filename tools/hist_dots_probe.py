@@ -18,6 +18,11 @@ M = 1 and M = 32, int8 and bf16, with
   one ``_fold_of`` ships (ISSUE 34); kernel time from a device trace,
   so the call's XLA prologue and unfold are not in it.  ``--rehearse``
   checks every forced fold against the unfolded sums, bit for bit;
+* the SHIPPED kernel as it ships at M = 1, 16, 32, 64 nodes with every
+  row in a node against a random HALF of the rows parked (``pos`` -1),
+  at the first cell's shape and the third's (``--only halves``, ISSUE
+  38): a level that builds its left children only is the second; the
+  kernel is dense, so the two should cost the same;
 
 and, beside them, the forms of the padded-slot guard at 28 and 13
 features (none = the parent's program; tile = one ``pl.when`` on the
@@ -27,6 +32,7 @@ ones under ``fi == last``).
 
     chiprun -- python tools/hist_dots_probe.py            # on the chip
     chiprun -- python tools/hist_dots_probe.py --only folds
+    chiprun -- python tools/hist_dots_probe.py --only halves
     JAX_PLATFORMS=cpu python tools/hist_dots_probe.py --rehearse
 
 The table goes to stdout and to ``chiprun_out/hist_dots_probe.json``.
@@ -138,11 +144,15 @@ def bin_ids(n_rows, f_pad, n_bin=B, seed=32):
                               jnp.int32)
 
 
-def row_operands(n_rows, m_pad, mode, seed=33):
-    """``(pos, gh)``: every row in one of the level's ``m_pad`` nodes."""
+def row_operands(n_rows, m_pad, mode, seed=33, parked=0.0):
+    """``(pos, gh)``: every row in one of the level's ``m_pad`` nodes,
+    but for a random share ``parked`` of them, in none (-1)."""
     n_pad = _round_up(n_rows, R_TILE)
-    k = jax.random.split(jax.random.PRNGKey(seed))
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
     pos = jax.random.randint(k[0], (1, n_pad), 0, m_pad, jnp.int32)
+    if parked:
+        pos = jnp.where(jax.random.uniform(k[2], (1, n_pad)) < parked,
+                        -1, pos)
     gh = jax.random.randint(k[1], (2, n_pad), -127, 128, jnp.int32)
     return pos, gh.astype(jnp.int32 if mode == "int8" else jnp.float32)
 
@@ -240,8 +250,8 @@ def main():
                     "every forced fold against the unfolded sums")
     ap.add_argument("--rows", type=int, default=8_400_000)
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--only", choices=("rows", "folds", "guards"),
-                    action="append", help="default: all three")
+    ap.add_argument("--only", choices=("rows", "folds", "guards", "halves"),
+                    action="append", help="default: the first three")
     args = ap.parse_args()
     parts = args.only or ["rows", "folds", "guards"]
     interp = args.rehearse
@@ -269,28 +279,32 @@ def main():
                 "F": n_feat, "guard": guard}, med, low, n_feat,
                n_feat if guard != "none" else _round_up(n_feat, F_TILE))
 
-    def run_folds(n_feat, n_bin, n, levels):
-        """``levels``: (mode, m_pad, folds) triples on one set of bin
-        ids; one trace for the lot."""
+    def run_folds(n_feat, n_bin, n, levels, part="folds"):
+        """``levels``: (mode, m_pad, folds[, parked share]) on one set
+        of bin ids; one trace for the lot."""
         t0 = time.perf_counter()
         f_tile = ph._tiling(n, n_feat, n_bin)[1]
         f_pad = _round_up(n_feat, f_tile)
         binned_t = bin_ids(n, f_pad, n_bin)
         calls, heads = [], []
-        for mode, m_pad, folds in levels:
-            ops = (binned_t,) + row_operands(n, m_pad, mode)
+        for mode, m_pad, folds, *parked in levels:
+            parked = parked[0] if parked else 0.0
+            ops = (binned_t,) + row_operands(n, m_pad, mode, parked=parked)
             fns = [shipped_level(mode, m_pad, n_feat, n_bin, fold, interp)
                    for fold in folds]
             if interp:
-                got = [np.asarray(fn(*ops)) for fn in fns]
-                assert got[0].any() and all(
-                    np.array_equal(g, got[0]) for g in got), (mode, m_pad,
-                                                              n_bin)
+                # sums only: every fold against the unfolded program
+                want = np.asarray(shipped_level(
+                    mode, m_pad, n_feat, n_bin, (n_bin, 1), interp)(*ops))
+                assert want.any() and all(
+                    np.array_equal(np.asarray(fn(*ops)), want)
+                    for fn in fns), (mode, m_pad, n_bin, parked)
             calls += [(fn, ops) for fn in fns]
             ships = ph._fold_of(n_bin, m_pad, mode)
-            heads += [{"part": "folds", "mode": mode, "M": m_pad,
+            heads += [{"part": part, "mode": mode, "M": m_pad,
                        "B": n_bin, "F": n_feat, "N": n, "rows": fold[0],
-                       "n_hi": fold[1], "ships": fold == ships}
+                       "n_hi": fold[1], "ships": fold == ships,
+                       "parked": parked}
                       for fold in folds]
         for head, (med, low) in zip(heads, kernel_timed(calls, reps)):
             report(head, med, low, n_feat, n_feat,
@@ -317,6 +331,14 @@ def main():
                    for m in levels])
         run_folds(28, 64, n_rows, [("int8", m, folds_of(64, m, "int8"))
                                    for m in levels[:5]])
+    # what ships at M nodes, every row in a node against half of them
+    # parked: the first cell's shape, then the third's (3.2 GB of bin ids)
+    if "halves" in parts:
+        for n_feat, n in ((28, n_rows), (2000, 400_000)):
+            run_folds(n_feat, B, 4096 if interp else n,
+                      [("int8", m, [ph._fold_of(B, m, "int8")], parked)
+                       for m in (1, 16, 32, 64) for parked in (0.0, 0.5)],
+                      part="halves")
     # the guard's forms where the last tile has padded slots
     if "guards" in parts:
         for n_feat in (28, 13):

@@ -261,6 +261,7 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
     row_leaf = jnp.zeros(N, jnp.int32)
     row_val = jnp.zeros(N, jnp.float32)
     prev = None  # (best, nst, do_split) of the previous level
+    raw = None   # its histogram's exact int32 block, where there is one
 
     # once-per-tree histogram precompute: the bins transpose and (int8
     # mode) gradient quantization hoisted out of the level loop —
@@ -269,7 +270,8 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
     # caller provides it (learner entries), is the RESIDENT
     # pre-transposed u8 operand: zero per-round transpose AND none of
     # the per-pallas-call layout copies an in-graph transpose incurs
-    from xgboost_tpu.ops.histogram import prepare_hist
+    from xgboost_tpu.ops.histogram import (level_histogram_carried,
+                                           prepare_hist)
     with jax.named_scope("grow.operand"):
         hist_prep = prepare_hist(binned, gh_used, cfg.n_bin,
                                  cfg.hist_precision, binned_t=binned_t)
@@ -294,12 +296,20 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
 
         if not terminal:
             with jax.named_scope(f"{scope}.hist"):
-                hist = dequantize_hist(
-                    red(build_level_histogram(binned, gh_used, pos,
-                                              n_node, cfg.n_bin,
-                                              cfg.hist_precision,
-                                              prep=hist_prep,
-                                              native=native)))
+                if hist_prep is None:
+                    hist = build_level_histogram(binned, gh_used, pos,
+                                                 n_node, cfg.n_bin,
+                                                 cfg.hist_precision)
+                else:
+                    # past the first level built, an int8 level builds
+                    # its left children only and takes the right ones
+                    # from the level above's raw sums (prev[2]: the
+                    # parents that split)
+                    hist, raw = level_histogram_carried(
+                        pos, n_node, cfg.n_bin, cfg.hist_precision,
+                        hist_prep, native,
+                        parent=None if raw is None else (raw, prev[2]))
+                hist = dequantize_hist(red(hist))
 
         with jax.named_scope(f"{scope}.split"):
             if terminal:

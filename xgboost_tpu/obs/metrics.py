@@ -557,10 +557,11 @@ class TrainingMetrics:
             "xgbtpu_hist_onehot_rows",
             "one-hot rows one feature pushes through the MXU per row "
             "tile, summed over the Pallas level histograms of the most "
-            "recently traced tree: 352 at depth 6 and 256 bins in int8 "
-            "where the levels of 1-32 nodes fold the bin id's high bits "
-            "into idle lanes, 1536 unfolded (0 until a Pallas histogram "
-            "is traced)")
+            "recently traced tree: 256 at depth 6 and 256 bins in int8, "
+            "where a level past the first builds its left children only "
+            "and a kernel of 1-32 nodes folds the bin id's high bits "
+            "into idle lanes (352 with every node built, 1536 unfolded; "
+            "0 until a Pallas histogram is traced)")
         # trace-time gauges of the level kernel's grid (_note_level too)
         self.hist_feature_tiles = Gauge(
             "xgbtpu_hist_feature_tiles",
@@ -572,8 +573,17 @@ class TrainingMetrics:
             "xgbtpu_hist_node_tiles",
             "node tiles (64 nodes each) of the Pallas level histograms "
             "of the most recently traced tree, summed over its levels: "
-            "6 at depth 6, 9 at depth 8, where the 128-node level "
-            "takes two (0 until a Pallas histogram is traced)")
+            "6 at depth 6, 8 at depth 8 in int8 (the 128-node level's "
+            "64 left children are one; 9 with every node built: 0 "
+            "until a Pallas histogram is traced)")
+        # trace-time gauge (ops/pallas_hist._hist_pallas_derived)
+        self.hist_derived_levels = Gauge(
+            "xgbtpu_hist_derived_levels",
+            "levels of the most recently traced tree whose Pallas "
+            "histogram built the left children only and took each "
+            "right child as parent - left in the kernel's int32 sums: "
+            "5 at depth 6 and 7 at depth 8 in int8, 0 where every node "
+            "is built (float modes, vmapped trees and lanes, scatter)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -592,7 +602,7 @@ class TrainingMetrics:
                      self.rounds_per_dispatch, self.hist_row_chunks,
                      self.hist_feature_dots, self.hist_onehot_rows,
                      self.hist_feature_tiles, self.hist_node_tiles,
-                     self.fused_fallback)
+                     self.hist_derived_levels, self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

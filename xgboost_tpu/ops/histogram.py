@@ -189,13 +189,23 @@ def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
 
 @functools.lru_cache(maxsize=None)
 def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
-                               interpret: bool, has_scale: bool,
-                               native: bool = False):
+                               interpret: bool, native: bool = False):
     """custom_vmap wrapper over PREPARED operands: the unbatched call
     runs the kernel on the hoisted transpose/quantization; a vmapped
     ensemble axis dispatches to the tree-batched kernel from the raw
     bins (its tiling depends on the tree count, so it re-transposes —
     cheap at ensemble workloads' row counts).
+
+    ``hist(binned, binned_t, gh_in, pos)`` in the float modes;
+    ``hist(binned, binned_t, gh_in, pos, scale, *parent)`` ->
+    ``(histogram, raw)`` in int8, where the unbatched call also hands
+    out the kernel's exact int32 block, and takes the level above's
+    where it is given (``parent`` = its raw block and the parents that
+    split) to build the left children only
+    (pallas_hist._hist_pallas_derived).  The batched kernels build
+    every node as they always did: their rules take no parent and hand
+    out a block of zeros nothing reads (the level below is batched
+    too), so what ``grow_tree`` carries has one shape either way.
 
     ``native`` returns the kernel's (F, B, 2, n_node) layout (see
     pallas_hist._hist_pallas_pre); the batched rule asks the batched
@@ -203,68 +213,55 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
     emits either order — no extra transpose either way)."""
     from jax.custom_batching import custom_vmap
     from xgboost_tpu.ops import pallas_hist as ph
+    int8 = precision == "int8"
 
-    def _nf(binned):
-        return (binned.shape[0], binned.shape[1])
+    def batched(axis_size, in_batched, binned, binned_t, gh_in, pos,
+                scale=None):
+        def bc(x, b):
+            return x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+        gh_in, pos = bc(gh_in, in_batched[2]), bc(pos, in_batched[3])
+        if int8:
+            scale = bc(scale, in_batched[4])
+        if in_batched[0]:
+            # batched bins = tenant lanes: the lane kernel rides the
+            # prepared operands straight through (per-lane int8 scales
+            # dequantize per lane after the launch)
+            return ph._hist_pallas_lanes_pre(
+                bc(binned_t, in_batched[1]), gh_in, scale, pos,
+                (binned.shape[1], binned.shape[2]), n_node, n_bin,
+                precision, interpret, native=native)
+        return ph._hist_pallas_batched_prequant(
+            binned, gh_in, scale, pos, n_node, n_bin, precision,
+            interpret, native=native)
 
-    if has_scale:
-        @custom_vmap
-        def hist(binned, binned_t, gh_in, scale, pos):
-            return ph._hist_pallas_pre(binned_t, gh_in, scale, pos,
-                                       _nf(binned), n_node, n_bin,
-                                       precision, interpret,
-                                       native=native)
-
-        @hist.def_vmap
-        def _rule(axis_size, in_batched, binned, binned_t, gh_in,
-                  scale, pos):
-            def bc(x, b):
-                return x if b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-            if in_batched[0]:
-                # batched bins = tenant lanes: the lane kernel rides
-                # the prepared operands straight through (per-lane
-                # int8 scales dequantize per lane after the launch)
-                out = ph._hist_pallas_lanes_pre(
-                    bc(binned_t, in_batched[1]),
-                    bc(gh_in, in_batched[2]),
-                    bc(scale, in_batched[3]), bc(pos, in_batched[4]),
-                    (binned.shape[1], binned.shape[2]), n_node, n_bin,
-                    precision, interpret, native=native)
-                return out, True
-            out = ph._hist_pallas_batched_prequant(
-                binned, bc(gh_in, in_batched[2]),
-                bc(scale, in_batched[3]), bc(pos, in_batched[4]),
-                n_node, n_bin, precision, interpret, native=native)
-            return out, True
-    else:
+    if not int8:
         @custom_vmap
         def hist(binned, binned_t, gh_in, pos):
             return ph._hist_pallas_pre(binned_t, gh_in, None, pos,
-                                       _nf(binned), n_node, n_bin,
-                                       precision, interpret,
-                                       native=native)
+                                       binned.shape, n_node, n_bin,
+                                       precision, interpret, native=native)
 
         @hist.def_vmap
-        def _rule(axis_size, in_batched, binned, binned_t, gh_in, pos):
-            def bc(x, b):
-                return x if b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-            if in_batched[0]:
-                # batched bins = tenant lanes (see the has_scale rule)
-                out = ph._hist_pallas_lanes_pre(
-                    bc(binned_t, in_batched[1]),
-                    bc(gh_in, in_batched[2]), None,
-                    bc(pos, in_batched[3]),
-                    (binned.shape[1], binned.shape[2]), n_node, n_bin,
-                    precision, interpret, native=native)
-                return out, True
-            out = ph._hist_pallas_batched_prequant(
-                binned, bc(gh_in, in_batched[2]), None,
-                bc(pos, in_batched[3]), n_node, n_bin, precision,
-                interpret, native=native)
-            return out, True
+        def _rule(axis_size, in_batched, *args):
+            return batched(axis_size, in_batched, *args), True
+        return hist
 
+    @custom_vmap
+    def hist(binned, binned_t, gh_in, pos, scale, *parent):
+        return ph._hist_pallas_derived(
+            binned_t, gh_in, scale, pos, *(parent or (None, None)),
+            binned.shape, n_node, n_bin, interpret, native=native)
+
+    @hist.def_vmap
+    def _rule(axis_size, in_batched, binned, binned_t, gh_in, pos, scale,
+              *parent):
+        out = batched(axis_size, in_batched, binned, binned_t, gh_in, pos,
+                      scale)
+        from xgboost_tpu.obs import training_metrics
+        training_metrics().hist_derived_levels.set(0.0)
+        nf = binned.shape[-2:]
+        return ((out, jnp.zeros(ph.raw_block_shape(nf, n_node, n_bin),
+                                jnp.int32)), (True, False))
     return hist
 
 
@@ -292,14 +289,8 @@ def build_level_histogram(binned: jax.Array, gh: jax.Array, pos: jax.Array,
     (F, B, 2, n_node) when ``native`` (prep path only, n_node <= 64).
     """
     if prep is not None:
-        fn = _pallas_hist_pre_vmappable(
-            n_node, n_bin, prep.precision,
-            hist_backend(precision).interpret,
-            prep.scale is not None, native)
-        if prep.scale is not None:
-            return fn(prep.binned, prep.binned_t, prep.gh_in,
-                      prep.scale, pos)
-        return fn(prep.binned, prep.binned_t, prep.gh_in, pos)
+        return level_histogram_carried(pos, n_node, n_bin, precision, prep,
+                                       native)[0]
     assert not native, "native layout requires the pallas prep path"
     impl, interpret = hist_backend(precision)
     if impl in _KERNEL_MODE:
@@ -319,6 +310,31 @@ def build_level_histogram(binned: jax.Array, gh: jax.Array, pos: jax.Array,
     hist = jnp.zeros((n_node * F * n_bin, 2), dtype=jnp.float32)
     hist = hist.at[flat].add(gh[:, None, :], mode="drop")
     return hist.reshape(n_node, F, n_bin, 2)
+
+
+def level_histogram_carried(pos: jax.Array, n_node: int, n_bin: int,
+                            precision: str, prep: HistPrep,
+                            native: bool = False, parent=None) -> tuple:
+    """``(histogram, raw)`` of one level on the Pallas prep path, for a
+    level loop that carries ``raw`` to the level below (``grow_tree``).
+
+    Where the kernel's sums are integers (``prep.precision == "int8"``)
+    ``raw`` is their exact int32 block, and a level given ``parent`` =
+    (the level above's ``raw``, the (n_node / 2,) bool of the parents
+    that split) builds its LEFT children only, at half the node lanes,
+    and takes each right child as parent - left in int32: the same
+    integers the kernel sums at ``n_node`` nodes, so the same histogram
+    bit for bit, at the kernel time of the level above.  In the float
+    modes ``raw`` is None and every node is built: a float32
+    difference is not the sum the kernel would have made.  Under
+    ``jax.vmap`` (trees, lanes) the batched kernels build every node."""
+    fn = _pallas_hist_pre_vmappable(n_node, n_bin, prep.precision,
+                                    hist_backend(precision).interpret,
+                                    native)
+    if prep.scale is None:
+        return fn(prep.binned, prep.binned_t, prep.gh_in, pos), None
+    return fn(prep.binned, prep.binned_t, prep.gh_in, pos, prep.scale,
+              *(parent or ()))
 
 
 def node_stats(gh: jax.Array, pos: jax.Array, n_node: int,

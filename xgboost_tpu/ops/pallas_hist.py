@@ -140,27 +140,31 @@ _LANE_COST = 0.7
 _FOLD_PAYS = 0.1
 
 
-def _note_level(n_node: int, rows: int, n_m_tiles: int,
+def _note_level(first: bool, rows: int, n_m_tiles: int,
                 f_tiles: int) -> None:
     """The trace-time gauges of a level histogram's grid.
 
     ``xgbtpu_hist_onehot_rows``: the one-hot rows one feature pushes
     through the MXU per row tile (``rows`` a node tile), summed over the
-    levels of the tree last traced: set at a level of one node, added
-    to at the others (trace time, like its neighbours).  Depth 6 at 256
-    bins in int8: 32 + 32 + 32 + 64 + 64 + 128 = 352 where the unfolded
-    kernel pushes 1,536.  ``xgbtpu_hist_node_tiles``: the node tiles of
-    64 nodes, summed the same way: 6 at depth 6, 9 at depth 8 (the
-    128-node level takes two).  ``xgbtpu_hist_feature_tiles``: the
-    feature tiles ``f_pad // f_tile`` of the level last traced (4 at 28
-    features and 256 bins, 250 at 2,000): with the row tiles, the grid
-    steps a node tile."""
+    levels of the tree last traced: set at the ``first`` level the
+    tree builds, added to at the others (trace time, like its
+    neighbours).  Depth 6 at 256 bins in int8, where a level past the
+    first builds its left children only (:func:`_hist_pallas_derived`):
+    32 + 32 + 32 + 32 + 64 + 64 = 256 (352 with every node built, 1,536
+    unfolded).  ``xgbtpu_hist_node_tiles``: the node tiles of 64 nodes,
+    summed the same way: 6 at depth 6, 8 at depth 8 (the 128-node
+    level's 64 left children are one; 9 with every node built).
+    ``xgbtpu_hist_feature_tiles``: the feature tiles ``f_pad // f_tile``
+    of the level last traced (4 at 28 features and 256 bins, 250 at
+    2,000): with the row tiles, the grid steps a node tile."""
     from xgboost_tpu.obs import training_metrics
     tm = training_metrics()
     tm.hist_feature_tiles.set(float(f_tiles))
+    if first:
+        tm.hist_derived_levels.set(0.0)     # a new tree: none derived yet
     for gauge, v in ((tm.hist_onehot_rows, rows * n_m_tiles),
                      (tm.hist_node_tiles, n_m_tiles)):
-        if n_node == 1:
+        if first:
             gauge.set(float(v))
         else:
             gauge.inc(float(v))
@@ -472,13 +476,32 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
                      n_bin: int, precision: str, interpret: bool,
                      native: bool = False, rows_per_acc=None) -> jax.Array:
     """Kernel invocation on PREPARED operands (transpose_bins /
-    quantize_gh hoisted to once per tree/round by the grow loop).
+    quantize_gh hoisted to once per tree/round by the grow loop): the
+    kernel's raw sums (:func:`_hist_level_raw`), then their tail
+    (:func:`_hist_level_tail`).
 
     ``native=True`` returns the kernel's own ``(F, B, 2, n_node)``
     layout (node minor) without the relayout transpose — consumed by
     split.find_best_splits_native; callers gate on n_node <= 64
     (single node tile).  ``rows_per_acc`` (tests only) forces the int8
     row chunks of :func:`_acc_tiles` at a small size."""
+    raw = _hist_level_raw(binned_t, gh_in, pos, nf, n_node, n_bin,
+                          precision, interpret, rows_per_acc)
+    return _hist_level_tail(raw, scale, nf, n_node, n_bin, precision, native)
+
+
+def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
+                    precision: str, interpret: bool, rows_per_acc=None,
+                    first=None) -> jax.Array:
+    """The ``hist_level_rows`` kernel and nothing that changes a sum:
+    ``(n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad)``, block ``c *
+    n_m_tiles + t`` the sums of row chunk ``c`` for the nodes of node
+    tile ``t``, lane ``channel * m_pad + node``, unfolded
+    (:func:`_unfold` is a permutation).  In int8 mode these are the
+    accumulators' exact int32 sums; float32 otherwise, one chunk.
+    ``first``: whether this is the first level its tree builds, for the
+    gauges that sum over a tree's levels (:func:`_note_level`); a level
+    of one node where the caller does not say."""
     N, F = nf
     r_tile, f_tile, n_pad, f_pad = _tiling(N, F, n_bin)
     n_tiles = n_pad // r_tile
@@ -493,7 +516,8 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     gh_t = jnp.pad(gh_in.T, ((0, 0), (0, n_pad - N)))        # (2, n_pad)
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
-    _note_level(n_node, rows, n_m_tiles, f_pad // f_tile)
+    _note_level(n_node == 1 if first is None else first, rows, n_m_tiles,
+                f_pad // f_tile)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -524,8 +548,21 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
         interpret=interpret,
         name="hist_level_rows",
     )(binned_t, pos_t, gh_t)
-    out = _unfold(_sum_chunks(out, n_chunks, 0), f_pad, n_bin, rows, n_hi,
-                  m_pad)
+    return _unfold(out, f_pad, n_bin, rows, n_hi, m_pad)
+
+
+def _hist_level_tail(raw, scale, nf, n_node: int, n_bin: int,
+                     precision: str, native: bool = False) -> jax.Array:
+    """A level's raw block (:func:`_hist_level_raw`'s, built or derived)
+    to the histogram the finders read: the row chunks widened and added,
+    the layout, the cut to the real nodes and features, the dequantize.
+    ``(n_node, F, n_bin, 2)`` float32, or ``(F, n_bin, 2, n_node)`` when
+    ``native``."""
+    N, F = nf
+    m_pad = min(n_node, 64)
+    n_m_tiles = -(-n_node // m_pad)
+    f_pad = raw.shape[1] // n_bin
+    out = _sum_chunks(raw, raw.shape[0] // n_m_tiles, 0)
 
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
@@ -543,6 +580,72 @@ def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
         # dequantize the exact int32 sums back to f32 cell values
         out = out.astype(jnp.float32) * (scale / 127.0)[None, None, None, :]
     return out
+
+
+def _derive_level(parent, left, parent_split, n_node: int) -> jax.Array:
+    """The raw block of a level of ``n_node`` = 2M nodes from its
+    parent level's raw block and the block of its LEFT children alone
+    (both ``(n_chunks * T, f_pad * n_bin, 2 * m)``, M = T * m nodes, as
+    :func:`_hist_level_raw` lays them out), chunk by chunk in int32:
+    node 2p is ``left[p]``, node 2p + 1 is ``parent[p] - left[p]``
+    where parent p split.  A parent that became a leaf parked its rows:
+    both children are empty, and ``parent - 0`` would hand its whole
+    mass to a node that does not exist, so the mask is not optional.
+    Every row of a parent that split is in exactly one of its children,
+    so the difference IS the sum the kernel would have made over the
+    right child's rows: the same integer."""
+    M = n_node // 2
+    m = min(M, 64)                      # nodes a tile of parent / left
+    T = M // m
+    fb = parent.shape[1]
+    shape = (-1, T, fb, 2, m)
+    left = left.reshape(shape)
+    split = parent_split.reshape(1, T, 1, 1, m)
+    right = jnp.where(split, parent.reshape(shape) - left, 0)
+    out = jnp.stack([left, right], axis=-1)      # (.., 2, m, child)
+    if 2 * m <= 64:                     # one tile of 2m nodes
+        return out.reshape(-1, fb, 4 * m)
+    # m == 64: a tile's 128 children are two tiles of the level
+    out = out.reshape(-1, T, fb, 2, 2, 64).transpose(0, 1, 4, 2, 3, 5)
+    return out.reshape(-1, fb, 128)
+
+
+def _hist_pallas_derived(binned_t, gh_in, scale, pos, parent, parent_split,
+                         nf, n_node: int, n_bin: int, interpret: bool,
+                         native: bool = False, rows_per_acc=None) -> tuple:
+    """``(histogram, raw block)`` of a level of ``n_node`` nodes in int8
+    mode, the raw block kept for the level below.  With ``parent`` (the
+    level above's raw block; ``parent_split`` (n_node / 2,) bool, the
+    parents that split) the kernel builds the LEFT children only, at
+    half the node lanes — ``pos`` even, as node ``pos >> 1`` of n_node /
+    2; every other row in no node — and each right child is parent -
+    left (:func:`_derive_level`): bit for bit the block the kernel
+    builds at ``n_node`` nodes, at the price of the level above, since
+    the kernel's time goes with its node lanes and one-hot rows and not
+    with the rows in a node (PERF.md section 7).  Without ``parent``
+    (the first level a tree builds) every node is built."""
+    if parent is None:
+        raw = _hist_level_raw(binned_t, gh_in, pos, nf, n_node, n_bin,
+                              "int8", interpret, rows_per_acc, first=True)
+    else:
+        pos_left = jnp.where((pos >= 0) & (pos & 1 == 0), pos >> 1, -1)
+        left = _hist_level_raw(binned_t, gh_in, pos_left, nf, n_node // 2,
+                               n_bin, "int8", interpret, rows_per_acc,
+                               first=False)
+        raw = _derive_level(parent, left, parent_split, n_node)
+        from xgboost_tpu.obs import training_metrics
+        training_metrics().hist_derived_levels.inc(1.0)
+    return (_hist_level_tail(raw, scale, nf, n_node, n_bin, "int8", native),
+            raw)
+
+
+def raw_block_shape(nf, n_node: int, n_bin: int, rows_per_acc=None) -> tuple:
+    """Shape of the int8 raw block of a level (:func:`_hist_level_raw`)."""
+    N, F = nf
+    r_tile, _, n_pad, f_pad = _tiling(N, F, n_bin)
+    n_chunks = _acc_tiles(n_pad // r_tile, r_tile, "int8", rows_per_acc)[1]
+    m_pad = min(n_node, 64)
+    return (n_chunks * -(-n_node // m_pad), f_pad * n_bin, 2 * m_pad)
 
 
 def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
@@ -580,7 +683,7 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     bt = binned_t.transpose(1, 0, 2).reshape(f_pad, L * n_pad)
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
-    _note_level(n_node, rows, n_m_tiles, f_pad // f_tile)
+    _note_level(n_node == 1, rows, n_m_tiles, f_pad // f_tile)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -821,7 +924,7 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
     T_pad = t_tiles * t_tile
     n_tiles = n_pad // r_tile
     rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
-    _note_level(n_node, n_bin, n_m_tiles, f_pad // f_tile)  # never folded
+    _note_level(n_node == 1, n_bin, n_m_tiles, f_pad // f_tile)  # no fold
     if n_pad != N or T_pad != T:
         gh = jnp.pad(gh, ((0, T_pad - T), (0, n_pad - N), (0, 0)))
         pos = jnp.pad(pos, ((0, T_pad - T), (0, n_pad - N)),
