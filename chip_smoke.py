@@ -605,14 +605,16 @@ def kernel_cases(n_rows: int = 70_000):
                     precision="int8", interpret=interpret,
                     rows_per_acc=rows_per_acc)
                 native = M <= 64
-                return (raw(pos=pos, n_node=M),
+                # past 64 nodes a block holds node c in _node_slot(c)
+                return (raw(pos=ph._node_slot(pos, M), n_node=M),
                         ph._hist_pallas_pre(
                             bt, gh_in, scale, pos, (N, F), M, B, "int8",
                             interpret, native=native,
                             rows_per_acc=rows_per_acc),
                         ph._hist_pallas_derived(
                             bt, gh_in, scale, pos,
-                            raw(pos=ppos, n_node=M // 2), did, (N, F), M, B,
+                            raw(pos=ph._node_slot(ppos, M // 2),
+                                n_node=M // 2), did, (N, F), M, B,
                             interpret, native=native,
                             rows_per_acc=rows_per_acc))
 
@@ -642,6 +644,71 @@ def kernel_cases(n_rows: int = 70_000):
             derived(n_rows, 28, 256, M, chunks)
     for M in (64, 128):
         derived(wide_rows, 2000, 256, M)
+    # depth 10 (ISSUE 39): the 256- and 512-node levels built as 2 and 4
+    # tiles of 64 left children, over 87 feature tiles whose last has
+    # one padded slot (695 = 86 x 8 + 7)
+    for M in (256, 512):
+        derived(wide_rows, 695, 256, M)
+
+    def chain(N, F, B, levels=(64, 128, 256, 512), rounds=2):
+        # what the grower runs at depth 10 and no case above does: the
+        # levels of 128, 256 and 512 nodes each derived from the one
+        # above INSIDE one lax.scan over rounds, every level's node
+        # totals against the host's.  The parent of PR 39 read the
+        # 128-node level's totals as zero in the cell's scan
+        # (PERF.md section 6, "PR 39")
+        def build(interpret):
+            rng = np.random.RandomState(23)
+            binned = rng.randint(0, B, (N, F)).astype(np.uint8)
+            gh = (rng.randint(-512, 512, (rounds, N, 2)) / 256.0
+                  ).astype(np.float32)
+            pos = [rng.randint(0, levels[0], N).astype(np.int32)]
+            pos[0][rng.rand(N) < 0.1] = -1
+            dids = []
+            for M in levels[1:]:
+                did = rng.rand(M // 2) < 0.8    # parents that split
+                did[0] = True
+                p = pos[-1]
+                pos.append(np.where((p >= 0) & did[np.maximum(p, 0)],
+                                    2 * p + rng.randint(0, 2, N), -1
+                                    ).astype(np.int32))
+                dids.append(did)
+
+            def fn(binned, gh, pos, dids):
+                bt = ph.transpose_bins(binned, B)
+
+                def body(carry, gh):
+                    gh_in, scale = ph.quantize_gh(gh)
+                    raw, sums = None, []
+                    for i, M in enumerate(levels):
+                        hist, raw = ph._hist_pallas_derived(
+                            bt, gh_in, scale, pos[i], raw,
+                            None if i == 0 else dids[i - 1], (N, F), M, B,
+                            interpret)
+                        sums.append(hist.sum(axis=2))   # (M, F, 2)
+                    return carry, sums
+                return jax.lax.scan(body, 0, gh)[1]
+
+            def verify(out):
+                for r in range(rounds):
+                    g = _expect(gh[r], "int8").astype(np.float64)
+                    for i, M in enumerate(levels):
+                        rows = pos[i] >= 0
+                        want = np.stack([np.bincount(
+                            pos[i][rows], weights=g[rows, c], minlength=M)
+                            for c in (0, 1)], axis=1)
+                        _close(np.asarray(out[i][r]),
+                               np.broadcast_to(want[:, None, :], (M, F, 2)),
+                               "int8", f"{name} round {r} level {M}",
+                               gh[r])
+            return fn, (jnp.asarray(binned), jnp.asarray(gh),
+                        [jnp.asarray(p) for p in pos],
+                        [jnp.asarray(d) for d in dids]), verify
+        name = (f"scan N={N} F={F} B={B} int8 levels "
+                + " -> ".join(map(str, levels)) + " derived in one scan")
+        cases.append((name, build))
+
+    chain(wide_rows, 695, 256)
 
     def batched(T, N, F, B, M, precision):
         def build(interpret):

@@ -32,7 +32,7 @@ def test_every_pallas_call_site_lowers_for_tpu():
     import chip_smoke
     cases = chip_smoke.kernel_cases(5000)
     kinds = {name.split()[0] for name, _ in cases}
-    assert kinds == {"solo", "batched", "lanes", "node-stats"}
+    assert kinds == {"solo", "batched", "lanes", "node-stats", "scan"}
     failed = []
     for name, build in cases:
         fn, args, _ = build(False)          # interpret=False
@@ -43,6 +43,84 @@ def test_every_pallas_call_site_lowers_for_tpu():
         except Exception as e:
             failed.append(f"{name}: {type(e).__name__}: {str(e)[:300]}")
     assert not failed, "\n".join(failed)
+
+
+@pytest.mark.parametrize("M,tiles", [(256, 2), (512, 4)])
+def test_derived_level_at_many_node_and_feature_tiles_lowers_for_tpu(M,
+                                                                     tiles):
+    """ISSUE 39's cases: the 256- and 512-node levels built as ``tiles``
+    node tiles of 64 left children, over 87 feature tiles whose last
+    has one padded slot (F = 695), lowered for TPU: each kernel a
+    Mosaic call, the parent level's and the left children's ``tiles``
+    node tiles deep.  ``chip_smoke.py --kernels`` runs them on the
+    chip, derived == built bit for bit."""
+    import re
+
+    import jax
+
+    import chip_smoke
+    name, build = next(
+        c for c in chip_smoke.kernel_cases(5000)
+        if f"F=695 B=256 M={M} int8 derived from {M // 2} left" in c[0])
+    fn, args, _ = build(False)
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = re.findall(r"tpu_custom_call.*?-> tensor<(\d+)x", text)
+    # the parent level and the left children (M / 128 tiles each), the
+    # level built whole, raw and dequantized (M / 64 tiles each)
+    assert sorted(int(c) for c in calls) == [tiles, tiles, 2 * tiles,
+                                             2 * tiles], (name, calls)
+
+
+def test_derived_chain_in_one_scan_lowers_for_tpu():
+    """PR 39's case of what the grower runs at depth 10: the levels of
+    64, 128, 256 and 512 nodes inside one ``lax.scan``, each past the
+    first derived from the one above.  Lowered for TPU, the scan's body
+    holds one Mosaic call a level: the 64 nodes built whole, then 64,
+    128 and 256 left children (1, 1, 2 and 4 node tiles), and the
+    128-node block reaches the 256-node level's derivation."""
+    import re
+
+    import jax
+
+    import chip_smoke
+    name, build = next(c for c in chip_smoke.kernel_cases(5000)
+                       if c[0].startswith("scan "))
+    assert "64 -> 128 -> 256 -> 512" in name and "F=695" in name
+    fn, args, _ = build(False)
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "stablehlo.while" in text
+    calls = re.findall(r"tpu_custom_call.*?-> tensor<(\d+)x", text)
+    assert sorted(int(c) for c in calls) == [1, 1, 2, 4], (name, calls)
+
+
+@pytest.mark.parametrize("fault", [None, "zero 128"])
+def test_derived_chain_case_runs_here_and_sees_a_zero_level(fault,
+                                                            monkeypatch):
+    """The same case run here in interpret mode: right as shipped, and
+    its check fails where the 128-node level's block reads zero, the
+    fault the chip showed in PR 39."""
+    import jax
+
+    import chip_smoke
+    from xgboost_tpu.ops import pallas_hist as ph
+    if fault:
+        derive = ph._derive_level
+
+        def zero_128(parent, left, split, n_node):
+            out = derive(parent, left, split, n_node)
+            return out * 0 if n_node == 128 else out
+        monkeypatch.setattr(ph, "_derive_level", zero_128)
+    name, build = next(c for c in chip_smoke.kernel_cases(5000)
+                       if c[0].startswith("scan "))
+    fn, args, verify = build(True)
+    out = jax.jit(fn)(*args)
+    if fault is None:
+        verify(out)
+    else:
+        with pytest.raises(SystemExit, match="level 128"):
+            verify(out)
 
 
 @pytest.mark.parametrize("kind,kernel", [

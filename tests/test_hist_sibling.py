@@ -58,10 +58,12 @@ def _built_and_derived(F, n_bin, n_node, native, rows_per_acc, **kw):
     bt, q, scale, ppos, did, pos = _level_case(N_ROWS, F, n_bin, n_node,
                                                **kw)
     nf = (N_ROWS, F)
-    parent = ph._hist_level_raw(bt, q, ppos, nf, n_node // 2, n_bin, "int8",
-                                True, rows_per_acc)
-    raw_built = ph._hist_level_raw(bt, q, pos, nf, n_node, n_bin, "int8",
-                                   True, rows_per_acc)
+    # past 64 nodes a level's block holds node c in _node_slot(c)
+    parent = ph._hist_level_raw(bt, q, ph._node_slot(ppos, n_node // 2), nf,
+                                n_node // 2, n_bin, "int8", True,
+                                rows_per_acc)
+    raw_built = ph._hist_level_raw(bt, q, ph._node_slot(pos, n_node), nf,
+                                   n_node, n_bin, "int8", True, rows_per_acc)
     built = ph._hist_pallas_pre(bt, q, scale, pos, nf, n_node, n_bin, "int8",
                                 True, native=native,
                                 rows_per_acc=rows_per_acc)
@@ -93,6 +95,10 @@ _LEVELS += [(n, b, 13, nat, rpa) for b in (64, 67) for n in (4, 32, 128)
 # 28 of 32 slots in four feature tiles; 264: every slot a feature
 _LEVELS += [(n, 256, F, n <= 64, rpa) for n in (8, 128) for F in (28, 264)
             for rpa in (None, 2048) if not (F == 264 and rpa)]
+# depth 10 (ISSUE 39): the 256- and 512-node levels, built as 2 and 4
+# tiles of 64 left children and interleaved into 4 and 8 tiles
+_LEVELS += [(n, 256, 13, False, rpa) for n in (256, 512)
+            for rpa in (None, 2048)]
 
 
 @pytest.mark.parametrize("n_node,n_bin,F,native,rows_per_acc", _LEVELS)
@@ -130,16 +136,36 @@ def test_a_parent_that_did_not_split_has_two_empty_children(n_node):
     np.testing.assert_array_equal(derived, built)
 
 
-def test_no_parent_builds_every_node():
-    bt, q, scale, _, _, pos = _level_case(N_ROWS, 5, 64, 8)
+@pytest.mark.parametrize("n_node", [8, 256])
+def test_no_parent_builds_every_node(n_node):
+    """Past 64 nodes the block holds node c in tile c mod T, lane c div
+    T (_node_slot), and the histogram is in node order all the same."""
+    bt, q, scale, _, _, pos = _level_case(N_ROWS, 5, 64, n_node)
     hist, raw = ph._hist_pallas_derived(bt, q, scale, pos, None, None,
-                                        (N_ROWS, 5), 8, 64, True)
+                                        (N_ROWS, 5), n_node, 64, True)
     np.testing.assert_array_equal(
         np.asarray(raw), np.asarray(ph._hist_level_raw(
-            bt, q, pos, (N_ROWS, 5), 8, 64, "int8", True)))
+            bt, q, ph._node_slot(pos, n_node), (N_ROWS, 5), n_node, 64,
+            "int8", True)))
     np.testing.assert_array_equal(
         np.asarray(hist), np.asarray(ph._hist_pallas_pre(
-            bt, q, scale, pos, (N_ROWS, 5), 8, 64, "int8", True)))
+            bt, q, scale, pos, (N_ROWS, 5), n_node, 64, "int8", True)))
+
+
+@pytest.mark.parametrize("n_node", [64, 128, 256, 512])
+def test_node_slot(n_node):
+    """A permutation of the level's nodes; the children of the parent
+    in tile u, lane j are in tiles 2u and 2u + 1, lane j."""
+    c = jnp.arange(n_node, dtype=jnp.int32)
+    slot = np.asarray(ph._node_slot(c, n_node))
+    assert sorted(slot) == list(range(n_node))
+    assert int(ph._node_slot(jnp.int32(-1), n_node)) == -1
+    if n_node > 64:
+        p = np.asarray(ph._node_slot(c[:n_node // 2], n_node // 2))
+        u, j = p // 64, p % 64
+        for s in (0, 1):
+            np.testing.assert_array_equal(
+                slot[2 * np.arange(n_node // 2) + s], (2 * u + s) * 64 + j)
 
 
 def _trace_tree(F, depth):
@@ -163,14 +189,16 @@ def _trace_tree(F, depth):
 
 
 @pytest.mark.parametrize("depth,F,node_tiles,rows", [
-    (6, 28, 6, 256), (6, 13, 6, 256), (8, 2000, 8, 640), (8, 264, 8, 640)])
+    (6, 28, 6, 256), (6, 13, 6, 256), (8, 2000, 8, 640), (8, 264, 8, 640),
+    (10, 695, 14, 2176)])
 def test_gauges_after_a_traced_tree(depth, F, node_tiles, rows):
     """The three gauges that sum over the levels of the tree last
     traced, counted for the nodes the kernel BUILDS: 32 + 32 + 32 + 32 +
     64 + 64 one-hot rows at depth 6 (352 with every node built), + 128 +
     256 at depth 8, where the 128-node level is ONE tile of 64 left
-    children (1,120 rows and 9 tiles with every node built); a second
-    tree starts again at its root."""
+    children (1,120 rows and 9 tiles with every node built), + 2 x 256
+    + 4 x 256 at depth 10 (2,176 rows, 14 tiles); a second tree starts
+    again at its root."""
     tm = obs.training_metrics()
     for _ in range(2):
         _trace_tree(F, depth)

@@ -297,14 +297,18 @@ def compute_cuts_exact(dmat: DMatrix, max_exact_bin: int = 4096) -> CutMatrix:
     return pack_cuts(per_feature)
 
 
-def bin_matrix(dmat: DMatrix, cuts: CutMatrix) -> np.ndarray:
-    """Quantize to a dense (n_rows, F) bin-id array (0 = missing)."""
+def bin_matrix(dmat: DMatrix, cuts: CutMatrix,
+               missing_cols: Optional[np.ndarray] = None) -> np.ndarray:
+    """Quantize to a dense (n_rows, F) bin-id array (0 = missing).
+    ``missing_cols``, an (F,) bool array where given, is set True for
+    every column with a missing cell (the binning pass meets them
+    anyway: no second pass over the matrix)."""
     n, F = dmat.num_row, cuts.num_feature
     dtype = np.uint8 if cuts.max_bin <= 256 else np.uint16
     out = np.zeros((n, F), dtype=dtype)
     src = _dense_source(dmat)
     if src is not None:
-        _bin_dense_into(out, src[0], cuts, src[1])
+        _bin_dense_into(out, src[0], cuts, src[1], missing_cols)
         return out
     rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(dmat.indptr))
     cols = dmat.indices
@@ -314,6 +318,8 @@ def bin_matrix(dmat: DMatrix, cuts: CutMatrix) -> np.ndarray:
     # differently depending on which quantizer ran; advisor, round 4)
     in_range = (cols < F) & ~np.isnan(dmat.values)
     rows, cols, vals = rows[in_range], cols[in_range], dmat.values[in_range]
+    if missing_cols is not None:
+        missing_cols |= np.bincount(cols, minlength=F)[:F] < n
     for f in range(F):
         m = cols == f
         if not m.any():
@@ -346,14 +352,19 @@ def bin_dense_device(X, cut_values):
 
 
 def _bin_dense_into(out: np.ndarray, X: np.ndarray, cuts: CutMatrix,
-                    missing: float) -> None:
+                    missing: float,
+                    missing_cols: Optional[np.ndarray] = None) -> None:
     """``out[i, f] = 1 + searchsorted(cuts_f, X[i, f], "right")`` for
     every present cell; missing cells and stored NaNs keep bin 0 (as an
     absent CSR entry and ``bin_dense_device``'s isnan mask do), ±inf
     goes where ``searchsorted`` sends it.  Row blocks, so that a
-    block's columns are read from cache and tasks write apart."""
+    block's columns are read from cache and tasks write apart.
+    ``missing_cols[f]`` (where given) is set for a column with a
+    missing cell, and for the columns past ``X``'s width."""
     width = min(X.shape[1], out.shape[1], cuts.num_feature)
     n_rows = _block_rows(width)
+    if missing_cols is not None:
+        missing_cols[width:] = X.shape[0] > 0
 
     def block(start: int) -> None:
         rows = slice(start, start + n_rows)
@@ -368,8 +379,21 @@ def _bin_dense_into(out: np.ndarray, X: np.ndarray, cuts: CutMatrix,
             else:
                 dst[present] = 1 + np.searchsorted(cut, col[present],
                                                    side="right")
+                if missing_cols is not None:
+                    missing_cols[f] = True
 
     _map(block, range(0, X.shape[0], n_rows))
+
+
+def bin_fill_pct(cuts: CutMatrix, n_bin: int,
+                 missing_cols: np.ndarray) -> float:
+    """Percent of a level histogram's ``F x n_bin`` cells that the cuts
+    can reach: bins 1 .. n_cuts + 1 of every feature, and bin 0 of a
+    feature with a missing cell (the gauge ``xgbtpu_hist_bin_fill``)."""
+    F = cuts.num_feature
+    reach = int(np.sum(cuts.n_cuts, dtype=np.int64)) + F + int(
+        np.count_nonzero(missing_cols))
+    return 100.0 * reach / max(F * int(n_bin), 1)
 
 
 def bin_dense(X: np.ndarray, cuts: CutMatrix, missing: float = np.nan) -> np.ndarray:

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from xgboost_tpu.binning import _rank0 as _is_rank0
-from xgboost_tpu.binning import bin_matrix, compute_cuts, holds_dense
+from xgboost_tpu.binning import (bin_fill_pct, bin_matrix, compute_cuts,
+                                  holds_dense)
 from xgboost_tpu.config import TrainParam
 from xgboost_tpu.data import DMatrix, MetaInfo, upload
 from xgboost_tpu.metrics import create_metric
@@ -448,8 +449,12 @@ class Booster:
             else:
                 bin_span = dict(rows=dmat.num_row, features=dmat.num_col,
                                 dense=holds_dense(dmat))
+                missing_cols = np.zeros(self.gbtree.cuts.num_feature, bool)
                 with span("ingest.bin", **bin_span):
-                    binned_host = bin_matrix(dmat, self.gbtree.cuts)
+                    binned_host = bin_matrix(dmat, self.gbtree.cuts,
+                                             missing_cols)
+                training_metrics().hist_bin_fill.set(bin_fill_pct(
+                    self.gbtree.cuts, self.gbtree.cfg.n_bin, missing_cols))
                 binned = upload(binned_host)
                 if self._col_mesh is not None:
                     # pad the feature axis ONCE per matrix (padding per

@@ -288,11 +288,13 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
         base = n_node - 1  # global index of first node at this level
         terminal = depth == d0 + D
         native = use_native and n_node <= 64
-        # a level that builds a histogram past 32 nodes (the unfolded
-        # 64-node tile, then node tiles) is set apart in a device trace:
-        # its kernels, split finding and routing go under deep.*; every
-        # other level, the terminal one included, stays under grow.*
-        scope = "deep" if n_node > 32 and not terminal else "grow"
+        # a level that builds a histogram past 32 nodes is set apart in
+        # a device trace: its kernels, split finding and routing go
+        # under deep.* at 64 and 128 nodes (the unfolded 64-node tile,
+        # then two node tiles) and under deeper.* from 256 nodes on;
+        # every other level, the terminal one included, stays grow.*
+        scope = ("grow" if n_node <= 32 or terminal
+                 else "deep" if n_node <= 128 else "deeper")
 
         if not terminal:
             with jax.named_scope(f"{scope}.hist"):

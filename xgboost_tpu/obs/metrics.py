@@ -584,6 +584,15 @@ class TrainingMetrics:
             "right child as parent - left in the kernel's int32 sums: "
             "5 at depth 6 and 7 at depth 8 in int8, 0 where every node "
             "is built (float modes, vmapped trees and lanes, scatter)")
+        # host gauge (learner._entry, once per binned entry): how much
+        # of a level histogram the cuts can fill at all
+        self.hist_bin_fill = Gauge(
+            "xgbtpu_hist_bin_fill",
+            "share in percent of a level histogram's (feature, bin) "
+            "cells that the features' cuts can reach: sum over features "
+            "of n_cuts + 1 (+1 for bin 0 where the feature has a "
+            "missing cell) over F x n_bin, set once per binned entry "
+            "(0 until one is built)")
         # loud fallback accounting: a multi-round train request that
         # took the per-round path instead of segmented fusion, by the
         # first failing eligibility reason (update_many's gate).  A
@@ -602,7 +611,8 @@ class TrainingMetrics:
                      self.rounds_per_dispatch, self.hist_row_chunks,
                      self.hist_feature_dots, self.hist_onehot_rows,
                      self.hist_feature_tiles, self.hist_node_tiles,
-                     self.hist_derived_levels, self.fused_fallback)
+                     self.hist_derived_levels, self.hist_bin_fill,
+                     self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

@@ -552,12 +552,14 @@ def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
 
 
 def _hist_level_tail(raw, scale, nf, n_node: int, n_bin: int,
-                     precision: str, native: bool = False) -> jax.Array:
+                     precision: str, native: bool = False,
+                     slots: bool = False) -> jax.Array:
     """A level's raw block (:func:`_hist_level_raw`'s, built or derived)
     to the histogram the finders read: the row chunks widened and added,
     the layout, the cut to the real nodes and features, the dequantize.
     ``(n_node, F, n_bin, 2)`` float32, or ``(F, n_bin, 2, n_node)`` when
-    ``native``."""
+    ``native``.  ``slots``: the block holds its nodes where
+    :func:`_node_slot` puts them, not tile by tile in order."""
     N, F = nf
     m_pad = min(n_node, 64)
     n_m_tiles = -(-n_node // m_pad)
@@ -571,9 +573,10 @@ def _hist_level_tail(raw, scale, nf, n_node: int, n_bin: int,
             out = (out.astype(jnp.float32)
                    * (scale / 127.0)[None, None, :, None])
         return out
-    # (m_tiles, f_pad*B, 2M) -> (m_tiles, F, B, 2, M) -> (m_tiles*M, F, B, 2)
+    # (m_tiles, f_pad*B, 2M) -> (m_tiles, F, B, 2, M) -> (m_tiles*M, F, B, 2),
+    # node t * M + j from tile t, lane j; with ``slots`` node j * m_tiles + t
     out = out.reshape(n_m_tiles, f_pad, n_bin, 2, m_pad)
-    out = out.transpose(0, 4, 1, 2, 3).reshape(
+    out = out.transpose(((4, 0) if slots else (0, 4)) + (1, 2, 3)).reshape(
         n_m_tiles * m_pad, f_pad, n_bin, 2)
     out = out[:n_node, :F, :, :]
     if precision == "int8":
@@ -582,11 +585,25 @@ def _hist_level_tail(raw, scale, nf, n_node: int, n_bin: int,
     return out
 
 
+def _node_slot(pos: jax.Array, n_node: int) -> jax.Array:
+    """Where the int8 raw block of a level of ``n_node`` nodes holds node
+    ``pos`` (-1, no node, stays -1).  Past one node tile of 64, node c of
+    T = n_node / 64 tiles sits in tile c mod T, lane c div T: the order
+    in which :func:`_derive_level` lays out a level's children without
+    moving a lane (the children of the parent in tile u, lane j are in
+    tiles 2u and 2u + 1, lane j).  Up to 64 nodes, node c itself."""
+    T = n_node // 64
+    if T <= 1:
+        return pos
+    return jnp.where(pos >= 0, (pos % T) * 64 + pos // T, -1)
+
+
 def _derive_level(parent, left, parent_split, n_node: int) -> jax.Array:
     """The raw block of a level of ``n_node`` = 2M nodes from its
     parent level's raw block and the block of its LEFT children alone
     (both ``(n_chunks * T, f_pad * n_bin, 2 * m)``, M = T * m nodes, as
-    :func:`_hist_level_raw` lays them out), chunk by chunk in int32:
+    :func:`_hist_level_raw` lays them out, nodes where
+    :func:`_node_slot` puts them), chunk by chunk in int32:
     node 2p is ``left[p]``, node 2p + 1 is ``parent[p] - left[p]``
     where parent p split.  A parent that became a leaf parked its rows:
     both children are empty, and ``parent - 0`` would hand its whole
@@ -600,14 +617,16 @@ def _derive_level(parent, left, parent_split, n_node: int) -> jax.Array:
     fb = parent.shape[1]
     shape = (-1, T, fb, 2, m)
     left = left.reshape(shape)
-    split = parent_split.reshape(1, T, 1, 1, m)
+    # parent p = j * T + u is tile u's lane j
+    split = parent_split.reshape(m, T).T.reshape(1, T, 1, 1, m)
     right = jnp.where(split, parent.reshape(shape) - left, 0)
-    out = jnp.stack([left, right], axis=-1)      # (.., 2, m, child)
     if 2 * m <= 64:                     # one tile of 2m nodes
+        out = jnp.stack([left, right], axis=-1)  # (.., 2, m, child)
         return out.reshape(-1, fb, 4 * m)
-    # m == 64: a tile's 128 children are two tiles of the level
-    out = out.reshape(-1, T, fb, 2, 2, 64).transpose(0, 1, 4, 2, 3, 5)
-    return out.reshape(-1, fb, 128)
+    # m == 64: tile u's 128 children are the level's tiles 2u (the left
+    # ones) and 2u + 1 (the right), each in its parent's lane
+    # (_node_slot's order): tiles stacked, no lane moves
+    return jnp.stack([left, right], axis=2).reshape(-1, fb, 128)
 
 
 def _hist_pallas_derived(binned_t, gh_in, scale, pos, parent, parent_split,
@@ -620,23 +639,27 @@ def _hist_pallas_derived(binned_t, gh_in, scale, pos, parent, parent_split,
     half the node lanes — ``pos`` even, as node ``pos >> 1`` of n_node /
     2; every other row in no node — and each right child is parent -
     left (:func:`_derive_level`): bit for bit the block the kernel
-    builds at ``n_node`` nodes, at the price of the level above, since
+    builds at ``n_node`` nodes (each node where :func:`_node_slot` puts
+    it, as the level below finds its parents), at the price of the
+    level above, since
     the kernel's time goes with its node lanes and one-hot rows and not
     with the rows in a node (PERF.md section 7).  Without ``parent``
     (the first level a tree builds) every node is built."""
     if parent is None:
-        raw = _hist_level_raw(binned_t, gh_in, pos, nf, n_node, n_bin,
-                              "int8", interpret, rows_per_acc, first=True)
+        raw = _hist_level_raw(binned_t, gh_in, _node_slot(pos, n_node), nf,
+                              n_node, n_bin, "int8", interpret, rows_per_acc,
+                              first=True)
     else:
         pos_left = jnp.where((pos >= 0) & (pos & 1 == 0), pos >> 1, -1)
-        left = _hist_level_raw(binned_t, gh_in, pos_left, nf, n_node // 2,
-                               n_bin, "int8", interpret, rows_per_acc,
-                               first=False)
+        left = _hist_level_raw(binned_t, gh_in,
+                               _node_slot(pos_left, n_node // 2), nf,
+                               n_node // 2, n_bin, "int8", interpret,
+                               rows_per_acc, first=False)
         raw = _derive_level(parent, left, parent_split, n_node)
         from xgboost_tpu.obs import training_metrics
         training_metrics().hist_derived_levels.inc(1.0)
-    return (_hist_level_tail(raw, scale, nf, n_node, n_bin, "int8", native),
-            raw)
+    return (_hist_level_tail(raw, scale, nf, n_node, n_bin, "int8", native,
+                             slots=n_node > 64), raw)
 
 
 def raw_block_shape(nf, n_node: int, n_bin: int, rows_per_acc=None) -> tuple:
