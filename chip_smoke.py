@@ -650,6 +650,47 @@ def kernel_cases(n_rows: int = 70_000):
     for M in (256, 512):
         derived(wide_rows, 695, 256, M)
 
+    def narrow(N, M, precision):
+        # the one-hot shape's width classes: 693 of 695
+        # features two-valued, built at NARROW_BIN bins in a kernel call
+        # of their own (64 to a feature tile) beside the two wide ones,
+        # against the one full-width call: equal to every bit
+        F, B = 695, 256
+
+        def build(interpret):
+            binned, gh, pos = _dyadic_case(N, F, B, M, 29, 0.1)
+            wide = np.zeros(F, bool)
+            wide[[50, 694]] = True
+            binned[:, ~wide] %= 3
+            classes = ph.width_classes(F, B, ph.narrow_features(
+                np.where(wide, B - 2, 2), B))
+
+            def fn(binned, gh, pos):
+                if precision == "int8":
+                    gh_in, scale = ph.quantize_gh(gh)
+                else:
+                    gh_in, scale = gh.astype(jnp.float32), None
+                return [ph._hist_pallas_pre(
+                    bt, gh_in, scale, pos, (N, F), M, B, precision,
+                    interpret, classes=c) for bt, c in (
+                        (ph.transpose_bins(binned, B), None),
+                        (ph.transpose_classes(binned, classes), classes))]
+
+            def verify(out):
+                one, two = (np.asarray(x) for x in out)
+                check(one.any() and np.array_equal(one.view(np.uint32),
+                                                   two.view(np.uint32)),
+                      f"{name}: two width classes != one call, bitwise")
+            return fn, (jnp.asarray(binned), jnp.asarray(gh),
+                        jnp.asarray(pos)), verify
+        name = (f"solo N={N} F={F} (693 narrow) B={B} M={M} {precision} "
+                "two width classes")
+        cases.append((name, build))
+
+    for M in (1, 64, 512):
+        narrow(wide_rows, M, "int8")
+    narrow(wide_rows, 16, "fp32")
+
     def chain(N, F, B, levels=(64, 128, 256, 512), rounds=2):
         # what the grower runs at depth 10 and no case above does: the
         # levels of 128, 256 and 512 nodes each derived from the one

@@ -84,8 +84,9 @@ def test_new_metrics_use_the_readers_the_benchmark_has(metric):
 def test_every_metric_listed_for_the_cell_has_its_files():
     bench = run.load_json(REPO, "BENCHMARK.json")
     mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert len(mine) == 34 and "widen_ms_per_round" not in mine
+    assert len(mine) == 35 and "widen_ms_per_round" not in mine
     assert "hist_bin_fill_pct" in mine          # since ISSUE 39
+    assert "hist_narrow_features" in mine       # width classes
     for name in mine:
         spec = run.load_json(BENCH, "metrics", f"{name}.json")
         assert os.path.exists(os.path.join(

@@ -23,6 +23,11 @@ M = 1 and M = 32, int8 and bf16, with
   at the first cell's shape and the third's (``--only halves``, ISSUE
   38): a level that builds its left children only is the second; the
   kernel is dense, so the two should cost the same;
+* the SHIPPED kernel at ``NARROW_BIN`` = 32 bins, where a level's
+  narrow features go (``--only narrow``): 1,000,000 rows x
+  693 features (the one-hot Airline cell's narrow class), M = 1 ... 64,
+  its feature tile forced to 8, 32 and 64 (``_tiling`` gives 64);
+  ``--rehearse`` checks each tile against the shipped one;
 
 and, beside them, the forms of the padded-slot guard at 28 and 13
 features (none = the parent's program; tile = one ``pl.when`` on the
@@ -30,9 +35,10 @@ tile index around the trailing slots; slot = one ``pl.when`` per slot;
 split = two whole loops, eight slots under ``fi < last`` and the real
 ones under ``fi == last``).
 
-    chiprun -- python tools/hist_dots_probe.py            # on the chip
-    chiprun -- python tools/hist_dots_probe.py --only folds
-    chiprun -- python tools/hist_dots_probe.py --only halves
+    python tools/hist_dots_probe.py                       # one TPU chip
+    python tools/hist_dots_probe.py --only folds
+    python tools/hist_dots_probe.py --only halves
+    python tools/hist_dots_probe.py --only narrow
     JAX_PLATFORMS=cpu python tools/hist_dots_probe.py --rehearse
 
 The table goes to stdout and to ``chiprun_out/hist_dots_probe.json``.
@@ -233,6 +239,29 @@ def shipped_level(mode, m_pad, n_feat, n_bin, fold, interpret):
     return jax.jit(fn)
 
 
+_SHIPPED_TILING = ph._tiling
+
+
+def narrow_level(m_pad, n_feat, f_tile, interpret):
+    """One int8 level of the shipped kernel at ``NARROW_BIN`` bins, its
+    feature tile forced (``ph._tiling`` is read at trace time)."""
+    def tiling(N, F, n_bin):
+        r_tile, _, n_pad, _ = _SHIPPED_TILING(N, F, n_bin)
+        return r_tile, f_tile, n_pad, _round_up(F, f_tile)
+
+    def fn(binned_t, pos, gh):
+        ph._tiling = tiling
+        try:
+            return ph._hist_pallas_pre(
+                binned_t[:_round_up(n_feat, f_tile)], gh.T,
+                jnp.ones((2,), jnp.float32), pos[0],
+                (binned_t.shape[1], n_feat), m_pad, ph.NARROW_BIN, "int8",
+                interpret, native=True)
+        finally:
+            ph._tiling = _SHIPPED_TILING
+    return jax.jit(fn)
+
+
 def folds_of(n_bin, m_pad, mode):
     """Unfolded, then every power-of-two fold that fits 128 lanes."""
     floor = {"int8": 32, "bf16": 16}[mode]
@@ -250,7 +279,8 @@ def main():
                     "every forced fold against the unfolded sums")
     ap.add_argument("--rows", type=int, default=8_400_000)
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--only", choices=("rows", "folds", "guards", "halves"),
+    ap.add_argument("--only", choices=("rows", "folds", "guards", "halves",
+                                       "narrow"),
                     action="append", help="default: the first three")
     args = ap.parse_args()
     parts = args.only or ["rows", "folds", "guards"]
@@ -339,6 +369,34 @@ def main():
                       [("int8", m, [ph._fold_of(B, m, "int8")], parked)
                        for m in (1, 16, 32, 64) for parked in (0.0, 0.5)],
                       part="halves")
+    # the narrow width class: 32 bins, the feature tile forced
+    if "narrow" in parts:
+        t0 = time.perf_counter()
+        n_feat, n = 693, 4096 if interp else 1_000_000
+        binned_t = bin_ids(n, _round_up(n_feat, 64), ph.NARROW_BIN)
+        ships = ph._tiling(n, n_feat, ph.NARROW_BIN)[1]
+        calls, heads = [], []
+        for m_pad in (1, 2, 4, 8, 16, 32, 64):
+            ops = (binned_t,) + row_operands(n, m_pad, "int8")
+            fns = [narrow_level(m_pad, n_feat, t, interp)
+                   for t in (8, 32, 64)]
+            if interp:
+                want = np.asarray(narrow_level(m_pad, n_feat, ships,
+                                               interp)(*ops))
+                assert want.any() and all(
+                    np.array_equal(np.asarray(fn(*ops)), want)
+                    for fn in fns), m_pad
+            calls += [(fn, ops) for fn in fns]
+            heads += [{"part": "narrow", "mode": "int8", "M": m_pad,
+                       "B": ph.NARROW_BIN, "F": n_feat, "N": n,
+                       "f_tile": t, "ships": t == ships}
+                      for t in (8, 32, 64)]
+        for head, (med, low) in zip(heads, kernel_timed(calls, reps)):
+            report(head, med, low, n_feat, n_feat,
+                   tiles=_round_up(n, R_TILE) // R_TILE,
+                   f_tile=head["f_tile"])
+        print(f"# {len(calls)} kernels in {time.perf_counter() - t0:.1f} s",
+              file=sys.stderr, flush=True)
     # the guard's forms where the last tile has padded slots
     if "guards" in parts:
         for n_feat in (28, 13):

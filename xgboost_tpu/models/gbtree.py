@@ -382,6 +382,19 @@ class GBTree:
                 pad_features(self.n_cuts_dev, n_shard, axis=0))
         return self._col_pad_cache[1], self._col_pad_cache[2]
 
+    def _grow_cfg(self, kept=None) -> GrowConfig:
+        """``cfg`` with the features the cuts make narrow
+        (``GrowConfig.hist_narrow``: their level histograms take a
+        kernel call of their own), for a grower handed every column or
+        the screened columns ``kept`` (ascending full-space ids).  The
+        column-split and lane-stacked growers keep ``cfg`` as it is."""
+        from xgboost_tpu.ops.pallas_hist import narrow_features
+        narrow = narrow_features(self.cuts.n_cuts, self.cfg.n_bin)
+        if kept is not None:
+            ns = set(narrow)
+            narrow = tuple(p for p, f in enumerate(kept) if int(f) in ns)
+        return self.cfg._replace(hist_narrow=narrow)
+
     def _split_finder(self):
         """The pluggable split finder: skmaker's 3-way sketch selection
         when updater=grow_skmaker, else None (= histogram argmax).
@@ -562,12 +575,12 @@ class GBTree:
                         jnp.ones(binned.shape[0], jnp.bool_)
                     tree, row_leaf, d = grow_tree_dp(
                         mesh, tkey, binned, gh[:, k, :], self.cut_values_dev,
-                        self.n_cuts_dev, self.cfg, rv,
+                        self.n_cuts_dev, self._grow_cfg(), rv,
                         split_finder=self._split_finder(), root=root)
                 else:
                     tree, row_leaf, d = grow_tree(
                         tkey, binned, gh[:, k, :], self.cut_values_dev,
-                        self.n_cuts_dev, self.cfg, row_valid,
+                        self.n_cuts_dev, self._grow_cfg(), row_valid,
                         split_finder=self._split_finder(), root=root,
                         binned_t=binned_t)
                 # host-side launch wall time of the collective unit the
@@ -848,7 +861,8 @@ class GBTree:
                     jnp.int32(first_iteration), cut_vals,
                     cut_ns, row_valid, binned_t,
                     tuple(eval_binned), tuple(eval_margins),
-                    n_rounds=n_rounds, K=K, npar=npar, cfg=self.cfg,
+                    n_rounds=n_rounds, K=K, npar=npar,
+                    cfg=self._grow_cfg(feature_screen),
                     split_finder=self._split_finder(), grad_fn=grad_fn,
                     mesh=mesh, eval_is_train=tuple(eval_is_train),
                     etransform=etransform, pred_chunk=self.pred_chunk)

@@ -60,6 +60,14 @@ class GrowConfig(NamedTuple):
     # slots; row i enters at node (2**d0 - 1) + root_index[i], matching
     # RegTree::GetLeafIndex(feat, root_id) semantics (model.h:534-543)
     n_roots: int = 1
+    # the features whose bin ids all fall below pallas_hist.NARROW_BIN,
+    # from the cuts (pallas_hist.narrow_features; static, so part of
+    # the jit key): on the Pallas prepared-operand path their level
+    # histograms take a kernel call of their own at that width
+    # (ops/histogram.prepare_hist).  Ids of the features grow_tree is
+    # given, so a path that hands it other columns (a feature shard, a
+    # screen) leaves it empty or maps it
+    hist_narrow: tuple = ()
 
 
 class SplitDecision(NamedTuple):
@@ -274,7 +282,8 @@ def grow_tree(key: jax.Array, binned: jax.Array, gh: jax.Array,
                                            prepare_hist)
     with jax.named_scope("grow.operand"):
         hist_prep = prepare_hist(binned, gh_used, cfg.n_bin,
-                                 cfg.hist_precision, binned_t=binned_t)
+                                 cfg.hist_precision, binned_t=binned_t,
+                                 narrow=cfg.hist_narrow)
     # kernel-NATIVE histogram layout (F, B, 2, n_node): the split
     # finder consumes the kernel's own output order, skipping the
     # per-level relayout transpose (~0.47 ms/round at 1M x 28 —

@@ -541,7 +541,7 @@ class TrainingMetrics:
             "histogram: int8 sums at most 2^24 rows per int32 "
             "accumulator block and adds the chunks in float32 "
             "(0 until a Pallas histogram is traced)")
-        # trace-time gauge (ops/pallas_hist._feature_dots): F, not the
+        # trace-time gauge (ops/pallas_hist._note_level): F, not the
         # padded f_pad, since the slots that only pad the last feature
         # tile run no dot
         self.hist_feature_dots = Gauge(
@@ -561,14 +561,16 @@ class TrainingMetrics:
             "where a level past the first builds its left children only "
             "and a kernel of 1-32 nodes folds the bin id's high bits "
             "into idle lanes (352 with every node built, 1536 unfolded; "
+            "a level of two width classes adds both calls' rows; "
             "0 until a Pallas histogram is traced)")
         # trace-time gauges of the level kernel's grid (_note_level too)
         self.hist_feature_tiles = Gauge(
             "xgbtpu_hist_feature_tiles",
             "feature tiles (f_pad // f_tile) of the most recently "
             "traced Pallas level histogram: 4 at 28 features and 256 "
-            "bins, 2 at 13, 250 at 2,000 (0 until a Pallas histogram "
-            "is traced)")
+            "bins, 2 at 13, 250 at 2,000, both calls' where a level "
+            "takes two width classes (0 until a Pallas histogram is "
+            "traced)")
         self.hist_node_tiles = Gauge(
             "xgbtpu_hist_node_tiles",
             "node tiles (64 nodes each) of the Pallas level histograms "
@@ -584,6 +586,16 @@ class TrainingMetrics:
             "right child as parent - left in the kernel's int32 sums: "
             "5 at depth 6 and 7 at depth 8 in int8, 0 where every node "
             "is built (float modes, vmapped trees and lanes, scatter)")
+        # trace-time gauge (ops/pallas_hist._note_level): the width
+        # class a level histogram builds at 32 bins in a call of its own
+        self.hist_narrow_features = Gauge(
+            "xgbtpu_hist_narrow_features",
+            "features whose bin ids all fall below 32, which the most "
+            "recently traced Pallas level histogram builds at 32 bins in "
+            "a kernel call of their own: 693 of 695 at the one-hot "
+            "Airline shape, 0 where every feature reaches a bin of 32 or "
+            "more, under jax.vmap, and where no Pallas histogram is "
+            "traced")
         # host gauge (learner._entry, once per binned entry): how much
         # of a level histogram the cuts can fill at all
         self.hist_bin_fill = Gauge(
@@ -612,7 +624,7 @@ class TrainingMetrics:
                      self.hist_feature_dots, self.hist_onehot_rows,
                      self.hist_feature_tiles, self.hist_node_tiles,
                      self.hist_derived_levels, self.hist_bin_fill,
-                     self.fused_fallback)
+                     self.hist_narrow_features, self.fused_fallback)
         registry().register("training", self.render)
 
     def observe_eval(self, scores: Dict[str, float]) -> None:

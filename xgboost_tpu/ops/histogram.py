@@ -165,15 +165,23 @@ class HistPrep(NamedTuple):
     precision: str               # the kernel mode hist_precision
     #                              resolved to: fp32 | bf16 | int8
     #                              (kernel_mode; never by the row count)
+    classes: tuple = None        # static: the width classes of the
+    #                              level kernel's calls, binned_t one
+    #                              operand each (pallas_hist.width_classes;
+    #                              None = one call, one operand)
 
 
 def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
-                 binned_t=None):
+                 binned_t=None, narrow: tuple = ()):
     """Build a :class:`HistPrep` for the pallas path, or None when the
     scatter fallback is active (callers pass prep straight through to
     :func:`build_level_histogram`).  ``binned_t`` is an optional
     RESIDENT pre-transposed operand (pallas_hist.host_transpose_bins,
-    built once per dataset by the learner entry)."""
+    built once per dataset by the learner entry), which keeps the one
+    full-width call.  Without it, ``narrow`` (the static feature ids
+    whose bin ids all fall below pallas_hist.NARROW_BIN, from the cuts)
+    splits the level kernel into a call per width class, one operand
+    each (pallas_hist.width_classes); none narrow, one operand."""
     mode = _KERNEL_MODE.get(hist_backend(precision).impl)
     if mode is None:
         return None
@@ -182,14 +190,18 @@ def prepare_hist(binned, gh, n_bin: int, precision: str = "auto",
         gh_in, scale = ph.quantize_gh(gh)
     else:
         gh_in, scale = gh.astype(jnp.float32), None
+    classes = None
     if binned_t is None:
-        binned_t = ph.transpose_bins(binned, n_bin)
-    return HistPrep(binned, binned_t, gh_in, scale, mode)
+        classes = ph.width_classes(binned.shape[1], n_bin, narrow)
+        binned_t = (ph.transpose_bins(binned, n_bin) if classes is None
+                    else ph.transpose_classes(binned, classes))
+    return HistPrep(binned, binned_t, gh_in, scale, mode, classes)
 
 
 @functools.lru_cache(maxsize=None)
 def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
-                               interpret: bool, native: bool = False):
+                               interpret: bool, native: bool = False,
+                               classes=None):
     """custom_vmap wrapper over PREPARED operands: the unbatched call
     runs the kernel on the hoisted transpose/quantization; a vmapped
     ensemble axis dispatches to the tree-batched kernel from the raw
@@ -210,7 +222,13 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
     ``native`` returns the kernel's (F, B, 2, n_node) layout (see
     pallas_hist._hist_pallas_pre); the batched rule asks the batched
     kernel for the native order directly (its single relayout pass
-    emits either order — no extra transpose either way)."""
+    emits either order — no extra transpose either way).
+
+    ``classes`` (pallas_hist.width_classes): ``binned_t`` is a tuple of
+    one operand per width class and the unbatched call makes one kernel
+    call each (``raw`` a tuple too); the batched kernels build every
+    feature at ``n_bin`` from the raw bins as they always did, and the
+    lane rule transposes its lanes' bins at full width."""
     from jax.custom_batching import custom_vmap
     from xgboost_tpu.ops import pallas_hist as ph
     int8 = precision == "int8"
@@ -226,8 +244,10 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
             # batched bins = tenant lanes: the lane kernel rides the
             # prepared operands straight through (per-lane int8 scales
             # dequantize per lane after the launch)
+            bt = (bc(binned_t, in_batched[1]) if classes is None else
+                  jax.vmap(lambda b: ph.transpose_bins(b, n_bin))(binned))
             return ph._hist_pallas_lanes_pre(
-                bc(binned_t, in_batched[1]), gh_in, scale, pos,
+                bt, gh_in, scale, pos,
                 (binned.shape[1], binned.shape[2]), n_node, n_bin,
                 precision, interpret, native=native)
         return ph._hist_pallas_batched_prequant(
@@ -239,7 +259,8 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
         def hist(binned, binned_t, gh_in, pos):
             return ph._hist_pallas_pre(binned_t, gh_in, None, pos,
                                        binned.shape, n_node, n_bin,
-                                       precision, interpret, native=native)
+                                       precision, interpret, native=native,
+                                       classes=classes)
 
         @hist.def_vmap
         def _rule(axis_size, in_batched, *args):
@@ -250,7 +271,8 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
     def hist(binned, binned_t, gh_in, pos, scale, *parent):
         return ph._hist_pallas_derived(
             binned_t, gh_in, scale, pos, *(parent or (None, None)),
-            binned.shape, n_node, n_bin, interpret, native=native)
+            binned.shape, n_node, n_bin, interpret, native=native,
+            classes=classes)
 
     @hist.def_vmap
     def _rule(axis_size, in_batched, binned, binned_t, gh_in, pos, scale,
@@ -259,9 +281,9 @@ def _pallas_hist_pre_vmappable(n_node: int, n_bin: int, precision: str,
                       scale)
         from xgboost_tpu.obs import training_metrics
         training_metrics().hist_derived_levels.set(0.0)
-        nf = binned.shape[-2:]
-        return ((out, jnp.zeros(ph.raw_block_shape(nf, n_node, n_bin),
-                                jnp.int32)), (True, False))
+        zeros = ph.raw_block_zeros(binned.shape[-2:], n_node, n_bin, classes)
+        return ((out, zeros),
+                (True, jax.tree_util.tree_map(lambda _: False, zeros)))
     return hist
 
 
@@ -330,7 +352,7 @@ def level_histogram_carried(pos: jax.Array, n_node: int, n_bin: int,
     ``jax.vmap`` (trees, lanes) the batched kernels build every node."""
     fn = _pallas_hist_pre_vmappable(n_node, n_bin, prep.precision,
                                     hist_backend(precision).interpret,
-                                    native)
+                                    native, prep.classes)
     if prep.scale is None:
         return fn(prep.binned, prep.binned_t, prep.gh_in, pos), None
     return fn(prep.binned, prep.binned_t, prep.gh_in, pos, prep.scale,

@@ -39,6 +39,16 @@ kernel reformulates the histogram as MXU matmuls:
   tree-batched kernel, whose lanes the trees fill) nothing folds and
   the program is the one above.
 
+  A feature whose bin ids all fall below 32 (:func:`narrow_features`:
+  one-hot columns, small integer codes) leaves rows 32 ... B - 1 of its
+  one-hot empty at every level.  Where a dataset has such features the
+  level takes one call per WIDTH CLASS (:func:`width_classes`): the
+  others at B bins, the narrow ones at 32, the unfolded program at every
+  level with 64 features to a tile; the tail pads the narrow sums back
+  to B bins and puts the features back in order.  Same rows, same
+  row-tile order: the histogram bit for bit.  Where none is narrow the
+  program is the one call it always was.
+
 Bins are consumed feature-major ((F, N), int32) so every block satisfies
 the TPU (8, 128) tile rule; the (N, F) -> (F, N) transpose happens once
 per jit trace (CSE collapses the per-level copies inside one tree).
@@ -140,8 +150,9 @@ _LANE_COST = 0.7
 _FOLD_PAYS = 0.1
 
 
-def _note_level(first: bool, rows: int, n_m_tiles: int,
-                f_tiles: int) -> None:
+def _note_level(first: bool, rows: int, n_m_tiles: int, f_tiles: int,
+                n_feat: int, narrow: bool = False,
+                again: bool = False) -> None:
     """The trace-time gauges of a level histogram's grid.
 
     ``xgbtpu_hist_onehot_rows``: the one-hot rows one feature pushes
@@ -156,10 +167,28 @@ def _note_level(first: bool, rows: int, n_m_tiles: int,
     level's 64 left children are one; 9 with every node built).
     ``xgbtpu_hist_feature_tiles``: the feature tiles ``f_pad // f_tile``
     of the level last traced (4 at 28 features and 256 bins, 250 at
-    2,000): with the row tiles, the grid steps a node tile."""
+    2,000): with the row tiles, the grid steps a node tile.
+    ``xgbtpu_hist_feature_dots``: the real features of the level last
+    traced, F: the one-hot dots one row tile runs over all its feature
+    tiles (:func:`_feature_dots`).
+
+    A level whose features take two kernel calls (:func:`width_classes`)
+    notes the second with ``again``: its one-hot rows, feature tiles and
+    dots add to the level's, its node tiles are the level's own already.
+    ``xgbtpu_hist_narrow_features``: the features of the call that
+    builds them at :data:`NARROW_BIN` bins (``narrow``), 0 where no
+    call of the level does."""
     from xgboost_tpu.obs import training_metrics
     tm = training_metrics()
+    tm.hist_narrow_features.set(float(n_feat) if narrow else 0.0)
+    if again:
+        for gauge, v in ((tm.hist_feature_tiles, f_tiles),
+                         (tm.hist_feature_dots, n_feat),
+                         (tm.hist_onehot_rows, rows * n_m_tiles)):
+            gauge.inc(float(v))
+        return
     tm.hist_feature_tiles.set(float(f_tiles))
+    tm.hist_feature_dots.set(float(n_feat))
     if first:
         tm.hist_derived_levels.set(0.0)     # a new tree: none derived yet
     for gauge, v in ((tm.hist_onehot_rows, rows * n_m_tiles),
@@ -192,11 +221,7 @@ def _feature_dots(bins, rhs_of, out_ref, lead: tuple, fi, *, n_feat: int,
     ``f_tile == F``, the 64-bin jobs) no guard is in the program.
 
     bins: (f_tile, R) int32; fi: this step's index along the
-    feature-tile grid axis.  Traced once per level, so the gauge
-    ``xgbtpu_hist_feature_dots`` holds the dots one row tile of the
-    last level traced runs over all its feature tiles: F."""
-    from xgboost_tpu.obs import training_metrics
-    training_metrics().hist_feature_dots.set(float(n_feat))
+    feature-tile grid axis."""
     hot_dtype, acc_dtype, prec = _mxu_mode(precision_mode)
     r_tile = bins.shape[1]
     bin_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, r_tile), 0)
@@ -442,6 +467,69 @@ def transpose_bins(binned: jax.Array, n_bin: int) -> jax.Array:
     return binned_t
 
 
+# A feature whose bin ids all fall below this is NARROW: its level
+# histogram is built at this many bins.  The int8 one-hot's sublane tile
+# (_fold_of's floor), so the narrow kernel call is the unfolded program
+# with one shared gh_exp at every level, and _tiling's VMEM rule gives
+# it 8x the features a tile of the 256-bin call
+NARROW_BIN = 32
+
+
+def narrow_features(n_cuts, n_bin: int) -> tuple:
+    """The features whose largest bin id, ``n_cuts + 1`` (bin 0 is the
+    missing bin), falls below :data:`NARROW_BIN`: static, from the cuts
+    alone.  Empty at ``n_bin`` <= NARROW_BIN: no call is narrower."""
+    import numpy as np
+    if n_bin <= NARROW_BIN:
+        return ()
+    return tuple(int(f) for f in
+                 np.flatnonzero(np.asarray(n_cuts) + 1 < NARROW_BIN))
+
+
+def width_classes(F: int, n_bin: int, narrow: tuple):
+    """The kernel calls a level histogram of ``F`` features takes, one
+    per width class: ``((features, width), ...)``, ``features`` the
+    class's feature ids in ascending order, or None for all F in order.
+    None where no feature is narrow (or ``n_bin`` is no wider than
+    :data:`NARROW_BIN`): ONE call at ``n_bin``, the program as it always
+    was.  Otherwise the wide features at ``n_bin``, then the ``narrow``
+    ones (:func:`narrow_features`) at NARROW_BIN bins, whose rows 32 ...
+    n_bin - 1 no bin id reaches: the same sums, at 32 one-hot rows a
+    feature in place of n_bin."""
+    if not narrow or n_bin <= NARROW_BIN:
+        return None
+    assert max(narrow) < F, "narrow feature ids past the feature count"
+    if len(narrow) == F:
+        return ((None, NARROW_BIN),)
+    ns = set(narrow)
+    return ((tuple(f for f in range(F) if f not in ns), n_bin),
+            (tuple(sorted(ns)), NARROW_BIN))
+
+
+def _runs(ids) -> list:
+    """Consecutive ids as ``[start, stop)`` runs, in order."""
+    runs = []
+    for i in ids:
+        if runs and runs[-1][1] == i:
+            runs[-1][1] = i + 1
+        else:
+            runs.append([i, i + 1])
+    return runs
+
+
+def transpose_classes(binned: jax.Array, classes: tuple) -> tuple:
+    """One kernel operand per width class (:func:`width_classes`): the
+    class's columns of ``binned`` in their order, as :func:`transpose_bins`
+    lays them out at the class's width; runs of consecutive columns are
+    slices, not a gather."""
+    out = []
+    for idx, width in classes:
+        cols = binned if idx is None else jnp.concatenate(
+            [binned[:, a:b] for a, b in _runs(idx)], axis=1)
+        out.append(transpose_bins(cols, width))
+    return tuple(out)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "n_node", "n_bin", "precision", "interpret"))
 def build_level_histogram_pallas(binned: jax.Array, gh: jax.Array,
@@ -474,25 +562,50 @@ def build_level_histogram_pallas(binned: jax.Array, gh: jax.Array,
 
 def _hist_pallas_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
                      n_bin: int, precision: str, interpret: bool,
-                     native: bool = False, rows_per_acc=None) -> jax.Array:
+                     native: bool = False, rows_per_acc=None,
+                     classes=None) -> jax.Array:
     """Kernel invocation on PREPARED operands (transpose_bins /
     quantize_gh hoisted to once per tree/round by the grow loop): the
-    kernel's raw sums (:func:`_hist_level_raw`), then their tail
+    kernel's raw sums (:func:`_level_raws`), then their tail
     (:func:`_hist_level_tail`).
 
     ``native=True`` returns the kernel's own ``(F, B, 2, n_node)``
     layout (node minor) without the relayout transpose — consumed by
     split.find_best_splits_native; callers gate on n_node <= 64
     (single node tile).  ``rows_per_acc`` (tests only) forces the int8
-    row chunks of :func:`_acc_tiles` at a small size."""
-    raw = _hist_level_raw(binned_t, gh_in, pos, nf, n_node, n_bin,
-                          precision, interpret, rows_per_acc)
-    return _hist_level_tail(raw, scale, nf, n_node, n_bin, precision, native)
+    row chunks of :func:`_acc_tiles` at a small size.  ``classes``
+    (:func:`width_classes`): ``binned_t`` is :func:`transpose_classes`'
+    tuple, one kernel call a class."""
+    raw = _level_raws(binned_t, gh_in, pos, nf, n_node, n_bin, precision,
+                      interpret, rows_per_acc, classes=classes)
+    return _hist_level_tail(raw, scale, nf, n_node, n_bin, precision, native,
+                            classes=classes)
+
+
+def _level_raws(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
+                precision: str, interpret: bool, rows_per_acc=None,
+                first=None, classes=None):
+    """The raw block of a level (:func:`_hist_level_raw`), or with
+    ``classes`` (:func:`width_classes`) a tuple of one block per width
+    class, each from its own kernel call on its class's operand (the
+    tuple ``binned_t``, :func:`transpose_classes`) at its class's width:
+    every cell a class holds sums the rows it sums in the one call at
+    ``n_bin``, in the same row-tile order."""
+    if classes is None:
+        return _hist_level_raw(binned_t, gh_in, pos, nf, n_node, n_bin,
+                               precision, interpret, rows_per_acc, first)
+    N, F = nf
+    return tuple(
+        _hist_level_raw(bt, gh_in, pos, (N, F if idx is None else len(idx)),
+                        n_node, width, precision, interpret, rows_per_acc,
+                        first, narrow=width < n_bin, again=c > 0)
+        for c, (bt, (idx, width)) in enumerate(zip(binned_t, classes)))
 
 
 def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
                     precision: str, interpret: bool, rows_per_acc=None,
-                    first=None) -> jax.Array:
+                    first=None, narrow: bool = False,
+                    again: bool = False) -> jax.Array:
     """The ``hist_level_rows`` kernel and nothing that changes a sum:
     ``(n_chunks * n_m_tiles, f_pad * n_bin, 2 * m_pad)``, block ``c *
     n_m_tiles + t`` the sums of row chunk ``c`` for the nodes of node
@@ -501,7 +614,9 @@ def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
     accumulators' exact int32 sums; float32 otherwise, one chunk.
     ``first``: whether this is the first level its tree builds, for the
     gauges that sum over a tree's levels (:func:`_note_level`); a level
-    of one node where the caller does not say."""
+    of one node where the caller does not say.  ``narrow`` and
+    ``again``: this call builds the narrow width class of the level,
+    and is its second call (:func:`_level_raws`)."""
     N, F = nf
     r_tile, f_tile, n_pad, f_pad = _tiling(N, F, n_bin)
     n_tiles = n_pad // r_tile
@@ -517,7 +632,7 @@ def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
     _note_level(n_node == 1 if first is None else first, rows, n_m_tiles,
-                f_pad // f_tile)
+                f_pad // f_tile, F, narrow, again)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -553,36 +668,78 @@ def _hist_level_raw(binned_t, gh_in, pos, nf, n_node: int, n_bin: int,
 
 def _hist_level_tail(raw, scale, nf, n_node: int, n_bin: int,
                      precision: str, native: bool = False,
-                     slots: bool = False) -> jax.Array:
+                     slots: bool = False, classes=None) -> jax.Array:
     """A level's raw block (:func:`_hist_level_raw`'s, built or derived)
     to the histogram the finders read: the row chunks widened and added,
     the layout, the cut to the real nodes and features, the dequantize.
     ``(n_node, F, n_bin, 2)`` float32, or ``(F, n_bin, 2, n_node)`` when
     ``native``.  ``slots``: the block holds its nodes where
-    :func:`_node_slot` puts them, not tile by tile in order."""
+    :func:`_node_slot` puts them, not tile by tile in order.
+
+    With ``classes`` (:func:`width_classes`) ``raw`` is one block per
+    width class, and each class's histogram is padded with zero cells
+    from its width to ``n_bin`` bins (the cells no bin id of its
+    features reaches, zero in the one call too) and set back among the
+    others in the features' own order, runs of consecutive features as
+    slices, before the one dequantize: the same histogram, bit for
+    bit."""
     N, F = nf
+    if classes is None:
+        out = _tail_layout(raw, F, n_node, n_bin, native, slots)
+    else:
+        axis = 0 if native else 1           # the feature axis
+        parts = []
+        for block, (idx, width) in zip(raw, classes):
+            part = _tail_layout(block, F if idx is None else len(idx),
+                                n_node, width, native, slots)
+            if width < n_bin:
+                pad = [(0, 0)] * part.ndim
+                pad[axis + 1] = (0, n_bin - width)
+                part = jnp.pad(part, pad)
+            parts.append(part)
+        out = _in_feature_order(parts, classes, axis)
+    if precision == "int8":
+        # dequantize the exact int32 sums back to f32 cell values
+        s = scale / 127.0
+        out = out.astype(jnp.float32) * (s[None, None, :, None] if native
+                                         else s[None, None, None, :])
+    return out
+
+
+def _tail_layout(raw, F: int, n_node: int, n_bin: int, native: bool,
+                 slots: bool) -> jax.Array:
+    """:func:`_hist_level_tail` of one block up to the dequantize."""
     m_pad = min(n_node, 64)
     n_m_tiles = -(-n_node // m_pad)
     f_pad = raw.shape[1] // n_bin
     out = _sum_chunks(raw, raw.shape[0] // n_m_tiles, 0)
-
     if native:
         assert n_m_tiles == 1, "native layout needs a single node tile"
-        out = out.reshape(f_pad, n_bin, 2, m_pad)[:F, :, :, :n_node]
-        if precision == "int8":
-            out = (out.astype(jnp.float32)
-                   * (scale / 127.0)[None, None, :, None])
-        return out
+        return out.reshape(f_pad, n_bin, 2, m_pad)[:F, :, :, :n_node]
     # (m_tiles, f_pad*B, 2M) -> (m_tiles, F, B, 2, M) -> (m_tiles*M, F, B, 2),
     # node t * M + j from tile t, lane j; with ``slots`` node j * m_tiles + t
     out = out.reshape(n_m_tiles, f_pad, n_bin, 2, m_pad)
     out = out.transpose(((4, 0) if slots else (0, 4)) + (1, 2, 3)).reshape(
         n_m_tiles * m_pad, f_pad, n_bin, 2)
-    out = out[:n_node, :F, :, :]
-    if precision == "int8":
-        # dequantize the exact int32 sums back to f32 cell values
-        out = out.astype(jnp.float32) * (scale / 127.0)[None, None, None, :]
-    return out
+    return out[:n_node, :F, :, :]
+
+
+def _in_feature_order(parts, classes, axis: int) -> jax.Array:
+    """The width classes' histograms (``parts``, features along ``axis``
+    in each class's order) as one, features in their own order: a
+    concatenation of slices, one a run of features that are consecutive
+    in one class and in the whole."""
+    if len(parts) == 1:
+        return parts[0]
+    pieces = []                         # (first feature, class, position, n)
+    for c, (idx, _) in enumerate(classes):
+        p = 0
+        for a, b in _runs(idx):
+            pieces.append((a, c, p, b - a))
+            p += b - a
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(parts[c], p, p + n, axis=axis)
+         for _, c, p, n in sorted(pieces)], axis=axis)
 
 
 def _node_slot(pos: jax.Array, n_node: int) -> jax.Array:
@@ -631,7 +788,8 @@ def _derive_level(parent, left, parent_split, n_node: int) -> jax.Array:
 
 def _hist_pallas_derived(binned_t, gh_in, scale, pos, parent, parent_split,
                          nf, n_node: int, n_bin: int, interpret: bool,
-                         native: bool = False, rows_per_acc=None) -> tuple:
+                         native: bool = False, rows_per_acc=None,
+                         classes=None) -> tuple:
     """``(histogram, raw block)`` of a level of ``n_node`` nodes in int8
     mode, the raw block kept for the level below.  With ``parent`` (the
     level above's raw block; ``parent_split`` (n_node / 2,) bool, the
@@ -644,22 +802,28 @@ def _hist_pallas_derived(binned_t, gh_in, scale, pos, parent, parent_split,
     level above, since
     the kernel's time goes with its node lanes and one-hot rows and not
     with the rows in a node (PERF.md section 7).  Without ``parent``
-    (the first level a tree builds) every node is built."""
+    (the first level a tree builds) every node is built.  With
+    ``classes`` (:func:`width_classes`) the raw block, ``parent`` and
+    the left children are each a tuple of one block per width class,
+    and each class derives on its own."""
     if parent is None:
-        raw = _hist_level_raw(binned_t, gh_in, _node_slot(pos, n_node), nf,
-                              n_node, n_bin, "int8", interpret, rows_per_acc,
-                              first=True)
+        raw = _level_raws(binned_t, gh_in, _node_slot(pos, n_node), nf,
+                          n_node, n_bin, "int8", interpret, rows_per_acc,
+                          first=True, classes=classes)
     else:
         pos_left = jnp.where((pos >= 0) & (pos & 1 == 0), pos >> 1, -1)
-        left = _hist_level_raw(binned_t, gh_in,
-                               _node_slot(pos_left, n_node // 2), nf,
-                               n_node // 2, n_bin, "int8", interpret,
-                               rows_per_acc, first=False)
-        raw = _derive_level(parent, left, parent_split, n_node)
+        left = _level_raws(binned_t, gh_in,
+                           _node_slot(pos_left, n_node // 2), nf,
+                           n_node // 2, n_bin, "int8", interpret,
+                           rows_per_acc, first=False, classes=classes)
+        raw = (_derive_level(parent, left, parent_split, n_node)
+               if classes is None else
+               tuple(_derive_level(p, lf, parent_split, n_node)
+                     for p, lf in zip(parent, left)))
         from xgboost_tpu.obs import training_metrics
         training_metrics().hist_derived_levels.inc(1.0)
     return (_hist_level_tail(raw, scale, nf, n_node, n_bin, "int8", native,
-                             slots=n_node > 64), raw)
+                             slots=n_node > 64, classes=classes), raw)
 
 
 def raw_block_shape(nf, n_node: int, n_bin: int, rows_per_acc=None) -> tuple:
@@ -669,6 +833,17 @@ def raw_block_shape(nf, n_node: int, n_bin: int, rows_per_acc=None) -> tuple:
     n_chunks = _acc_tiles(n_pad // r_tile, r_tile, "int8", rows_per_acc)[1]
     m_pad = min(n_node, 64)
     return (n_chunks * -(-n_node // m_pad), f_pad * n_bin, 2 * m_pad)
+
+
+def raw_block_zeros(nf, n_node: int, n_bin: int, classes=None):
+    """int32 zeros in the structure of a level's int8 raw block: one
+    block, or with ``classes`` a tuple of one per width class."""
+    if classes is None:
+        return jnp.zeros(raw_block_shape(nf, n_node, n_bin), jnp.int32)
+    N, F = nf
+    return tuple(jnp.zeros(raw_block_shape(
+        (N, F if idx is None else len(idx)), n_node, width), jnp.int32)
+        for idx, width in classes)
 
 
 def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
@@ -706,7 +881,7 @@ def _hist_pallas_lanes_pre(binned_t, gh_in, scale, pos, nf, n_node: int,
     bt = binned_t.transpose(1, 0, 2).reshape(f_pad, L * n_pad)
 
     rows, n_hi = _fold_of(n_bin, m_pad, precision)
-    _note_level(n_node == 1, rows, n_m_tiles, f_pad // f_tile)
+    _note_level(n_node == 1, rows, n_m_tiles, f_pad // f_tile, F)
 
     out_dtype = jnp.int32 if precision == "int8" else jnp.float32
     kernel = functools.partial(_hist_kernel, m_pad=m_pad,
@@ -947,7 +1122,7 @@ def _hist_pallas_batched_pre(binned_t, gh, scale, pos, nf, n_node: int,
     T_pad = t_tiles * t_tile
     n_tiles = n_pad // r_tile
     rpa, n_chunks = _acc_tiles(n_tiles, r_tile, precision, rows_per_acc)
-    _note_level(n_node == 1, n_bin, n_m_tiles, f_pad // f_tile)  # no fold
+    _note_level(n_node == 1, n_bin, n_m_tiles, f_pad // f_tile, F)  # no fold
     if n_pad != N or T_pad != T:
         gh = jnp.pad(gh, ((0, T_pad - T), (0, n_pad - N), (0, 0)))
         pos = jnp.pad(pos, ((0, T_pad - T), (0, n_pad - N)),
