@@ -95,7 +95,7 @@ def test_new_metrics_use_the_readers_the_benchmark_has(metric):
 def test_every_metric_but_widen_is_listed_for_the_cell_and_has_its_files():
     bench = run.load_json(REPO, "BENCHMARK.json")
     mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert len(mine) == len(bench["per_layer"]) - 1 == 38
+    assert len(mine) == len(bench["per_layer"]) - 1 == 40
     assert "widen_ms_per_round" not in mine
     assert bench["workloads"][-1]["name"] == CELL       # appended, last
     for name in mine:

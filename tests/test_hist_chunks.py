@@ -181,8 +181,6 @@ def test_kernel_mode_names_the_scatter_paths(monkeypatch):
 
 def test_train_launch_says_which_mode_ran(tmp_path, monkeypatch):
     monkeypatch.setenv("XGBTPU_HIST", "pallas_int8")
-    # a log alone would move the run off the fused path (PERF.md §7)
-    monkeypatch.setenv("XGBTPU_OBS_PHASES", "0")
     rng = np.random.default_rng(0)
     X = rng.normal(size=(600, 5)).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float32)

@@ -471,8 +471,8 @@ def test_the_metric_file_reads_the_gauge():
         spec = json.load(f)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "hist_narrow_features"
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "hist_narrow_features")
     assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
     assert (spec["reader"], spec["layer"], spec["moves"], spec["unit"]) == (
         "program_gauge", "histogram kernels", "train_rounds_per_s", "count")

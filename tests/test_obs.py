@@ -273,7 +273,8 @@ def test_training_rounds_emit_timeline_and_metrics(obs_log):
     rounds0 = obs.training_metrics().rounds.value
     ar0 = comm.metrics().count["allreduce"].value
     calls0 = mock.collective_calls()
-    _train(rounds=3)
+    # per-round phases are profile>=1's: a log alone keeps the fused path
+    _train(rounds=3, profile=1)
     assert obs.training_metrics().rounds.value - rounds0 == 3
     # comm allreduce count matches the mock seam's collective calls
     assert (comm.metrics().count["allreduce"].value - ar0
@@ -305,7 +306,9 @@ def test_eval_scores_exported_as_gauges():
 
 def test_cli_train_scrape_and_timeline(tmp_path):
     """Acceptance: CLI train with metrics_port= is scrapeable over HTTP
-    while running, and obs_log= leaves a timeline obs_report renders."""
+    while running, and obs_log= leaves a timeline obs_report renders
+    (per-round phase lines under profile=1, which keeps the round loop
+    on the host)."""
     rng = np.random.RandomState(11)
     X = rng.rand(300, 5).astype(np.float32)
     y = (X[:, 0] + X[:, 1] > 1.0).astype(np.float32)
@@ -324,7 +327,7 @@ def test_cli_train_scrape_and_timeline(tmp_path):
             f"data={train}", "task=train", "num_round=40",
             "objective=binary:logistic", "max_depth=3", "silent=1",
             f"eval[train]={train}", f"model_out={model}",
-            f"obs_log={log}", "metrics_port=0"])
+            f"obs_log={log}", "metrics_port=0", "profile=1"])
 
     t = threading.Thread(target=run)
     t.start()

@@ -282,14 +282,12 @@ def train_stall_mode(args) -> int:
                    f"model_out={out}",
                    f"checkpoint_dir={os.path.join(work, f'ck_{cell}_{run:03d}')}",
                    f"mock={mock}"]
-            # XGBTPU_OBS_PHASES=0: the event log must witness the run
-            # WITHOUT forcing per-round phases (which would itself
-            # block fusion — the fallback we are asserting against)
+            # the event log witnesses the run; it no longer moves the
+            # run off the fused path (the fallback asserted against)
             r = subprocess.run(cmd, cwd=repo, capture_output=True,
                                text=True, timeout=600,
                                env=dict(os.environ, JAX_PLATFORMS="cpu",
-                                        XGBTPU_OBS_LOG=obs_log,
-                                        XGBTPU_OBS_PHASES="0"))
+                                        XGBTPU_OBS_LOG=obs_log))
             entry["rc"] = r.returncode
             entry["watchdog_kills"] = r.stderr.count("[launch] STALL")
             entry["restarts"] = r.stderr.count("[launch] restarting")
@@ -397,8 +395,7 @@ def degrade_cells(args, work, repo, report) -> bool:
                "--restart-backoff-sec", "0.2",
                "--gang-dir", gang_dir, *launch_extra, "--", *worker]
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   XGBTPU_OBS_LOG=obs_log, XGBTPU_OBS_PHASES="0",
-                   **env_extra)
+                   XGBTPU_OBS_LOG=obs_log, **env_extra)
         log = open(os.path.join(work, f"{tag}.log"), "ab")
         paths = {"out": out, "obs": obs_log, "gang_dir": gang_dir,
                  "ck": ck, "log": log.name,

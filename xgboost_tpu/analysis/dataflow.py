@@ -546,9 +546,8 @@ class ImpureTracedScope(Rule):
     nested in one).  Traced Python runs ONCE at trace time: an obs
     ``event()``/``span()``, ``faults.inject()``, ``time.*`` read,
     ``print``/``open``, or global/nonlocal mutation fires once per
-    compile — not per execution — which is exactly the silent
-    obs-vs-XLA divergence the ``XGBTPU_OBS_PHASES=0`` fallback existed
-    to dodge; ``np.asarray`` on a traced value raises a
+    compile — not per execution — a silent divergence between the
+    log and what XLA runs; ``np.asarray`` on a traced value raises a
     TracerArrayConversionError at best.  Hoist the side effect to the
     host-side caller (the mock.collective replay in do_boost_fused is
     the worked example), or use ``jax.debug.*`` (exempt)."""

@@ -172,9 +172,9 @@ class TrainParam:
     # to a crash-safe JSONL timeline (tools/obs_report.py renders it;
     # XGBTPU_OBS_LOG is the env equivalent); metrics_port= serves live
     # /metrics + /healthz during task=train from a daemon thread
-    # (0 = ephemeral port, printed at startup; -1 = off).  Either one
-    # enables per-round phase instrumentation — same cost contract as
-    # profile=1 (a device barrier per phase, no fused round loop).
+    # (0 = ephemeral port, printed at startup; -1 = off).  Neither one
+    # changes the path: fused segments log their train.* spans; per-round
+    # phases (a device barrier each, no fused round loop) are profile=1's.
     obs_log: str = ""
     metrics_port: int = -1
 

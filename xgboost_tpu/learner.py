@@ -892,19 +892,15 @@ class Booster:
     @property
     def profiler(self):
         """Lazily created RoundProfiler when param profile>=1 (the
-        report_stats analog, SURVEY.md §5.1) — or, at level 0, when the
-        observability layer is on (``obs_log=``/``metrics_port=``):
-        phase spans, the event-log timeline and the
-        live training metrics all need the per-phase boundaries, which
-        also means per-round host control (no fused multi-round launch)
-        and a device barrier per phase — the same cost contract as
-        ``profile=1``."""
+        report_stats analog, SURVEY.md §5.1): per-phase boundaries mean
+        per-round host control (no fused multi-round launch) and a
+        device barrier per phase.  A log (``obs_log=``) or a scrape port
+        (``metrics_port=``) alone creates none: the fused path's
+        ``train.*`` spans and counters describe the run they watch."""
         if getattr(self, "_profiler", None) is not None:
             return self._profiler
         if self.param.profile <= 0:
-            from xgboost_tpu import obs
-            if not obs.phases_enabled():
-                return None
+            return None
         from xgboost_tpu.obs import RoundProfiler
         self._profiler = RoundProfiler(
             self.param.profile, self.param.profile_dir or None)
@@ -1076,8 +1072,8 @@ class Booster:
 
         Falls back to per-round :meth:`update` (same callbacks, one
         boundary per round) when fusion is ineligible — custom/host
-        objective, pruning, refresh, column split, profiler/obs
-        phases, external-memory matrices — or when the resolved
+        objective, pruning, refresh, column split, ``profile>=1``,
+        external-memory matrices — or when the resolved
         segment size is 0 (the per-round A/B baseline).  Every
         fallback is LOUD: ``xgbtpu_train_fused_fallback_total`` and a
         ``train.fused_fallback`` event record the first blocking

@@ -824,12 +824,22 @@ class GBTree:
         # wall time to a collective family — that wall time is device
         # compute and belongs to xgbtpu_train_dispatch_seconds alone.
         from xgboost_tpu.obs import span, training_metrics
+        from xgboost_tpu.obs.metrics import dispatch_readings, wait_attrs
         from xgboost_tpu.parallel import mock
+        tm = training_metrics()
+        device = min(binned.sharding.addressable_devices,
+                     key=lambda d: d.id)
         with span("train.dispatch", first_round=first_iteration,
                   n_rounds=n_rounds,
                   mesh_fused=bool(mesh_scan)) as dispatch:
             with span("train.launch",
                       hist_mode=kernel_mode(self.cfg.hist_precision)):
+                # before the scan call allocates: what a stalled wait
+                # is read against (train.wait's attributes)
+                at_launch = dispatch_readings(device)
+                tm.launch_bytes_in_use.set(at_launch.get("bytes_in_use", 0))
+                tm.launch_bytes_reserved.set(
+                    at_launch.get("bytes_reserved", 0))
                 label = info.label_dev()
                 weight = info.weight_dev(margin.shape[0])
                 cut_vals, cut_ns = self.cut_values_dev, self.n_cuts_dev
@@ -855,24 +865,35 @@ class GBTree:
                         else:
                             mock.collective(nbytes=comm_nbytes)
                 scan = _scan_rounds_mesh if mesh_scan else _scan_rounds
+                cfg = self._grow_cfg(feature_screen)
                 margin_f, emargins_f, stacks, eouts = scan(
                     binned, margin, label, weight,
                     self.base_key(),
                     jnp.int32(first_iteration), cut_vals,
                     cut_ns, row_valid, binned_t,
                     tuple(eval_binned), tuple(eval_margins),
-                    n_rounds=n_rounds, K=K, npar=npar,
-                    cfg=self._grow_cfg(feature_screen),
+                    n_rounds=n_rounds, K=K, npar=npar, cfg=cfg,
                     split_finder=self._split_finder(), grad_fn=grad_fn,
                     mesh=mesh, eval_is_train=tuple(eval_is_train),
                     etransform=etransform, pred_chunk=self.pred_chunk)
-            with span("train.wait"):
+            with span("train.wait") as wait:
                 # block at the segment boundary: the driver pulls eval
                 # lines / checkpoint bytes from this dispatch next, and
                 # the histogram must record device wall time, not async
                 # dispatch
+                t0 = time.perf_counter()
                 jax.block_until_ready(margin_f)
-        tm = training_metrics()
+                wait_s = time.perf_counter() - t0
+                at_end = dispatch_readings(device)
+                # the wait against the quickest of its kind: the kind is
+                # what the compiled scan is keyed on
+                excess_s, stalled = tm.observe_wait(
+                    (n_rounds, binned.shape, binned.dtype,
+                     tuple(e.shape for e in eval_binned), mesh, cfg),
+                    wait_s)
+                for k, v in wait_attrs(wait_s, excess_s, stalled,
+                                       at_launch, at_end).items():
+                    wait.set(k, v)
         tm.dispatch_seconds.observe(dispatch.seconds)
         tm.rounds_per_dispatch.set(float(n_rounds))
         with span("train.absorb"):

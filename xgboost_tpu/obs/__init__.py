@@ -26,9 +26,9 @@ Four pieces, one package:
 
 Cost contract (OBSERVABILITY.md): spans, their always-on totals and
 their profiler annotations cost microseconds and change nothing the
-program does; the per-round :class:`RoundProfiler` (``profile>=1``,
-``obs_log=``, ``metrics_port=``) puts a device barrier at each phase
-and keeps the round loop on the host.
+program does, with a log or a scrape port on or off; only the per-round
+:class:`RoundProfiler` (``profile>=1``) puts a device barrier at each
+phase and keeps the round loop on the host.
 """
 
 from xgboost_tpu.obs import comm  # noqa: F401
@@ -53,25 +53,6 @@ from xgboost_tpu.obs.trace import (current_trace_id, event,  # noqa: F401
                                    span, trace_context)
 
 
-def phases_enabled() -> bool:
-    """True when round-phase instrumentation should run even without
-    ``profile>=1``: the event log is configured or the metrics server
-    is up.  Phase timing forces device barriers at phase boundaries
-    (and keeps the round loop on the host), so it is opt-in — the same
-    cost contract as ``profile=1``.
-
-    ``XGBTPU_OBS_PHASES=0`` keeps a configured event log / metrics
-    server WITHOUT the phase barriers: discrete events and dispatch
-    spans still land in the JSONL log, but the fused multi-round
-    dispatch stays eligible.  The chaos suite's fallback-free
-    verification rides this — it needs ``train.fused_fallback`` events
-    observable without the observer forcing the fallback."""
-    import os
-    if os.environ.get("XGBTPU_OBS_PHASES", "") == "0":
-        return False
-    return get_log() is not None or get_metrics_server() is not None
-
-
 __all__ = [
     "comm", "span", "event", "trace_context", "current_trace_id",
     "EventLog", "configure_log", "get_log",
@@ -84,5 +65,4 @@ __all__ = [
     "reliability_metrics", "training_metrics", "span_totals",
     "RoundProfiler",
     "start_metrics_server", "get_metrics_server", "stop_metrics_server",
-    "phases_enabled",
 ]

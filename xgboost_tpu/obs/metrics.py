@@ -20,6 +20,7 @@ the benchmark reads for host work done before any profiler starts.
 from __future__ import annotations
 
 import bisect
+import resource
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -485,6 +486,72 @@ def reliability_metrics() -> ReliabilityMetrics:
 
 
 # ---------------------------------------------------------------- training
+#: a dispatch whose wait passes its kind's least by more than this share
+#: is stalled: alone it would move `train_rounds_per_s` by its 1 % bound
+STALL_SHARE = 0.01
+#: what a fused dispatch reads of its device's allocator, where the
+#: runtime reports it (`Device.memory_stats()`; None on the CPU)
+DEVICE_READINGS = ("bytes_in_use", "bytes_reserved", "num_allocs",
+                   "largest_free_block_bytes")
+#: what it reads of the host, as counts since the process started
+HOST_READINGS = ("cpu_s", "nivcsw")
+
+
+class WaitFloor:
+    """The least ``train.wait`` seconds seen for each kind of fused
+    dispatch (what the compiled scan is keyed on), and what each
+    dispatch lags it.  :meth:`observe` gives ``(excess_s, stalled)``:
+    0 and False for the first dispatch of a kind and for one that sets
+    a new least; else ``wait - least`` and whether that passes
+    :data:`STALL_SHARE` of the least."""
+
+    def __init__(self):
+        self._least: Dict[object, float] = {}
+        self._lock = threading.Lock()
+
+    def observe(self, kind, wait_s: float) -> Tuple[float, bool]:
+        with self._lock:
+            least = self._least.get(kind)
+            if least is None or wait_s <= least:
+                self._least[kind] = wait_s
+                return 0.0, False
+        excess = wait_s - least
+        return excess, excess > STALL_SHARE * least
+
+
+def dispatch_readings(device) -> Dict[str, float]:
+    """What can name the cause of a stalled dispatch, read at one
+    instant: the process's CPU seconds and involuntary context switches
+    (``getrusage``), and :data:`DEVICE_READINGS` of
+    ``device``'s allocator where its backend reports them (a CPU backend
+    reports none: those keys are absent, nothing is counted as an
+    error)."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    out = {"cpu_s": ru.ru_utime + ru.ru_stime, "nivcsw": ru.ru_nivcsw}
+    stats = device.memory_stats()
+    if stats:
+        out.update((k, stats[k]) for k in DEVICE_READINGS if k in stats)
+    return out
+
+
+def wait_attrs(wait_s: float, excess_s: float, stalled: bool,
+               at_launch: dict, at_end: dict) -> dict:
+    """``train.wait``'s attributes at exit: the wait against the
+    quickest of its kind; the host's :data:`HOST_READINGS` from the head
+    of ``train.launch`` to the end of the wait; the device's
+    :data:`DEVICE_READINGS` at both ends (``launch_*``, ``end_*``),
+    where its backend reports them."""
+    out = {"wait_s": wait_s, "excess_s": excess_s, "stalled": stalled}
+    for k in HOST_READINGS:
+        out[k] = at_end[k] - at_launch[k]
+    for k in DEVICE_READINGS:
+        if k in at_launch:
+            out[f"launch_{k}"] = at_launch[k]
+        if k in at_end:
+            out[f"end_{k}"] = at_end[k]
+    return out
+
+
 class TrainingMetrics:
     """Training-side metric group (``xgbtpu_training_*``): live progress
     of a long run, scrapeable mid-run via the ``metrics_port=`` daemon
@@ -533,6 +600,28 @@ class TrainingMetrics:
             "xgbtpu_train_rounds_per_dispatch",
             "rounds covered by the most recent fused training dispatch "
             "(segment size; stays 0 on the per-round path)")
+        # each fused dispatch's train.wait against the quickest one of
+        # its kind (models/gbtree.do_boost_fused, WaitFloor)
+        self.wait_floor = WaitFloor()
+        self.wait_excess_seconds = Counter(
+            "xgbtpu_train_wait_excess_seconds_total",
+            "seconds by which fused dispatches waited longer than the "
+            "quickest dispatch of their kind seen before them (0 for "
+            "the first of a kind and for a new quickest)")
+        self.stalled_dispatches = Counter(
+            "xgbtpu_train_stalled_dispatches_total",
+            "fused dispatches whose wait passed the quickest of their "
+            "kind by more than 1 % of it")
+        self.launch_bytes_in_use = Gauge(
+            "xgbtpu_train_launch_bytes_in_use",
+            "device bytes in use at the head of the most recent fused "
+            "dispatch, before its scan allocates (0 where the backend "
+            "reports no memory stats)")
+        self.launch_bytes_reserved = Gauge(
+            "xgbtpu_train_launch_bytes_reserved",
+            "device bytes the allocator held reserved at the head of "
+            "the most recent fused dispatch: where this runtime keeps a "
+            "running program's scratch (0 where not reported)")
         # trace-time gauge (ops/pallas_hist._sum_chunks): 1 while a
         # job's rows fit one int32 accumulator block, 3 at 40M rows
         self.hist_row_chunks = Gauge(
@@ -620,7 +709,9 @@ class TrainingMetrics:
                      self.phase_seconds, self.eval_score,
                      self.checkpoints, self.checkpoint_seconds,
                      self.device_memory, self.dispatch_seconds,
-                     self.rounds_per_dispatch, self.hist_row_chunks,
+                     self.rounds_per_dispatch, self.wait_excess_seconds,
+                     self.stalled_dispatches, self.launch_bytes_in_use,
+                     self.launch_bytes_reserved, self.hist_row_chunks,
                      self.hist_feature_dots, self.hist_onehot_rows,
                      self.hist_feature_tiles, self.hist_node_tiles,
                      self.hist_derived_levels, self.hist_bin_fill,
@@ -635,6 +726,15 @@ class TrainingMetrics:
                 self.eval_score.set(k, float(v))
             except (TypeError, ValueError):
                 pass
+
+    def observe_wait(self, kind, wait_s: float) -> Tuple[float, bool]:
+        """Count one fused dispatch's wait against the least of its
+        kind: ``(excess_s, stalled)``, as :meth:`WaitFloor.observe`."""
+        excess, stalled = self.wait_floor.observe(kind, wait_s)
+        self.wait_excess_seconds.inc(excess)
+        if stalled:
+            self.stalled_dispatches.inc()
+        return excess, stalled
 
     def refresh_device_memory(self) -> None:
         """Best-effort device-memory gauge via

@@ -10,17 +10,18 @@ consumers, all driven by the same phase boundaries:
   captures a ``jax.profiler`` trace (``profile=2``);
 - event log — every phase and every round emit ``kind="span"`` records
   (name ``train.phase``/``train.round``) when ``obs_log=`` is
-  configured, the round record carrying the phase breakdown and the
-  round's collective tallies (obs/comm.py) so a dead run leaves a
-  replayable timeline;
+  configured beside ``profile>=1``, the round record carrying the phase
+  breakdown and the round's collective tallies (obs/comm.py) so a dead
+  run leaves a replayable timeline;
 - metrics — rounds completed, per-phase seconds, round wall time and
   device memory land on :class:`~xgboost_tpu.obs.metrics.TrainingMetrics`
   for the ``metrics_port=`` scrape.
 
 Phases force a device barrier at their boundaries (``.block``) so
 async dispatch doesn't smear costs across phases — which is also why
-the learner only instruments rounds when profiling or observability is
-explicitly enabled (a barrier per phase serializes host and device).
+the learner only instruments rounds under ``profile>=1`` (a barrier per
+phase serializes host and device); a log or a scrape port alone keeps
+the fused path and its ``train.*`` spans.
 """
 
 from __future__ import annotations
@@ -36,8 +37,8 @@ from xgboost_tpu.obs.metrics import training_metrics
 class RoundProfiler:
     """Collects per-phase wall time per boosting round.
 
-    ``level=0`` keeps the spans/metrics but prints nothing — the shape
-    an ``obs_log=``-only run uses; ``level>=1`` adds the ``[prof]``
+    ``level=0`` keeps the spans/metrics but prints nothing (the
+    timeline self-test's shape); ``level>=1`` adds the ``[prof]``
     stderr lines; ``level>=2`` adds the jax.profiler trace."""
 
     def __init__(self, level: int = 1, trace_dir: Optional[str] = None,
